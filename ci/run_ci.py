@@ -7,6 +7,11 @@ reference's per-package matrix, ``pipeline.yaml:323-384``).
     python ci/run_ci.py                # everything
     python ci/run_ci.py --only tests --package lightgbm2
     python ci/run_ci.py --only examples
+
+Everything here runs on the CPU, in children: this parent imports no
+JAX, so it never holds a chip, and every child is pinned to the CPU
+platform (``tests/conftest.py``, ``JAX_PLATFORMS=cpu``). What runs on
+the chip is ``chip_smoke.py``, sent through the chip tool.
 """
 
 from __future__ import annotations
@@ -42,7 +47,7 @@ PACKAGES: dict[str, list[str]] = {
                  "test_recommendation_lime.py", "test_cyber.py"],
     "io": ["test_native_codegen.py", "test_benchmarks.py",
            "test_reference_parity.py", "test_out_of_core.py",
-           "test_ci.py", "test_bench_banking.py", "test_rcheck.py"],
+           "test_ci.py", "test_rcheck.py"],
     "obs": ["test_obs.py", "test_obs_profile.py"],
     # fleet telemetry plane: federation + straggler/burn health + the
     # chaos trajectory, and the HBM memory profiler's degradation story
@@ -78,6 +83,11 @@ PACKAGES: dict[str, list[str]] = {
     # device cost-attribution plane: PeakSpec/rooflines, AOT cost
     # persistence, goodput ledger, xprof capture surface, schema v6
     "attribution": ["test_attribution.py"],
+    # the chip, without the chip: chip_smoke.py's phases rehearsed tiny
+    # on the CPU, and the main path's kernels and two whole programs
+    # compiled for a described v5e (one file: one process may load the
+    # TPU's library)
+    "chip": ["test_chip_smoke.py", "test_chip_compile.py"],
 }
 
 # traceable-count ratchet (ISSUE 10): the analysis gate fails if the
@@ -592,11 +602,13 @@ def analysis() -> int:
 
 def regression_gate() -> int:
     """The perf-regression trajectory gate (ISSUE 16): diff the newest
-    banked ``BENCH_r0*.json`` against its predecessor, the whole
-    trajectory pricing each metric's noise. Exit 1 = a gated metric
-    regressed beyond tolerance; a too-short trajectory (fresh clone,
-    < 2 banked runs) is a pass with a note, not a failure. Budget:
-    < 60 s — it is pure JSON diffing, no JAX, no benchmarks re-run."""
+    bench run against its predecessor, the whole trajectory pricing
+    each metric's noise. Exit 1 = a gated metric regressed beyond
+    tolerance; a too-short trajectory is a pass with a note, not a
+    failure. The tree holds no bench runs at present (none was measured
+    on the current code), so the trajectory is empty until the
+    benchmark records some. Budget: < 60 s — it is pure JSON diffing,
+    no JAX, no benchmarks re-run."""
     t0 = time.monotonic()
     rc = _run([sys.executable, "-m", "mmlspark_tpu.obs.regression",
                "gate"], env=dict(os.environ, JAX_PLATFORMS="cpu"))
@@ -612,7 +624,7 @@ def regression_gate() -> int:
 
 
 def aot_roundtrip() -> int:
-    """Build-then-load round trip across two scrubbed processes: the
+    """Build-then-load round trip across two CPU-pinned processes: the
     store built by one process must warm-load in a fresh one with zero
     runtime compiles and bit-equal output (the AOT acceptance's
     cross-process half, as a standing CI job)."""

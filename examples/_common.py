@@ -14,7 +14,9 @@ import jax  # noqa: E402
 
 if os.environ.get("MMLSPARK_TPU_EXAMPLES_CPU", "1") != "0":
     jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/mmlspark_tpu_jax_cache")
+from mmlspark_tpu.core.aot import place_jax_cache  # noqa: E402
+
+place_jax_cache("cpu")
 
 import numpy as np  # noqa: E402
 

@@ -3,6 +3,9 @@
 every ``examples/*.py`` runs as a subprocess and must print
 ``EXAMPLE_OK <name>``).
 
+The runner imports no JAX and each example pins the CPU platform
+(``_common.py``), so nothing here takes a chip.
+
 Usage: ``python examples/run_all.py [pattern]``; exits non-zero if any
 example fails. Each example gets a timeout and one flaky retry, mirroring
 the reference CI's retry policy (``pipeline.yaml:406-408``).

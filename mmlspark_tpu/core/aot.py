@@ -3,7 +3,7 @@
 Why: every (route, padding-bucket, mesh) combination pays its XLA
 compile the first time traffic hits it, so an autoscaler scale-up is a
 compile storm on the fresh worker — first-request latency is seconds
-while steady-state p99 is ~0.8 ms (BENCH_r05). Per the full-program
+against a steady state of milliseconds. Per the full-program
 compilation thesis (arXiv:1810.09868) and fingerprint-keyed caching
 (arXiv:2008.01040), the fix is to move compilation to build time:
 ``core/compile.py``'s :class:`~.compile.FusedSegment` is already the
@@ -70,8 +70,9 @@ _LOG = logging.getLogger("mmlspark_tpu.core.aot")
 #: path would let any local user plant code another user's server
 #: boot would execute (maybe_warm additionally refuses roots this uid
 #: does not own).
-DEFAULT_STORE_ROOT = "/tmp/mmlspark_tpu_aot_store-" + str(
-    getattr(os, "getuid", lambda: "u")())
+DEFAULT_STORE_ROOT = os.path.join(
+    tempfile.gettempdir(), "mmlspark_tpu_aot_store-" + str(
+        getattr(os, "getuid", lambda: "u")()))
 _META = "meta.json"
 _EXE = "exe.bin"
 _HLO = "hlo.txt"
@@ -80,23 +81,41 @@ STORE_VERSION = 1
 
 def store_root() -> str:
     """The configured store root: ``MMLSPARK_TPU_AOT_STORE`` or the
-    default. Shared config point with ``core.utils.scrubbed_cpu_env``'s
-    JAX persistent-cache placement."""
+    default."""
     return os.environ.get("MMLSPARK_TPU_AOT_STORE") or DEFAULT_STORE_ROOT
 
 
-def jax_cache_dir() -> str:
-    """Where the JAX persistent compilation cache should live: an
-    explicit ``JAX_COMPILATION_CACHE_DIR`` wins; with a configured AOT
-    store root the two caches co-locate under it; else the historical
-    default. ``core.utils.scrubbed_cpu_env`` honors this instead of
-    clobbering (ISSUE 11 satellite)."""
+#: the checkout that holds this package: the persistent compile cache
+#: lives under it at a FIXED path (the path is part of the cache key —
+#: a directory that moves with a pid, the time or mkdtemp never hits)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def jax_cache_dir(subdir: str = "") -> str:
+    """Where the JAX persistent compilation cache lives. Two rules:
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the whole answer (the
+    operator placed the cache from outside); otherwise a fixed
+    directory inside the checkout, ``<checkout>/.jax_cache[/subdir]``
+    (git-ignored). ``subdir`` keeps the test suite's CPU executables
+    apart from a script's."""
     explicit = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if explicit:
         return explicit
-    if os.environ.get("MMLSPARK_TPU_AOT_STORE"):
-        return os.path.join(store_root(), "jax_cache")
-    return "/tmp/mmlspark_tpu_jax_cache"
+    return os.path.join(_CHECKOUT, ".jax_cache", subdir).rstrip(os.sep)
+
+
+def place_jax_cache(subdir: str = "") -> str:
+    """Point this process's persistent compile cache at
+    :func:`jax_cache_dir` and return the directory in use. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX's own handling of the
+    variable stands and nothing is configured here — the ONE place in
+    the tree that may set ``jax_compilation_cache_dir``."""
+    path = jax_cache_dir(subdir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------- metrics
@@ -668,19 +687,14 @@ class AotStore:
                 segment.name, cost["flops"], cost["bytes"],
                 service=segment.name.split(":", 1)[0])
         blob = None
-        if compat.aot_serialization_available():
-            try:
-                blob = compat.serialize_compiled(compiled)
-            except Exception:
-                _LOG.warning(
-                    "executable serialization failed for segment %s; "
-                    "storing a retrace-tier entry (warm loads will "
-                    "re-lower at boot, not at request time)",
-                    segment.name, exc_info=True)
-        else:
+        try:
+            blob = compat.serialize_compiled(compiled)
+        except Exception:
             _LOG.warning(
-                "this JAX build cannot serialize executables; storing "
-                "a retrace-tier entry for segment %s", segment.name)
+                "executable serialization failed for segment %s; "
+                "storing a retrace-tier entry (warm loads will "
+                "re-lower at boot, not at request time)",
+                segment.name, exc_info=True)
         try:
             self.save(full_fp=full_fp, static_fp=static_fp,
                       segment_name=segment.name,
@@ -1155,7 +1169,7 @@ def _cli(argv=None) -> int:
                         "versions' entries (rollback horizon); spared "
                         "entries count in aot_gc_kept_versions")
     st = sub.add_parser("selftest", help="build-then-load round trip "
-                        "in two scrubbed subprocesses (CI job)")
+                        "in two CPU-pinned subprocesses (CI job)")
     st.add_argument("--root", default=None)
     v = sub.add_parser("verify", help="warm-load a service from the "
                        "store and assert zero runtime compiles")
@@ -1228,10 +1242,10 @@ def _cli(argv=None) -> int:
         return _verify(args.root, args.service)
 
     if args.cmd == "selftest":
-        from .utils import scrubbed_cpu_env
+        from .utils import cpu_child_env
         root = args.root or tempfile.mkdtemp(
             prefix="mmlspark_tpu_aot_selftest_")
-        env = scrubbed_cpu_env()
+        env = cpu_child_env()
         rc = subprocess.call(
             [sys.executable, "-m", "mmlspark_tpu.core.aot", "build",
              "--service", _SELFTEST_SERVICE, "--root", root], env=env)

@@ -1,9 +1,8 @@
 """Whole-pipeline XLA compilation: fuse traceable stage runs into single
 jitted/pjit'd computations.
 
-Why: ``BENCH_TPU_BANKED.json`` shows a served model step at ~1 ms while
-the contended device-dispatch RTT is ~64 ms — host↔device round trips
-BETWEEN pipeline stages, not compute, dominate end-to-end latency.
+Why: when a pipeline executes stage by stage, host↔device round trips
+BETWEEN pipeline stages, not compute, can dominate end-to-end latency.
 Following the Julia-to-TPU full-program compilation approach
 (arXiv:1810.09868) and TVM's end-to-end operator fusion
 (arXiv:1802.04799), a ``PipelineModel`` of featurize → model → postproc

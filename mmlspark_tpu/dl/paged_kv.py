@@ -51,9 +51,9 @@ import numpy as np
 from ..obs import registry as _default_registry
 
 __all__ = ["PagedKVManager", "SequenceHandle", "OutOfBlocks",
-           "blocks_for_hbm_budget", "init_pools", "gather_dense",
-           "paged_attention_enabled", "scatter_positions",
-           "take_positions"]
+           "blocks_for_hbm_budget", "pool_block_bytes", "init_pools",
+           "gather_dense", "paged_attention_enabled",
+           "scatter_positions", "take_positions"]
 
 #: the reserved trash block — device programs route padded/inactive
 #: writes here; the host half never hands it to a sequence
@@ -120,6 +120,33 @@ def _chunk_hash(prev: str, tokens) -> str:
     h.update(b"|")
     h.update(",".join(str(int(t)) for t in tokens).encode())
     return h.hexdigest()
+
+
+def pool_block_bytes(encoder, block_len: int) -> int:
+    """Bytes ONE block is priced at when ``encoder``'s per-layer k and v
+    pools are sized from an HBM budget. Every array a step can hold is
+    counted in the tiled layout the TPU kernel reads a
+    ``[block_len, heads, head_dim]`` block in — the minor dim rounds up
+    to 128 lanes, the one before it to the dtype's sublane count (8
+    rows of 32 bits; a dim below that to its power of two), so 8 heads
+    of 64 cost 2x their logical bytes and 2 heads of 16 cost 8x:
+    ``2 * depth`` arrays at rest, plus the four (one layer's k and v,
+    coming and going) that XLA materializes in that layout around the
+    attention kernel, because it keeps a pool with a narrow minor dim
+    compact at rest and re-lays it out on every step (first chip run,
+    PR 23: sized from logical bytes, 8M blocks of [4, 2, 16] asked for
+    one 32.9 GB padded copy). Pure shape arithmetic — no JAX."""
+    itemsize = np.dtype(encoder.dtype).itemsize
+    hd = encoder.width // encoder.heads
+    sublanes = 8 * max(4 // itemsize, 1)
+    heads = encoder.heads
+    if heads < sublanes:
+        heads = 1 << (heads - 1).bit_length()
+    else:
+        heads = -(-heads // sublanes) * sublanes
+    lanes = -(-hd // 128) * 128
+    return ((2 * encoder.depth + 4) * int(block_len) * heads * lanes
+            * itemsize)
 
 
 def blocks_for_hbm_budget(block_bytes: int, *, fraction: float = 0.5,

@@ -279,11 +279,8 @@ def grow_tree(bins: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     gh1 = jnp.stack([g, h, cnt_w], axis=1)  # [n, 3]
     bin_idx = feat_offsets + bins.astype(jnp.int32)        # [n, F]
 
-    try:
-        from .pallas_hist import hist_pallas, use_pallas_hist
-        pallas_ok = use_pallas_hist()
-    except Exception:  # pragma: no cover - pallas unavailable
-        pallas_ok = False
+    from .pallas_hist import hist_pallas, use_pallas_hist
+    pallas_ok = use_pallas_hist()
 
     def local_hist(row_sel, full: bool = False):
         """SHARD-LOCAL histogram of one row subset → [F, B, 3]: the

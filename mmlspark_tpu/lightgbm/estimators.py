@@ -222,7 +222,11 @@ class _LightGBMBase(Estimator, LightGBMSharedParams):
         devices = jax.devices()
         if ns == 0:
             ns = len(devices) if n_rows >= 4096 and len(devices) > 1 else 1
-        ns = min(ns, len(devices))
+        if ns > len(devices):
+            raise ValueError(
+                f"numShards={ns} asks for more devices than exist "
+                f"({len(devices)}); training on fewer shards than asked "
+                "for is never silent")
         if ns <= 1:
             return None
         axes = self._shard_axes()

@@ -9,13 +9,19 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
+import tempfile
 import threading
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
-_CACHE_DIR = os.environ.get("MMLSPARK_TPU_NATIVE_CACHE",
-                            "/tmp/mmlspark_tpu_native")
+# under the temp dir, never the checkout: the build is -march=native,
+# and a checkout is copied between machines
+_CACHE_DIR = os.environ.get(
+    "MMLSPARK_TPU_NATIVE_CACHE",
+    os.path.join(tempfile.gettempdir(), "mmlspark_tpu_native"))
+_LOG = logging.getLogger("mmlspark_tpu.native")
 
 
 class NativeLoader:
@@ -72,7 +78,9 @@ _libs: dict[str, ctypes.CDLL | None] = {}
 def _lazy_native(name: str, sources: list[str], configure):
     """Shared lazy loader: one build+load per process, honoring the
     ``MMLSPARK_TPU_DISABLE_NATIVE=1`` kill-switch; returns None when the
-    toolchain is unavailable (callers fall back to Python paths)."""
+    library cannot be built or loaded (callers fall back to Python
+    paths). The reason is logged once at WARNING, compiler stderr
+    included, so "no g++" can be told from "source no longer builds"."""
     if name in _libs:
         return _libs[name]
     if os.environ.get("MMLSPARK_TPU_DISABLE_NATIVE", "") == "1":
@@ -81,7 +89,12 @@ def _lazy_native(name: str, sources: list[str], configure):
     try:
         lib = NativeLoader(name, sources).load()
         configure(lib)
-    except Exception:
+    except Exception as e:
+        stderr = getattr(e, "stderr", None)
+        if isinstance(stderr, bytes):
+            stderr = stderr.decode(errors="replace")
+        _LOG.warning("native library %s unavailable: %r%s", name, e,
+                     f"\n{stderr.strip()[-4000:]}" if stderr else "")
         _libs[name] = None
         return None
     _libs[name] = lib

@@ -10,8 +10,7 @@ analytic ceiling. This module owns both halves of that comparison:
   MFU columns, the regression sentinel's synthetic steps). Resolution
   order: explicit argument > ``MMLSPARK_TPU_PEAK_FLOPS`` /
   ``MMLSPARK_TPU_PEAK_BYTES_PER_S`` env overrides > the detected TPU
-  generation (``device_kind``) > the platform family default > the CPU
-  fallback row.
+  generation (``device_kind``). A device with no row raises.
 
 - :class:`CostAttribution` — records each compiled program's analytic
   cost (XLA ``cost_analysis()`` flops / bytes accessed, normalized by
@@ -41,12 +40,15 @@ gauges; jax is only touched behind the same no-init guards
 
 from __future__ import annotations
 
+import logging
 import os
 import sys
 import threading
 from dataclasses import dataclass, replace
 
 from .metrics import registry as _registry
+
+_LOG = logging.getLogger(__name__)
 
 #: env overrides — an operator pinning the peak for an unlisted part
 #: (or a derated clock) wins over the table, whatever the platform.
@@ -70,24 +72,18 @@ class PeakSpec:
 
 
 #: Per-platform peaks. TPU rows are bf16 per-chip peaks with the
-#: published HBM bandwidths; the ``cpu`` row is the bench harness's
-#: longstanding 1 Tflop/s reference point (testing/benchmarks.py used
-#: it inline) with a DDR-class bandwidth, so CPU rooflines stay
-#: comparable across runs rather than pretending to model the host.
+#: published HBM bandwidths (Google Cloud documentation); the ``cpu``
+#: row is the host-only scenarios' 1 Tflop/s reference point with a
+#: DDR-class bandwidth — it prices CPU test runs only and never stands
+#: in for a device that has no row.
 PEAK_SPECS: dict[str, PeakSpec] = {
     "tpu-v5e": PeakSpec("tpu-v5e", 197e12, 819e9),
     "tpu-v4": PeakSpec("tpu-v4", 275e12, 1228e9),
     "cpu": PeakSpec("cpu", 1.0e12, 100e9),
 }
 
-#: family default: a TPU whose generation we cannot read resolves to
-#: the fleet's current default part (v5e — the ROADMAP target slice)
-_TPU_DEFAULT = "tpu-v5e"
-_FALLBACK = "cpu"
-
-
-def _tpu_generation() -> str | None:
-    """``device_kind``-derived generation key, with the same
+def _device_kind() -> str | None:
+    """The live first device's ``device_kind``, with the same
     never-initialize guard as ``profile.device_platform``: only ask a
     backend that already exists."""
     mod = sys.modules.get("jax")
@@ -96,10 +92,12 @@ def _tpu_generation() -> str | None:
     xb = sys.modules.get("jax._src.xla_bridge")
     if xb is None or not getattr(xb, "_backends", None):
         return None
-    try:
-        kind = str(mod.devices()[0].device_kind).lower()
-    except Exception:
-        return None
+    return str(mod.devices()[0].device_kind)
+
+
+def _tpu_generation(kind: str | None) -> str | None:
+    """``device_kind`` → the table's generation key, or None."""
+    kind = (kind or "").lower()
     if "v5 lite" in kind or "v5e" in kind or "v5litepod" in kind:
         return "tpu-v5e"
     if "v4" in kind:
@@ -109,18 +107,25 @@ def _tpu_generation() -> str | None:
 
 def peak_spec(platform: str | None = None) -> PeakSpec:
     """Resolve the :class:`PeakSpec` for ``platform`` (default: the
-    live ``device_platform()``), applying the documented resolution
-    order. Never raises: anything unrecognized (including the
-    jax-absent ``"none"``/``"uninitialized"`` states) lands on the CPU
-    fallback row."""
+    live ``device_platform()``): a table key as-is, a bare ``"tpu"``
+    through the live ``device_kind``. A device that is not in the table
+    is an error, not a default — a roofline or an MFU priced against
+    another part's peaks is a wrong number under the right name."""
     from .profile import device_platform
     key = (platform or device_platform() or "").strip().lower()
     spec = PEAK_SPECS.get(key)
-    if spec is None and (key == "tpu" or key.startswith("tpu")):
-        spec = PEAK_SPECS.get(_tpu_generation() or _TPU_DEFAULT) \
-            or PEAK_SPECS[_TPU_DEFAULT]
+    if spec is None and key.startswith("tpu"):
+        kind = _device_kind()
+        gen = _tpu_generation(kind)
+        if gen is None:
+            raise LookupError(
+                f"no PeakSpec row for TPU device_kind {kind!r}; add its "
+                "published peaks to obs.attribution.PEAK_SPECS")
+        spec = PEAK_SPECS[gen]
     if spec is None:
-        spec = PEAK_SPECS[_FALLBACK]
+        raise LookupError(
+            f"no PeakSpec row for platform {key!r} (known: "
+            f"{sorted(PEAK_SPECS)})")
     flops_env = os.environ.get(ENV_PEAK_FLOPS)
     bytes_env = os.environ.get(ENV_PEAK_BYTES)
     try:
@@ -131,6 +136,32 @@ def peak_spec(platform: str | None = None) -> PeakSpec:
     except (TypeError, ValueError):
         pass  # a junk override must not take the metrics plane down
     return spec
+
+
+_m_peak_missing = _registry.counter(
+    "profile_peak_spec_missing_total",
+    "roofline/MFU exports skipped because the device has no PeakSpec "
+    "row, by platform")
+_warned_missing: set = set()
+
+
+def telemetry_peak_spec(platform: str | None = None) -> PeakSpec | None:
+    """:func:`peak_spec` for the telemetry sinks on serving and build
+    paths (``CostAttribution.record_program``, ``StepProfiler.step``):
+    a device without a row is counted, logged once, and answered with
+    None — the sink then skips what it would have priced. A missing row
+    never prices a number against another part's peaks, and never stops
+    a warm-up, an AOT build or a served request either."""
+    try:
+        return peak_spec(platform)
+    except LookupError as e:
+        from .profile import device_platform
+        key = platform or device_platform() or "unknown"
+        _m_peak_missing.inc(1, platform=key)
+        if key not in _warned_missing:
+            _warned_missing.add(key)
+            _LOG.warning("%s — roofline and MFU gauges are skipped", e)
+        return None
 
 
 class CostAttribution:
@@ -159,31 +190,33 @@ class CostAttribution:
         """Record one compiled program's analytic cost and export its
         roofline placement against the resolved :class:`PeakSpec`.
         Returns the stored info dict (also what ``meta.json`` and the
-        bench bank)."""
-        spec = peak_spec(platform)
+        bench bank). On a device without a PeakSpec row the flops and
+        bytes are still recorded and exported; the placement fields
+        are None and no roofline gauge is set."""
+        spec = telemetry_peak_spec(platform)
         flops = max(float(flops), 0.0)
         bytes_ = max(float(bytes_), 0.0)
-        t_compute = flops / spec.peak_flops
-        t_memory = bytes_ / spec.hbm_bytes_per_s
-        critical = max(t_compute, t_memory, 1e-18)
-        bound = "compute" if t_compute >= t_memory else "memory"
         self._g_flops.set(flops, program=program)
         self._g_bytes.set(bytes_, program=program)
-        self._g_roofline.set(t_compute / critical, program=program,
-                             bound="compute")
-        self._g_roofline.set(t_memory / critical, program=program,
-                             bound="memory")
-        info = {
-            "program": program,
-            "service": service,
-            "platform": spec.platform,
-            "flops": flops,
-            "bytes": bytes_,
-            "bound": bound,
-            "roofline_seconds": spec.roofline_seconds(flops, bytes_),
-            "compute_seconds": t_compute,
-            "memory_seconds": t_memory,
-        }
+        info = {"program": program, "service": service,
+                "flops": flops, "bytes": bytes_}
+        if spec is None:
+            info.update(platform=platform, bound=None,
+                        roofline_seconds=None, compute_seconds=None,
+                        memory_seconds=None)
+        else:
+            t_compute = flops / spec.peak_flops
+            t_memory = bytes_ / spec.hbm_bytes_per_s
+            critical = max(t_compute, t_memory, 1e-18)
+            self._g_roofline.set(t_compute / critical, program=program,
+                                 bound="compute")
+            self._g_roofline.set(t_memory / critical, program=program,
+                                 bound="memory")
+            info.update(
+                platform=spec.platform,
+                bound="compute" if t_compute >= t_memory else "memory",
+                roofline_seconds=spec.roofline_seconds(flops, bytes_),
+                compute_seconds=t_compute, memory_seconds=t_memory)
         with self._lock:
             self._costs[program] = info
         return info

@@ -16,9 +16,9 @@ trace files to rotate):
   delta (dispatch returns as soon as XLA enqueues; the remainder until
   the sync completes is device/transfer time). This generalizes
   bench.py's MFU accounting into an always-on gauge: pass ``flops`` and
-  ``profile_mfu{stage=...}`` updates per step. The ~64 ms contended
-  dispatch RTT in BENCH_TPU_BANKED.json is exactly what this surface
-  makes visible per stage, continuously.
+  ``profile_mfu{stage=...}`` updates per step. A dispatch that costs
+  more than the step is exactly what this surface makes visible per
+  stage, continuously.
 
 - :class:`FeatureLog` — a bounded structured log appending one record
   per served request (route, batch/bucket, dtype/shapes when known,
@@ -43,7 +43,7 @@ import sys
 import threading
 import time
 
-from .attribution import PEAK_SPECS, peak_spec
+from .attribution import PEAK_SPECS, peak_spec, telemetry_peak_spec
 from .metrics import registry as _registry
 from .tracing import tracer as _tracer, wall_now
 
@@ -377,7 +377,10 @@ class StepProfiler:
             # absent on hosts whose devices report no memory stats)
             memory_profiler.segment_delta(
                 stage, mem0, memory_profiler.watermark())
-            if flops:
+            # telemetry inside a served step: a device without a
+            # PeakSpec row is counted and the gauge skipped, not raised
+            if flops and (self.peak_flops is not None
+                          or telemetry_peak_spec() is not None):
                 self.record_mfu(stage, flops, t2 - t0)
             dspan = self._tracer.emit_span(
                 "profile.dispatch", parent=parent, seconds=dispatch_s,

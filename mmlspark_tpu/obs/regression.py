@@ -4,11 +4,11 @@ Two halves, one contract — "slower than it was" is detected, not
 discovered in a postmortem:
 
 - **Offline trajectory gate** (``python -m mmlspark_tpu.obs.regression
-  compare OLD.json NEW.json`` / ``... gate [FILES...]``): diffs two
-  banked bench JSONs metric by metric, with the good/bad direction
+  compare OLD.json NEW.json`` / ``... gate FILES...``): diffs two
+  bench JSONs metric by metric, with the good/bad direction
   inferred from the metric name (images_per_sec up is good; _ms up is
-  bad) and a noise-aware tolerance — MAD over the full banked
-  ``BENCH_r0*`` trajectory when it is deep enough, a relative floor
+  bad) and a noise-aware tolerance — MAD over the whole given
+  trajectory when it is deep enough, a relative floor
   when it is not, plus an absolute floor for sub-millisecond latency
   jitter. Exit status is the verdict, so CI wires it straight in as
   the RegressionGate job.
@@ -31,7 +31,6 @@ for free.
 
 from __future__ import annotations
 
-import glob as _glob
 import json
 import re
 import sys
@@ -430,22 +429,17 @@ fleet_health.attach_sentinel(sentinel)
 # CLI
 
 
-def _default_trajectory() -> list:
-    return sorted(_glob.glob("BENCH_r0*.json") or
-                  _glob.glob("BENCH_r*.json"))
-
-
 def main(argv=None) -> int:
     """``compare OLD NEW [--history F...]`` diffs two runs; ``gate
-    [FILES...]`` diffs the newest banked run against its predecessor
-    with the whole trajectory pricing the noise. Exit 0 = pass, 1 =
-    regression, 2 = not enough data."""
+    FILES...`` diffs the last of the given runs against its
+    predecessor with the whole trajectory pricing the noise. Exit 0 =
+    pass, 1 = regression, 2 = not enough data."""
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] not in ("compare", "gate"):
         print("usage: python -m mmlspark_tpu.obs.regression "
               "compare OLD.json NEW.json [--history FILE...]\n"
               "       python -m mmlspark_tpu.obs.regression "
-              "gate [FILES...]", file=sys.stderr)
+              "gate FILES...", file=sys.stderr)
         return 2
     cmd, rest = argv[0], argv[1:]
     if cmd == "compare":
@@ -461,7 +455,7 @@ def main(argv=None) -> int:
         old_p, new_p = rest
         files = hist_files
     else:
-        files = rest or _default_trajectory()
+        files = rest
         if len(files) < 2:
             print(f"gate: need >= 2 trajectory files, got {len(files)}",
                   file=sys.stderr)

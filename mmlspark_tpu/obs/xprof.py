@@ -31,6 +31,7 @@ import io
 import json
 import os
 import sys
+import tempfile
 import threading
 import time
 import urllib.parse
@@ -65,7 +66,8 @@ class XprofCaptures:
     def __init__(self, root: str | None = None, registry=None):
         reg = registry if registry is not None else _registry
         self._root = root or os.environ.get(ENV_DIR) \
-            or os.path.join("/tmp", f"mmlspark_tpu_xprof_{os.getpid()}")
+            or os.path.join(tempfile.gettempdir(),
+                            f"mmlspark_tpu_xprof_{os.getpid()}")
         self._lock = threading.Lock()
         self._active: str | None = None
         self._seq = 0

@@ -1,13 +1,10 @@
-"""JAX API compatibility for the sharding layer.
+"""The sharding layer's single call sites into JAX.
 
-The framework targets current JAX, where ``shard_map`` is a top-level
-``jax.shard_map`` with a ``check_vma`` knob; on the previous API
-generation the same transform lives at
-``jax.experimental.shard_map.shard_map`` and the knob is ``check_rep``.
-Every in-repo call site goes through :func:`shard_map` so the version
-split is handled in exactly one place (the bake-what-you-have stance:
-no pip installs inside the image, so the code must run on the JAX it
-finds).
+Written for the one installation there is (JAX 0.9.0): no branch for an
+API generation that is not installed. Every in-repo use of
+``shard_map``, the sharding constraint, executable serialization and
+the Pallas TPU compiler params goes through here, so the next JAX
+upgrade is edited in exactly one place.
 
 JAX-free at module scope, like the rest of the package's light
 surface.
@@ -35,18 +32,11 @@ _m_cost_missing = _obs.counter(
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on
-    old, with ``check_vma``/``check_rep`` translated. ``check_vma=None``
-    means "library default" on either version."""
+    """``jax.shard_map``; ``check_vma=None`` means "library default"."""
     import jax
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def jit(fn=None, *, name: str | None = None, **jit_kwargs):
@@ -105,29 +95,13 @@ def cost_analysis(compiled) -> dict | None:
     return {"flops": flops, "bytes": bytes_}
 
 
-def aot_serialization_available() -> bool:
-    """Whether this JAX build can serialize compiled executables
-    (``jax.experimental.serialize_executable``). When False the AOT
-    store (``core/aot.py``) degrades to retrace-tier entries — still a
-    build-time cost, just paid per process at warm load."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return hasattr(serialize_executable, "serialize")
-    except ImportError:
-        return False
-
-
 def serialize_compiled(compiled) -> bytes:
     """``jax.stages.Compiled`` → one self-contained blob (payload +
-    pytree defs pickled together). Raises RuntimeError on JAX builds
-    without ``serialize_executable`` — the AOT store catches it and
-    writes a retrace-tier entry instead."""
+    pytree defs pickled together). A backend that refuses raises — the
+    AOT store catches it and writes a retrace-tier entry instead."""
     import pickle
-    try:
-        from jax.experimental.serialize_executable import serialize
-    except ImportError as e:
-        raise RuntimeError(
-            "this JAX build has no serialize_executable") from e
+
+    from jax.experimental.serialize_executable import serialize
     payload, in_tree, out_tree = serialize(compiled)
     return pickle.dumps((payload, in_tree, out_tree),
                         protocol=pickle.HIGHEST_PROTOCOL)
@@ -138,47 +112,29 @@ def deserialize_compiled(blob: bytes, backend=None):
     ``jax.stages.Compiled`` bound to ``backend`` (default: the
     process's default backend)."""
     import pickle
-    try:
-        from jax.experimental.serialize_executable import (
-            deserialize_and_load)
-    except ImportError as e:
-        raise RuntimeError(
-            "this JAX build has no serialize_executable") from e
+
+    from jax.experimental.serialize_executable import deserialize_and_load
     payload, in_tree, out_tree = pickle.loads(blob)
     return deserialize_and_load(payload, in_tree, out_tree,
                                 backend=backend)
 
 
 def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across the rename: ``CompilerParams``
-    on new JAX was ``TPUCompilerParams`` one generation back — same
-    fields, renamed class."""
+    """Pallas TPU compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def _context_mesh():
     """The physical mesh an enclosing ``with mesh:`` bound to this
-    thread, or None. The pjit resource env moved modules across JAX
-    generations; every read is guarded so API drift degrades to "no
-    context mesh" (a no-op constraint), never to an ImportError."""
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not getattr(m, "empty", True):
-            return m
-    except Exception:
-        pass
-    return None
+    thread, or None."""
+    from jax._src.mesh import thread_resources
+    m = thread_resources.env.physical_mesh
+    return None if m.empty else m
 
 
 def with_sharding_constraint(x, spec, mesh=None):
-    """One wrapper for the sharding-constraint API split (current JAX:
-    ``jax.lax.with_sharding_constraint``; the previous generation:
-    ``jax.experimental.pjit.with_sharding_constraint``) — the same
-    single-call-site contract :func:`shard_map` gives the other split.
+    """The one call site of ``jax.lax.with_sharding_constraint``.
     graftcheck's collective-audit flags raw constraint call sites
     outside ``parallel/``, so this is THE way model and train-step code
     annotates activations.
@@ -199,9 +155,7 @@ def with_sharding_constraint(x, spec, mesh=None):
     replicates loudly instead of failing the compile."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    fn = getattr(jax.lax, "with_sharding_constraint", None)
-    if fn is None:  # previous API generation
-        from jax.experimental.pjit import with_sharding_constraint as fn
+    fn = jax.lax.with_sharding_constraint
     if isinstance(spec, NamedSharding):
         return fn(x, spec)
     if mesh is None:
@@ -228,21 +182,11 @@ def with_sharding_constraint(x, spec, mesh=None):
 
 
 def make_array_from_process_local_data(sharding, local_data):
-    """Per-host feeding across the API generations: each process hands
-    its LOCAL rows and gets back one global array sharded per
-    ``sharding`` (current JAX: ``jax.make_array_from_process_local_
-    data``; older: ``multihost_utils.host_local_array_to_global_
-    array``). On a single-process mesh this degrades to a plain
-    ``device_put`` of the (already-global) data."""
+    """Per-host feeding: each process hands its LOCAL rows and gets
+    back one global array sharded per ``sharding``
+    (``jax.make_array_from_process_local_data``)."""
     import jax
-    fn = getattr(jax, "make_array_from_process_local_data", None)
-    if fn is not None:
-        return fn(sharding, local_data)
-    if jax.process_count() == 1:  # pragma: no cover - old-API fallback
-        return jax.device_put(local_data, sharding)
-    from jax.experimental import multihost_utils  # pragma: no cover
-    return multihost_utils.host_local_array_to_global_array(
-        local_data, sharding.mesh, sharding.spec)
+    return jax.make_array_from_process_local_data(sharding, local_data)
 
 
 def process_allgather(x, *, tiled: bool = False):
@@ -259,29 +203,19 @@ def process_allgather(x, *, tiled: bool = False):
     return np.asarray(multihost_utils.process_allgather(x, tiled=tiled))
 
 
-def enable_cpu_multiprocess_collectives() -> bool:
+def enable_cpu_multiprocess_collectives() -> None:
     """Switch the CPU backend's collectives to the gloo implementation
     — REQUIRED before ``jax.distributed.initialize`` on a multi-process
     CPU (DCN-style) run: without it initialization succeeds but the
     first cross-process execution fails with "Multiprocess computations
-    aren't implemented on the CPU backend". Returns whether the config
-    took (False on JAX builds without the knob, e.g. TPU-only)."""
+    aren't implemented on the CPU backend"."""
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except Exception:
-        return False
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def axis_size(axis) -> int:
-    """STATIC size of a named mesh axis from inside shard_map/pjit.
-
-    ``jax.lax.axis_size`` on new JAX; on old JAX the classic
-    ``psum(1, axis)`` trick — a psum of a concrete Python scalar is
-    evaluated at trace time, so the result is a real int either way
-    (ring permutation tables and loop bounds need it concrete)."""
+    """STATIC size of a named mesh axis from inside shard_map/pjit
+    (``jax.lax.axis_size``): a real int at trace time — ring
+    permutation tables and loop bounds need it concrete."""
     import jax
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)

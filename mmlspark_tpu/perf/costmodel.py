@@ -46,6 +46,7 @@ import json
 import logging
 import math
 import os
+import tempfile
 import threading
 
 import numpy as np
@@ -63,8 +64,9 @@ __all__ = ["CostModel", "shared_cost_model", "enabled", "perf_root",
 #: params + autotune winner registry). Per-user for the same reason as
 #: the AOT store: a shared /tmp path would let any local user plant
 #: parameters another user's server boot would trust.
-DEFAULT_PERF_ROOT = "/tmp/mmlspark_tpu_perf-" + str(
-    getattr(os, "getuid", lambda: "u")())
+DEFAULT_PERF_ROOT = os.path.join(
+    tempfile.gettempdir(), "mmlspark_tpu_perf-" + str(
+        getattr(os, "getuid", lambda: "u")()))
 
 #: the model's feature vector (after the intercept); per-key training
 #: means fill features the caller cannot supply at estimate time. The

@@ -2174,7 +2174,7 @@ def llm_decode_scenario(*, service: str = "llm-decode-bench",
     from ..dl.text_encoder import make_attention_fn
     from ..obs.metrics import registry as _default
     from ..obs.profile import compile_tracker
-    from ..serving.llm import LLMEngine, _bucket_window
+    from ..serving.llm import LLMEngine
 
     import jax
 
@@ -2193,8 +2193,9 @@ def llm_decode_scenario(*, service: str = "llm-decode-bench",
     engine = LLMEngine(module, variables, slots=slots,
                        block_len=block_len, max_seq_len=context_tokens,
                        service=service, registry=reg)
-    windows = sorted({_bucket_window(prompt_len), 1})
-    fps = engine.warm(prefill_windows=tuple(windows), mark_steady=True)
+    # the prompt is wider than the kernel's widest window: warm() takes
+    # its length and compiles every chunk window it will be fed through
+    fps = engine.warm(prefill_windows=(prompt_len, 1), mark_steady=True)
     try:
         engine.submit("ctx0", prompt, max_new_tokens)
         engine.step()            # admit + prefill + first decode step

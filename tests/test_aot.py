@@ -17,7 +17,7 @@ import pytest
 from mmlspark_tpu.core import DataFrame, compile_pipeline
 from mmlspark_tpu.core import aot
 from mmlspark_tpu.core.aot import AotStore
-from mmlspark_tpu.core.utils import scrubbed_cpu_env
+from mmlspark_tpu.core.utils import cpu_child_env
 from mmlspark_tpu.obs.metrics import registry as _reg
 from mmlspark_tpu.obs.profile import compile_tracker
 
@@ -114,7 +114,7 @@ class TestFingerprints:
         out = subprocess.run(
             [sys.executable, "-c", NO_JAX_FP_SNIPPET],
             capture_output=True, text=True, cwd=REPO,
-            env=scrubbed_cpu_env(), check=True)
+            env=cpu_child_env(), check=True)
         child = tuple(json.loads(out.stdout.strip()))
         assert child == self._fp_here()
 
@@ -397,20 +397,46 @@ class TestServingIntegration:
         finally:
             aot._BUILDERS.pop("aot-build-test", None)
 
-    def test_scrubbed_env_cache_dir_contract(self, monkeypatch):
-        # explicit operator override wins
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/mine")
-        assert scrubbed_cpu_env()["JAX_COMPILATION_CACHE_DIR"] \
-            == "/tmp/mine"
-        # AOT store root co-locates the jax cache
+    # the compile cache is placed from outside: two rules, not three
+    def test_cache_dir_set_from_outside_stands(self, monkeypatch):
+        import jax
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        # the AOT store root no longer moves the jax cache
+        monkeypatch.setenv("MMLSPARK_TPU_AOT_STORE", "/tmp/aotroot")
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append(k))
+        assert aot.place_jax_cache() == "/some/dir"
+        assert aot.place_jax_cache("tests") == "/some/dir"
+        assert "jax_compilation_cache_dir" not in updates
+        assert cpu_child_env()["JAX_COMPILATION_CACHE_DIR"] \
+            == "/some/dir"
+
+    def test_cache_dir_unset_is_fixed_path_in_checkout(self, monkeypatch):
+        import jax
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("MMLSPARK_TPU_AOT_STORE", "/tmp/aotroot")
-        assert scrubbed_cpu_env()["JAX_COMPILATION_CACHE_DIR"] \
-            == os.path.join("/tmp/aotroot", "jax_cache")
-        # neither set → the historical default
-        monkeypatch.delenv("MMLSPARK_TPU_AOT_STORE", raising=False)
-        assert scrubbed_cpu_env()["JAX_COMPILATION_CACHE_DIR"] \
-            == "/tmp/mmlspark_tpu_jax_cache"
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert aot.jax_cache_dir() == os.path.join(repo, ".jax_cache")
+        assert cpu_child_env()["JAX_COMPILATION_CACHE_DIR"] \
+            == os.path.join(repo, ".jax_cache", "cpu")
+        updates = {}
+        monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+        assert aot.place_jax_cache("tests") == \
+            os.path.join(repo, ".jax_cache", "tests")
+        assert updates == {"jax_compilation_cache_dir":
+                           os.path.join(repo, ".jax_cache", "tests")}
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    def test_cache_dir_is_the_same_on_every_call(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first = aot.jax_cache_dir("tests")
+        assert aot.jax_cache_dir("tests") == first
+        # never under the temp dir, never from a pid or the time
+        import tempfile
+        assert not first.startswith(tempfile.gettempdir() + os.sep)
+        assert str(os.getpid()) not in os.path.basename(first)
 
 
 # ------------------------------------------------------- scale-up scenario
@@ -438,18 +464,18 @@ class TestScaleUpScenario:
 @pytest.mark.slow
 class TestCli:
     def test_selftest_round_trip(self):
-        """build in one scrubbed process, verify (warm-load + zero
+        """build in one CPU-pinned process, verify (warm-load + zero
         runtime compiles + bit-equal) in another — the CI job's body."""
         out = subprocess.run(
             [sys.executable, "-m", "mmlspark_tpu.core.aot", "selftest"],
             capture_output=True, text=True, cwd=REPO,
-            env=scrubbed_cpu_env(), timeout=600)
+            env=cpu_child_env(), timeout=600)
         assert out.returncode == 0, out.stdout + out.stderr
         assert "selftest OK" in out.stdout
 
     def test_list_and_gc_cli(self, tmp_path):
         root = str(tmp_path / "store")
-        env = scrubbed_cpu_env()
+        env = cpu_child_env()
         out = subprocess.run(
             [sys.executable, "-m", "mmlspark_tpu.core.aot", "build",
              "--service", "__selftest__", "--root", root],

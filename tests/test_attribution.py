@@ -53,14 +53,20 @@ class TestPeakSpec:
             PEAK_SPECS["tpu-v4"].hbm_bytes_per_s
         assert peak_spec("cpu").platform == "cpu"
 
-    def test_unknown_platform_falls_back_to_cpu(self):
-        assert peak_spec("riscv-accel").platform == "cpu"
-        assert peak_spec("").platform in PEAK_SPECS
+    def test_unknown_platform_raises(self):
+        # the cpu row never stands in for a device without a row
+        with pytest.raises(LookupError, match="riscv-accel"):
+            peak_spec("riscv-accel")
+        assert peak_spec("").platform in PEAK_SPECS   # live: cpu
 
-    def test_tpu_family_defaults_to_v5e(self):
-        # a bare "tpu" platform string (no readable generation in a
-        # CPU test process) resolves to the fleet's default part
+    def test_tpu_resolves_through_device_kind(self, monkeypatch):
+        monkeypatch.setattr(attr_mod, "_device_kind",
+                            lambda: "TPU v5 lite")
         assert peak_spec("tpu").platform == "tpu-v5e"
+        # a generation the table has no row for is an error, never v5e
+        monkeypatch.setattr(attr_mod, "_device_kind", lambda: "TPU v9x")
+        with pytest.raises(LookupError, match="TPU v9x"):
+            peak_spec("tpu")
 
     def test_env_overrides_win_over_table(self, monkeypatch):
         monkeypatch.setenv(attr_mod.ENV_PEAK_FLOPS, "5e12")
@@ -113,6 +119,36 @@ class TestCostAttribution:
             ca.record_program(f"p{i}", f, b, platform="cpu")
             for v in _roofline(reg, f"p{i}").values():
                 assert v <= 1.0
+
+    def test_device_without_a_row_is_counted_not_raised(self,
+                                                       monkeypatch):
+        """``peak_spec`` raises for a part the table does not know;
+        the telemetry sinks on serving and build paths count it and
+        skip the roofline / MFU gauges instead of stopping a warm-up,
+        an AOT build or a served step."""
+        from mmlspark_tpu.obs.metrics import registry as default_reg
+        from mmlspark_tpu.obs.profile import StepProfiler
+        monkeypatch.setattr(attr_mod, "_device_kind", lambda: "TPU v9x")
+        missing = 'profile_peak_spec_missing_total{platform="tpu"}'
+        before = default_reg.snapshot().get(missing, 0.0)
+        reg = _reg()
+        info = CostAttribution(registry=reg).record_program(
+            "p_v9x", 1e9, 1e3, service="svc", platform="tpu")
+        assert (info["flops"], info["bytes"]) == (1e9, 1e3)
+        assert info["bound"] is None and info["roofline_seconds"] is None
+        assert reg.snapshot()[
+            'profile_analytic_flops{program="p_v9x"}'] == 1e9
+        assert _roofline(reg, "p_v9x") == {}
+        assert default_reg.snapshot()[missing] == before + 1
+        # a served step on that part: timed and counted, no MFU gauge
+        monkeypatch.setattr("mmlspark_tpu.obs.profile.telemetry_peak_spec",
+                            lambda: attr_mod.telemetry_peak_spec("tpu"))
+        sreg = _reg()
+        with StepProfiler(registry=sreg).step("v9x_stage", flops=1e9):
+            pass
+        snap = sreg.snapshot()
+        assert any(k.startswith("profile_steps_total") for k in snap)
+        assert not any(k.startswith("profile_mfu") for k in snap)
 
     def test_analytic_gauges_and_service_sums(self):
         reg = _reg()

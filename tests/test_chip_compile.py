@@ -1,0 +1,288 @@
+"""Compile the main path's Pallas kernels, and the two whole programs
+that carry them, for the real chip — described (``v5e:2x2``), not
+attached. The TPU's compiler is installed here, so a slice that is not
+aligned to the tiling, a kernel that asks for too much fast memory or a
+program that does not fit HBM is refused here at no chip time. Nothing
+runs: this says nothing about results or speed (``chip_smoke.py`` does,
+on the chip).
+
+The only file that describes the chip. The topology is described inside
+a fixture, after a test of this file has started — never at import — so
+that every xdist worker collects the same tests and only the worker that
+is given this file loads the TPU's library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one: keep the
+    # cache off around these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def steer_tpu(monkeypatch):
+    """Whole programs ask ``target_platform()`` which path to build, and
+    here it answers ``cpu``: steer it from the test (not through an
+    option of the program) so that the chip's path is what is lowered."""
+    import mmlspark_tpu.dl.pallas_paged_attention as paged
+    import mmlspark_tpu.utils.platform as plat
+    monkeypatch.setattr(plat, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(paged, "target_platform", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    import jax
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert KERNEL in text, "no Mosaic kernel in the compiled program"
+    return compiled, text
+
+
+@pytest.mark.parametrize("n,F,B", [(500_000, 28, 256), (4097, 5, 16)],
+                         ids=["higgs-500kx28-b256", "ragged-4097x5-b16"])
+def test_hist_pallas(one_chip, n, F, B):
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.lightgbm.pallas_hist import hist_pallas
+    _compile(lambda b, v: hist_pallas(b, v, num_bins=B, interpret=False),
+             _sds((n, F), jnp.uint8, one_chip),
+             _sds((n, 3), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("mode", ["forward", "causal", "grad-pallas"])
+def test_flash_attention(one_chip, mode):
+    """B8 H8 T2048 D64 bf16 — the encoder phase's attention shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_attention import flash_attention
+    qkv = [_sds((8, 8, 2048, 64), jnp.bfloat16, one_chip)] * 3
+    if mode == "grad-pallas":
+        def fn(q, k, v):
+            return jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, interpret=False, bwd_impl="pallas").astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    else:
+        def fn(q, k, v):
+            return flash_attention(q, k, v, interpret=False,
+                                   causal=mode == "causal")
+    _, text = _compile(fn, *qkv)
+    if mode == "grad-pallas":   # forward + the fused dq and dkv kernels
+        assert text.count(KERNEL) >= 3
+
+
+@pytest.mark.parametrize("block_len", [8, 16])
+@pytest.mark.parametrize("window", [1, 4, 256],
+                         ids=["decode", "window4", "prefill256"])
+def test_paged_attention(one_chip, block_len, window):
+    """``bench_gen`` width (8 heads of 64), 512 positions per slot, a
+    pool of 4096 blocks; ``window`` 1 is ``paged_attention``, 4 the
+    speculative-verify ``paged_window_attention``, 256 the widest
+    prefill window ``chip_smoke.py`` asks for."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_paged_attention import (
+        paged_attention, paged_window_attention)
+    S, H, hd, blocks = 8, 8, 64, 4096
+    pool = _sds((blocks, block_len, H, hd), jnp.bfloat16, one_chip)
+    rows = _sds((S, 512 // block_len), jnp.int32, one_chip)
+    pos = _sds((S,), jnp.int32, one_chip)
+    if window == 1:
+        q = _sds((S, H, hd), jnp.bfloat16, one_chip)
+        fn = paged_attention
+    else:
+        q = _sds((S, H, window, hd), jnp.bfloat16, one_chip)
+        fn = paged_window_attention
+    _compile(lambda q, k, v, r, p: fn(q, k, v, r, p, impl="pallas",
+                                      interpret=False),
+             q, pool, pool, rows, pos)
+
+
+@pytest.mark.parametrize("H,hd,dtype,block_len", [
+    (8, 64, "bfloat16", 16), (2, 16, "float32", 128),
+    (32, 128, "bfloat16", 16), (16, 256, "float32", 32)],
+    ids=["bench_gen", "toy-f32", "32x128", "16x256-f32"])
+def test_widest_prefill_window(one_chip, H, hd, dtype, block_len):
+    """A window is held whole in fast memory (at 4096 rows the chip's
+    compiler refuses the kernel even for 2 heads of 16), so prefill
+    chunks a long suffix at ``max_window``: that width compiles."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_paged_attention import (
+        max_window, paged_window_attention)
+    dtype = jnp.dtype(dtype)
+    w = max_window(H, hd, dtype)
+    assert w >= 64
+    pool = _sds((1024, block_len, H, hd), dtype, one_chip)
+    _compile(lambda q, k, v, r, p: paged_window_attention(
+        q, k, v, r, p, impl="pallas", interpret=False),
+        _sds((4, H, w, hd), dtype, one_chip), pool, pool,
+        _sds((4, 4096 // block_len), jnp.int32, one_chip),
+        _sds((4,), jnp.int32, one_chip))
+
+
+#: free HBM the chip's allocator reported to the first engine built on
+#: it (bytes_limit 16.91 GB less 0.24 GB of weights; my chip run, PR 23)
+_FREE_HBM = 16_670_000_000
+
+
+@pytest.mark.parametrize("config", ["bench_gen", "toy-serving",
+                                    "toy-serving-spec", "toy-decode"])
+def test_llm_engine_programs(one_chip, steer_tpu, config):
+    """The engine's decode program and its widest prefill program, pools
+    donated and sized as the engine sizes them on the chip: half of
+    free HBM at ``pool_block_bytes``. ``bench_gen`` is ``chip_smoke.py``'s
+    engine; the toys (1 layer, 2 heads of 16, float32) are ``bench.py``'s
+    two scenarios, whose narrow blocks XLA pads 8x around the kernel —
+    each program's arguments and temporaries must stay inside the half
+    the pools were given."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl import MaskedLMModel, TextEncoder
+    from mmlspark_tpu.dl.paged_kv import pool_block_bytes
+    from mmlspark_tpu.dl.text_encoder import make_attention_fn
+    from mmlspark_tpu.obs.metrics import MetricsRegistry
+    from mmlspark_tpu.serving.llm import LLMEngine
+
+    attn = make_attention_fn("dense", causal=True)
+    if config == "bench_gen":
+        enc = TextEncoder(vocab=32768, width=512, depth=8, heads=8,
+                          mlp_dim=2048, attention_fn=attn)
+        kw, longest = dict(slots=8, block_len=16, max_seq_len=512,
+                           prefill_batch=4), 256
+    else:
+        enc = TextEncoder(vocab=64, width=32, depth=1, heads=2,
+                          mlp_dim=64, dtype=jnp.float32,
+                          attention_fn=attn)
+        kw, longest = {
+            "toy-serving": (dict(slots=2, block_len=4, max_seq_len=22),
+                            16),
+            "toy-serving-spec": (dict(slots=2, block_len=4, spec_k=2,
+                                      max_seq_len=22), 16),
+            "toy-decode": (dict(slots=1, block_len=128,
+                                max_seq_len=4096), 4064)}[config]
+    module = MaskedLMModel(enc)
+    spec = bool(kw.get("spec_k"))
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), shapes["params"])
+    # the engine only closes over the module; weights and pools are
+    # arguments, so tiny stand-ins build the same programs
+    engine = LLMEngine(module, {"params": None},
+                       draft_module=module if spec else None,
+                       draft_variables={"params": None} if spec else None,
+                       num_blocks=4, registry=MetricsRegistry(),
+                       service="chip-compile", **kw)
+    budget = _FREE_HBM // 2
+    num_blocks = budget // (pool_block_bytes(enc, engine.block_len)
+                            * (2 if spec else 1))
+    pools = jax.tree.map(
+        lambda a: _sds((num_blocks,) + a.shape[1:], a.dtype, one_chip),
+        engine.pools.target)
+    draft = (params, pools) if spec else (None, None)
+    S, MB, P = engine.decoder.slots, engine.max_blocks, \
+        engine.prefiller.batch
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    w = max(engine.prefiller.windows_for(longest))
+    programs = {
+        "decode": engine.decoder._build().lower(
+            params, draft[0], pools, draft[1], i32(S, MB), i32(S),
+            i32(S), i32(S), _sds((S,), jnp.bool_, one_chip)),
+        f"prefill_w{w}": engine.prefiller._program(w).lower(
+            params, draft[0], pools, draft[1], i32(P, MB), i32(P, w),
+            i32(P), i32(P))}
+    at_rest = sum(np.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(pools)) * (2 if spec else 1)
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        assert compiled.as_text().count(KERNEL) >= enc.depth, name
+        mem = compiled.memory_analysis()
+        # donated: the pools come back in the buffers they arrived in
+        assert mem.alias_size_in_bytes >= at_rest, name
+        held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
+        assert held - weights <= budget, (name, held, budget)
+
+
+def test_gbdt_boosting_step(one_chip, steer_tpu, monkeypatch):
+    """The fused boosting step (gradients, growth with the histogram
+    kernel under ``lax.cond``, score update) for 500k x 28."""
+    import jax
+
+    import mmlspark_tpu.lightgbm.pallas_hist as pallas_hist
+    import mmlspark_tpu.lightgbm.trainer as trainer
+    from mmlspark_tpu.core import DataFrame
+    from mmlspark_tpu.lightgbm import LightGBMClassifier
+
+    # a tiny CPU fit (scatter path) hands over the step's statics and
+    # the pytree of its arguments
+    captured = {}
+    build = trainer._fused_cached
+
+    def spy(st):
+        step, chunk = build(st)
+
+        def wrapped(*args):
+            captured.setdefault("step", (st, args))
+            return step(*args)
+        return wrapped, chunk
+
+    n0 = 512
+    rng = np.random.default_rng(7)
+    feats = rng.normal(size=(n0, 28)).astype(np.float32)
+    labels = (feats[:, :4].sum(1) > 0).astype(np.float32)
+    with monkeypatch.context() as m:
+        m.setattr(trainer, "_fused_cached", spy)
+        m.setattr(pallas_hist, "use_pallas_hist", lambda: False)
+        LightGBMClassifier(numIterations=2, numLeaves=31, numShards=1,
+                           learningRate=0.1).fit(
+            DataFrame({"features": feats, "label": labels}))
+    st, args = captured["step"]
+    assert st.n == n0 and pallas_hist.use_pallas_hist()   # steered: tpu
+
+    n = 500_000
+    args = jax.tree.map(
+        lambda a: _sds([n if d == n0 else d for d in np.shape(a)],
+                       a.dtype, one_chip), args)
+    step, _ = trainer._build_fused(st._replace(n=n))
+    assert KERNEL in step.lower(*args).compile().as_text()

@@ -1,4 +1,4 @@
-"""Streaming speech SDK + Azure Search index management (VERDICT r1
+"""Streaming speech SDK + Azure Search index management (review round 1
 item 9) against local mock services: pull-audio reads, VAD utterance
 segmentation, partial-result assembly, conversation transcription
 speaker attribution, and the index management API."""

@@ -1,4 +1,4 @@
-"""Checkpoint conversion + verified weights (VERDICT r1 item 5).
+"""Checkpoint conversion + verified weights (review round 1 item 5).
 
 torch (CPU) is the numerical oracle: a state_dict in exact torchvision
 naming/layout converts to our flax ResNet and must produce the same

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from mmlspark_tpu.core.aot import AotStore
-from mmlspark_tpu.core.utils import scrubbed_cpu_env
+from mmlspark_tpu.core.utils import cpu_child_env
 from mmlspark_tpu.obs.metrics import MetricsRegistry
 from mmlspark_tpu.obs.metrics import registry as _process_reg
 from mmlspark_tpu.resilience import FaultRule, faults, injector
@@ -441,7 +441,7 @@ class TestAotGcProtection:
         mreg.register("v2", static_fps=("b" * 64,))
         _fake_entry(store, "1", "a")
         _fake_entry(store, "2", "b")
-        env = scrubbed_cpu_env()
+        env = cpu_child_env()
         out = subprocess.run(
             [sys.executable, "-m", "mmlspark_tpu.core.aot", "list",
              "--root", root],
@@ -556,6 +556,6 @@ def test_deploy_plane_imports_without_jax():
         "print('deploy plane OK (no jax)')\n")
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        cwd=REPO, env=scrubbed_cpu_env(), timeout=600)
+        cwd=REPO, env=cpu_child_env(), timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "deploy plane OK (no jax)" in out.stdout

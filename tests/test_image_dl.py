@@ -298,9 +298,8 @@ class TestIO:
 
 
 def test_tpumodel_caches_jitted_apply():
-    """Repeated transforms must not retrace/recompile (through a remote
-    compiler that is the whole latency budget): one jit trace serves
-    every transform of the same model."""
+    """Repeated transforms must not retrace/recompile: one jit trace
+    serves every transform of the same model."""
     count = {"n": 0}
 
     class Counting(ResNet):

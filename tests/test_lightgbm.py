@@ -257,7 +257,7 @@ def test_missing_values_handled():
 
 
 def test_hot_loop_no_bulk_host_pulls():
-    """De-synced boosting loop (VERDICT r1 weak #5): GOSS sampling and the
+    """De-synced boosting loop (review round 1 weak #5): GOSS sampling and the
     auc/rmse eval metrics run on device, so no O(n) device->host copy
     happens inside the iteration loop, and eval_freq thins the scalar
     reads."""

@@ -1,4 +1,4 @@
-"""Cross-implementation LightGBM text-format checks (VERDICT r1 item 7).
+"""Cross-implementation LightGBM text-format checks (review round 1 item 7).
 
 Round 1 only round-tripped our own writer through our own reader. Two
 independent anchors close that loop:

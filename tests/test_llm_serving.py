@@ -149,6 +149,104 @@ class TestGreedyIdentity:
         np.testing.assert_array_equal(got[0], ref[0][:len(p) + 1])
 
 
+class TestChunkedPrefill:
+    """A suffix wider than the kernel's widest window is fed in
+    ``max_window`` chunks (the width is VMEM-bound on the chip; here it
+    is steered down to 8 so that tiny prompts chunk)."""
+
+    @pytest.fixture
+    def narrow(self, monkeypatch):
+        import mmlspark_tpu.dl.pallas_paged_attention as paged
+        monkeypatch.setattr(paged, "max_window", lambda *a: 8)
+
+    @pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "spec"])
+    def test_chunked_matches_generate(self, lm, draft_lm, narrow,
+                                      spec_k):
+        module, variables = lm
+        # 1, 2 and 3+ chunks in one batch, plus an exact multiple
+        prompts = _prompts(seed=17, sizes=(5, 13, 21, 16))
+        ref = _ref(lm, prompts)
+        draft = dict(draft_module=draft_lm[0],
+                     draft_variables=draft_lm[1]) if spec_k else {}
+        eng = LLMEngine(module, variables, slots=2, block_len=4,
+                        max_seq_len=32, prefill_batch=4, spec_k=spec_k,
+                        registry=MetricsRegistry(), **draft)
+        assert eng.prefiller.max_window == 8
+        assert eng.prefiller.windows_for(21) == [8, 8, 8]
+        assert eng.prefiller.windows_for(16) == [8, 8]
+        for i, p in enumerate(prompts):
+            eng.submit(i, p, MAXNEW)
+        got = eng.run_until_drained()
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(got[i],
+                                          ref[i][:len(p) + MAXNEW])
+        # no window wider than the cap was ever built
+        assert max(eng.prefiller._programs) <= 8
+
+    def test_chunk_after_reused_prefix_and_steady_state(self, lm,
+                                                        narrow):
+        module, variables = lm
+        p = _prompts(seed=19, sizes=(27,))[0]
+        ref = _ref(lm, [p])
+        eng = LLMEngine(module, variables, slots=1, block_len=4,
+                        max_seq_len=32, service="llmchunk",
+                        registry=MetricsRegistry())
+        # warm() takes suffix LENGTHS: 27 -> 8, 8, 8 and the bucket of
+        # 3; the warm repeat re-feeds 27 - 24 reused = 3 tokens
+        fps = eng.warm(prefill_windows=(27, 1), mark_steady=True)
+        try:
+            eng.submit("cold", p, MAXNEW)
+            cold = eng.run_until_drained()["cold"]
+            eng.submit("warm", p, MAXNEW)
+            warm = eng.run_until_drained()["warm"]
+            compile_tracker.assert_steady_state()
+        finally:
+            compile_tracker.unmark_steady()
+        np.testing.assert_array_equal(cold, ref[0][:len(p) + MAXNEW])
+        np.testing.assert_array_equal(warm, cold)
+        assert {n for n in fps if "prefill" in n} == {
+            "llm_prefill_llmchunk_w1_b2", "llm_prefill_llmchunk_w4_b2",
+            "llm_prefill_llmchunk_w8_b2"}
+
+
+class TestPoolSizing:
+    def test_block_priced_in_the_kernels_tiled_layout(self):
+        from mmlspark_tpu.dl.paged_kv import pool_block_bytes
+
+        def price(heads, hd, dtype, depth=8, block_len=16):
+            return pool_block_bytes(TextEncoder(
+                vocab=8, width=heads * hd, depth=depth, heads=heads,
+                mlp_dim=8, dtype=dtype), block_len)
+        # 2*depth arrays at rest + 4 in flight around the kernel, each
+        # [block_len, heads, 128 lanes]
+        assert price(8, 128, jnp.bfloat16) == 20 * 16 * 8 * 128 * 2
+        # 64 lanes pad to 128; 2 heads of 16 in f32 cost 8x logical
+        assert price(8, 64, jnp.bfloat16) == price(8, 128, jnp.bfloat16)
+        assert price(2, 16, jnp.float32, depth=1, block_len=4) == \
+            6 * 8 * (4 * 2 * 16 * 4)
+        # the row dim rounds to the dtype's sublane tile once past it
+        assert price(20, 128, jnp.bfloat16) == \
+            price(32, 128, jnp.bfloat16)
+
+    def test_target_and_draft_share_one_fraction(self, lm, monkeypatch):
+        import mmlspark_tpu.obs.memory as memory
+        from mmlspark_tpu.dl.paged_kv import pool_block_bytes
+        module, variables = lm
+        free = 1 << 22
+        monkeypatch.setattr(
+            memory, "device_memory_stats",
+            lambda: [{"bytes_limit": free + 1000, "bytes_in_use": 1000}])
+        kw = dict(slots=1, block_len=4, max_seq_len=16,
+                  registry=MetricsRegistry())
+        one = pool_block_bytes(module.encoder, 4)
+        plain = LLMEngine(module, variables, **kw)
+        spec = LLMEngine(module, variables, draft_module=module,
+                         draft_variables=variables, spec_k=1, **kw)
+        assert plain.kv.num_blocks == free // 2 // one
+        # both models' pools together stay inside the same half
+        assert spec.kv.num_blocks == free // 2 // (2 * one)
+
+
 class TestPrefixReuseAndTTFT:
     def test_repeated_prefix_hits_and_ttft_split(self, lm):
         module, variables = lm

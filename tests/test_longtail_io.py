@@ -1,4 +1,4 @@
-"""Long-tail coverage gaps (VERDICT r1 table #7/#33/#55/#57): port
+"""Long-tail coverage gaps (review round 1 table #7/#33/#55/#57): port
 forwarding, dataclass↔row codecs + categorical metadata, R binding
 generation, streaming file/image source."""
 

@@ -218,6 +218,7 @@ class TestModelRuleSets:
 
     def test_registry_covers_the_zoo(self):
         # registration happens at model-definition import time
+        import mmlspark_tpu.dl.bert           # noqa: F401
         import mmlspark_tpu.dl.pretrain       # noqa: F401
         import mmlspark_tpu.models.resnet     # noqa: F401
         import mmlspark_tpu.models.vit        # noqa: F401

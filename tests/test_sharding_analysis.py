@@ -1,4 +1,4 @@
-"""Sharding is MEASURED, not asserted (VERDICT r1 weak #8): inspect the
+"""Sharding is MEASURED, not asserted (review round 1 weak #8): inspect the
 actual placements `shard_train_state` produces and the collectives XLA
 inserts into the compiled dp/tp train step, ring attention, and the
 distributed GBDT grower — the compiled-HLO ground truth of the SPMD

@@ -1,4 +1,4 @@
-"""The text pretrained-weights chain (VERDICT r3 Missing #4): corpus →
+"""The text pretrained-weights chain (review round 3 Missing #4): corpus →
 BPE → masked-LM pretraining → CheckpointManager/zoo round-trip →
 TextEncoderFeaturizer with REAL (non-random) weights, whose frozen
 features beat the random-init floor (nearest-centroid margin — the

@@ -1,4 +1,4 @@
-"""Transfer-learning E2E (VERDICT r1 item 5 / BASELINE north star shape).
+"""Transfer-learning E2E (review round 1 item 5 / BASELINE north star shape).
 
 The reference's headline workflow: a pretrained backbone feeds
 ``ImageFeaturizer`` and a cheap head learns a new task from frozen features
