@@ -48,7 +48,7 @@ PACKAGES: dict[str, list[str]] = {
     "io": ["test_native_codegen.py", "test_benchmarks.py",
            "test_reference_parity.py", "test_out_of_core.py",
            "test_ci.py", "test_rcheck.py"],
-    "obs": ["test_obs.py", "test_obs_profile.py"],
+    "obs": ["test_obs.py", "test_obs_profile.py", "test_obs_timeline.py"],
     # fleet telemetry plane: federation + straggler/burn health + the
     # chaos trajectory, and the HBM memory profiler's degradation story
     "fleet": ["test_fleet.py", "test_obs_memory.py"],

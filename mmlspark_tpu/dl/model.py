@@ -18,6 +18,9 @@ TPU-native equivalent:
 
 from __future__ import annotations
 
+import collections
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +28,7 @@ import numpy as np
 from ..core import ComplexParam, Model, Param, TypeConverters as TC
 from ..core.contracts import HasInputCol, HasOutputCol
 from ..models.zoo import LoadedModel
+from ..obs.tracing import tracer as _tracer
 
 
 class TPUModel(Model, HasInputCol, HasOutputCol):
@@ -73,7 +77,9 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
     # told from framework overhead in e2e numbers. Keys:
     # prep_ms (host coercion), dispatch_ms (batch slicing + async
     # submit incl. transfer enqueue), drain_ms (waiting on device
-    # compute + output pull), total_ms. Overwritten by every transform.
+    # compute + output pull), total_ms. Overwritten by every transform;
+    # summed from the transform's spans (``tpu_model.*``), so the two
+    # can never disagree.
     last_stats: dict | None = None
 
     def __init__(self, **kwargs):
@@ -107,11 +113,47 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         return self._run_cache[1]
 
     def _transform(self, df):
-        import time
-        t_start = time.perf_counter()
-        col = df[self.getInputCol()]
-        x = self._coerce_input(col)
-        prep_ms = (time.perf_counter() - t_start) * 1e3
+        """One transform as a tree of spans on the tracer's ring:
+        ``tpu_model.transform`` ⊃ ``prep``, then per minibatch ``stage``
+        (slice, tail pad), ``put`` (host-to-device call), ``launch`` (the
+        jitted call) and ``drain`` (wait for the device + copy out), then
+        ``collect``. The children name the root as their parent and leave
+        the ambient context alone (the drain of minibatch k runs inside
+        iteration k+1)."""
+        root = _tracer.start_span("tpu_model.transform")
+        sums: dict[str, float] = collections.defaultdict(float)
+
+        @contextlib.contextmanager
+        def child(what, **attrs):
+            span = _tracer.start_span(f"tpu_model.{what}", parent=root,
+                                      current=False, **attrs)
+            try:
+                yield span
+            except BaseException as e:
+                _tracer.end_span(span, error=e)
+                raise
+            _tracer.end_span(span)
+            sums[span.name] += span.seconds
+
+        try:
+            df = self._transform_spanned(df, root, child)
+        except BaseException as e:
+            _tracer.end_span(root, error=e)
+            raise
+        _tracer.end_span(root)
+        self.last_stats = {
+            "prep_ms": round(sums["tpu_model.prep"] * 1e3, 3),
+            "dispatch_ms": round(
+                (sums["tpu_model.stage"] + sums["tpu_model.put"]
+                 + sums["tpu_model.launch"]) * 1e3, 3),
+            "drain_ms": round(sums["tpu_model.drain"] * 1e3, 3),
+            "total_ms": round(root.seconds * 1e3, 3),
+        }
+        return df
+
+    def _transform_spanned(self, df, root, child):
+        with child("prep"):
+            x = self._coerce_input(df[self.getInputCol()])
         n = x.shape[0]
         bs = self.get("minibatchSize")
         run = self._apply_fn()
@@ -120,15 +162,19 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
             self.get("outputNode"): self.getOutputCol()}
 
         chunks: dict[str, list[np.ndarray]] = {k: [] for k in fetch}
-        dispatch_ms = drain_ms = 0.0
+        bytes_in = bytes_out = 0
 
         def drain(entry):
-            nonlocal drain_ms
-            t0 = time.perf_counter()
-            real, out = entry
-            for endpoint in fetch:
-                chunks[endpoint].append(np.asarray(out[endpoint])[:real])
-            drain_ms += (time.perf_counter() - t0) * 1e3
+            nonlocal bytes_out
+            k, real, out = entry
+            with child("drain", minibatch=k) as span:
+                pulled = 0
+                for endpoint in fetch:
+                    host = np.asarray(out[endpoint])
+                    pulled += host.nbytes
+                    chunks[endpoint].append(host[:real])
+                span.set_attr("bytes", pulled)
+            bytes_out += pulled
 
         # pipelined dispatch: pulling a batch's outputs blocks the
         # host, so keep the next batch(es) already dispatched before
@@ -140,40 +186,44 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                 f"pipelineDepth={depth} must be >= 2 (one batch "
                 "computing while one drains); there is no synchronous "
                 "mode")
-        inflight: list[tuple[int, dict]] = []
-        for start in range(0, n, bs):
-            t0 = time.perf_counter()
-            piece = x[start:start + bs]
-            real = piece.shape[0]
-            if real < bs:  # pad tail to the compiled shape
-                pad = np.zeros((bs - real,) + piece.shape[1:], piece.dtype)
-                piece = np.concatenate([piece, pad])
-            out = run(jnp.asarray(piece))
-            if not isinstance(out, dict):
-                out = {self.get("outputNode"): out}
-            for endpoint in fetch:
-                if endpoint not in out:
-                    raise KeyError(
-                        f"endpoint {endpoint!r} not in model outputs "
-                        f"{sorted(out)}")
-            inflight.append((real, out))
-            dispatch_ms += (time.perf_counter() - t0) * 1e3
+        inflight: list[tuple[int, int, dict]] = []
+        for k, start in enumerate(range(0, n, bs)):
+            with child("stage", minibatch=k):
+                piece = x[start:start + bs]
+                real = piece.shape[0]
+                if real < bs:  # pad tail to the compiled shape
+                    pad = np.zeros((bs - real,) + piece.shape[1:],
+                                   piece.dtype)
+                    piece = np.concatenate([piece, pad])
+            with child("put", minibatch=k, bytes=piece.nbytes):
+                batch = jnp.asarray(piece)
+            with child("launch", minibatch=k):
+                out = run(batch)
+                del batch
+                if not isinstance(out, dict):
+                    out = {self.get("outputNode"): out}
+                for endpoint in fetch:
+                    if endpoint not in out:
+                        raise KeyError(
+                            f"endpoint {endpoint!r} not in model outputs "
+                            f"{sorted(out)}")
+            bytes_in += piece.nbytes
+            inflight.append((k, real, out))
             if len(inflight) >= depth:
                 drain(inflight.pop(0))
         for entry in inflight:
             drain(entry)
 
-        for endpoint, out_col in fetch.items():
-            val = np.concatenate(chunks[endpoint])
-            if self.get("convertOutputToDenseVector") and val.ndim > 2:
-                val = val.reshape(val.shape[0], -1)
-            df = df.with_column(out_col, val.astype(np.float32))
-        self.last_stats = {
-            "prep_ms": round(prep_ms, 3),
-            "dispatch_ms": round(dispatch_ms, 3),
-            "drain_ms": round(drain_ms, 3),
-            "total_ms": round((time.perf_counter() - t_start) * 1e3, 3),
-        }
+        with child("collect"):
+            for endpoint, out_col in fetch.items():
+                val = np.concatenate(chunks[endpoint])
+                if self.get("convertOutputToDenseVector") and val.ndim > 2:
+                    val = val.reshape(val.shape[0], -1)
+                df = df.with_column(out_col, val.astype(np.float32))
+        minibatches = -(-n // bs)
+        root.attrs.update(rows=n, minibatches=minibatches,
+                          padded_rows=minibatches * bs,
+                          bytes_in=bytes_in, bytes_out=bytes_out)
         return df
 
     def _coerce_input(self, col) -> np.ndarray:

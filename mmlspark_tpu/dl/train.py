@@ -23,6 +23,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..obs.tracing import tracer as _tracer
 from ..parallel import compat as _compat
 
 
@@ -382,19 +383,36 @@ def train_epoch(step, state, batches, placement=None):
     if placement is None:
         placement = jax.devices()[0]
     losses = []
-    it = iter(batches)
-    try:
-        x, y = next(it)
-    except StopIteration:
-        return state, []
-    cur = (jax.device_put(x, placement), jax.device_put(y, placement))
-    while cur is not None:
-        state, loss = step(state, *cur)     # async dispatch
+
+    def nbytes(x, y):
+        return getattr(x, "nbytes", 0) + getattr(y, "nbytes", 0)
+
+    def put(x, y, k):
+        with _tracer.span("train.put", step=k, bytes=nbytes(x, y)):
+            return (jax.device_put(x, placement),
+                    jax.device_put(y, placement))
+
+    # spans on the tracer's ring: ``train.epoch`` ⊃ per step ``put`` (the
+    # two device_put calls) and ``launch`` (the call of ``step``), then
+    # ``fetch``, where the host waits for the device
+    with _tracer.span("train.epoch", steps=0, bytes_per_step=0) as root:
+        it = iter(batches)
         try:
-            x, y = next(it)                 # transfer overlaps the step
-            cur = (jax.device_put(x, placement),
-                   jax.device_put(y, placement))
+            x, y = next(it)
         except StopIteration:
-            cur = None
-        losses.append(loss)
-    return state, [float(l) for l in jax.device_get(losses)]
+            return state, []
+        root.set_attr("bytes_per_step", nbytes(x, y))
+        cur = put(x, y, 0)
+        while cur is not None:
+            with _tracer.span("train.launch", step=len(losses)):
+                state, loss = step(state, *cur)     # async dispatch
+            try:
+                x, y = next(it)             # transfer overlaps the step
+                cur = put(x, y, len(losses) + 1)
+            except StopIteration:
+                cur = None
+            losses.append(loss)
+        with _tracer.span("train.fetch"):
+            losses = [float(l) for l in jax.device_get(losses)]
+        root.set_attr("steps", len(losses))
+    return state, losses

@@ -45,7 +45,8 @@ import time
 
 from .attribution import PEAK_SPECS, peak_spec, telemetry_peak_spec
 from .metrics import registry as _registry
-from .tracing import tracer as _tracer, wall_now
+from .timeline import PING, Capture
+from .tracing import now_ns, tracer as _tracer, wall_now
 
 # kept importable for callers that pinned against the old constant —
 # but it is now the v5e row of the shared PeakSpec table
@@ -577,21 +578,81 @@ def pipeline_profiler() -> StepProfiler | None:
 # ----------------------------------------------------- XProf device traces
 # (folded in from utils/profiling.py — the duplicate timing path PR 1
 # left behind; that module now shims here with a DeprecationWarning)
-@contextlib.contextmanager
-def profile_trace(log_dir: str, *, host_tracer_level: int = 2):
-    """Capture a device+host trace for the enclosed region
-    (``jax.profiler.trace`` wrapper; open with XProf/TensorBoard)."""
+def start_device_trace(log_dir: str, *, host_tracer_level: int = 0) -> None:
+    """Start the JAX profiler the way this chip can bear: device planes
+    only unless asked otherwise. With the host tracer on, at any level,
+    the TPU runtime's threads write over a million events and a 1 s
+    transform takes 14 s (PERF.md section 6). Every capture path of the
+    program starts the profiler here."""
     import jax
-    jax.profiler.start_trace(log_dir, create_perfetto_link=False)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, create_perfetto_link=False,
+                             profiler_options=options)
+
+
+def profile_ping(x):
+    """The ping's program; a capture has it as ``timeline.PING_PROGRAM``."""
+    return x + 1
+
+
+@functools.cache
+def _pinger():
+    """The ping's program and its input, compiled once a process."""
+    import jax
+    import jax.numpy as jnp
+    fn = jax.jit(profile_ping)
+    x = jax.block_until_ready(jnp.zeros((8, 128), jnp.float32))
+    fn(x).block_until_ready()
+    return fn, x
+
+
+def _ping() -> None:
+    """One tiny program run to its end while the device is quiet, under
+    a ``launch`` and a ``fetch`` span: it starts within the launch call
+    and its result is back within microseconds of its end, so it pins
+    the device clock to the spans' (``timeline.clock_offset``)."""
+    fn, x = _pinger()
+    with _tracer.span(PING, parent=None):
+        with _tracer.span(PING + ".launch"):
+            y = fn(x)
+        with _tracer.span(PING + ".fetch"):
+            y.block_until_ready()
+
+
+@contextlib.contextmanager
+def profile_trace(log_dir: str, *, host_tracer_level: int = 0):
+    """Capture a device trace of the enclosed region and yield its
+    :class:`~mmlspark_tpu.obs.timeline.Capture`. After the block the
+    handle has the capture's ``.xplane.pb`` path and the tracer ring's
+    spans of the stretch, and puts the two on one clock:
+    ``device_programs()``, ``clock_offset()``, ``idle_by_span()``. Start
+    and end it between whole operations, while the device is quiet: the
+    capture runs one tiny program (``profile.ping``) before and after
+    the block, and those two alone pin the clocks together."""
+    import jax
+    capture = Capture(log_dir)
+    _pinger()                            # compiled outside the capture
+    start_device_trace(log_dir, host_tracer_level=host_tracer_level)
+    trace_ns = start_ns = end_ns = now_ns()
     try:
-        yield
+        _ping()
+        start_ns = now_ns()
+        try:
+            yield capture
+        finally:
+            end_ns = now_ns()
+        _ping()
     finally:
         jax.profiler.stop_trace()
+        capture.close(start_ns, end_ns, trace_ns)
 
 
 def profiled(name: str | None = None):
-    """Decorator: annotate a function in device traces
-    (``jax.profiler.TraceAnnotation``) and record wall time."""
+    """Decorator: annotate a function (``jax.profiler.TraceAnnotation``)
+    for a capture taken with the host tracer on; a device-only capture,
+    the default here, drops it."""
     def wrap(fn):
         label = name or fn.__qualname__
 

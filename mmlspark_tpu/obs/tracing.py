@@ -10,11 +10,20 @@ logger ``BasicLogging`` writes stage telemetry to
 LightGBM ``fit`` shows the stage event and its nested boosting-round
 spans side by side.
 
-Device time: a span with ``device=True`` additionally wraps the region
-in ``jax.profiler.TraceAnnotation`` so it shows up named in XProf
-traces captured by ``utils.profiling.profile_trace`` — wall time on the
-span, device time in the profile, correlated by name. JAX is imported
-lazily and only then; this module must import with no backend.
+The ring: every finished span, whatever its sinks and whether or not
+it is emitted, is appended to a bounded ring (``tracer.recent``). It is
+the one always-on collection point: the benchmark's per-layer readers,
+``obs.profile.profile_trace`` and the tests read it, and nobody has to
+install a sink before the stretch they want to look at.
+
+Device time: spans do NOT appear in a device trace. On the TPU the
+profiler is only usable with its host tracer off (with it on a 1 s
+transform takes 14 s, PERF.md section 6), and a device-only trace
+drops every host annotation. Instead each span carries integer
+``start_ns`` / ``end_ns`` on this module's clock, and
+``obs.profile.profile_trace`` puts the device's events onto that clock
+(``Capture.clock_offset``), so device idle time is put down to the host
+span it falls under. This module imports no JAX.
 
 Cross-thread propagation: ``contextvars`` do not cross ``threading``
 boundaries, so hand the parent over explicitly —
@@ -31,6 +40,7 @@ header field.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import itertools
@@ -56,14 +66,25 @@ _PROC = f"{os.getpid():x}"
 # this single read means an NTP step mid-run can never make a child span
 # appear to start before its parent (and no deadline-path code ever
 # reads time.time()).
-_WALL0 = time.time()
-_PERF0 = time.perf_counter()
+_WALL0_NS = time.time_ns()
+_PERF0_NS = time.perf_counter_ns()
+_WALL0 = _WALL0_NS / 1e9
+_PERF0 = _PERF0_NS / 1e9
+
+#: finished spans the tracer keeps (``Tracer.recent``)
+RING_SIZE = 4096
 
 
 def wall_now() -> float:
     """Epoch seconds derived from the monotonic clock (one wall read at
     import, monotonic deltas after) — the timestamp base for every span."""
     return _WALL0 + (time.perf_counter() - _PERF0)
+
+
+def now_ns() -> int:
+    """``wall_now`` in whole nanoseconds: the clock of ``Span.start_ns``
+    and ``Span.end_ns``."""
+    return _WALL0_NS + (time.perf_counter_ns() - _PERF0_NS)
 
 
 def _new_id() -> str:
@@ -87,7 +108,8 @@ class Span:
     seconds: float | None = None  # wall duration, set at end
     error: str | None = None
     proc: str = ""                # emitting process (hex pid)
-    _t0: float = 0.0              # perf_counter anchor
+    start_ns: int = 0             # ``now_ns`` at start (0: a remote span)
+    end_ns: int | None = None     # ``now_ns`` at end; seconds = their gap
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -134,6 +156,9 @@ class Tracer:
         # finished-span sinks (the flight recorder / test collectors):
         # called on EVERY end_span regardless of the logging gate
         self._sinks: list = []
+        # every finished span, newest last; the deque's own lock is the
+        # only one (append and list() are each one C call)
+        self._ring: collections.deque = collections.deque(maxlen=RING_SIZE)
 
     # -- context -----------------------------------------------------------
     def current_span(self) -> Span | None:
@@ -152,6 +177,21 @@ class Tracer:
             self._sinks.remove(sink)
         except ValueError:
             pass
+
+    # -- the ring --------------------------------------------------------
+    def recent(self, name: str | None = None, last: int | None = None,
+               since: int | None = None) -> list:
+        """Finished spans still in the ring, in the order they ended:
+        those called ``name``, that started at or after ``since`` (on
+        ``now_ns``'s clock), the ``last`` of them."""
+        spans = list(self._ring)
+        if name is not None:
+            spans = [s for s in spans if s.name == name]
+        if since is not None:
+            spans = [s for s in spans if s.start_ns >= since]
+        if last is not None:
+            spans = spans[-last:] if last > 0 else []
+        return spans
 
     # -- span lifecycle ----------------------------------------------------
     def start_span(self, name: str, *, parent=_UNSET,
@@ -173,10 +213,11 @@ class Tracer:
             trace_id, parent_id = tid, getattr(parent, "span_id", None)
         else:
             trace_id, parent_id = _new_id(), None
+        start_ns = now_ns()
         span = Span(name=name, trace_id=trace_id, span_id=_new_id(),
-                    parent_id=parent_id, attrs=dict(attrs),
-                    start_wall=wall_now(), proc=_PROC,
-                    _t0=time.perf_counter())
+                    parent_id=parent_id, attrs=attrs,
+                    start_wall=start_ns / 1e9, proc=_PROC,
+                    start_ns=start_ns)
         if current:
             span._token = _current_span.set(span)
         return span
@@ -186,7 +227,9 @@ class Tracer:
         if getattr(span, "_done", False):
             return span  # already ended (loop break + fallthrough)
         span._done = True
-        span.seconds = time.perf_counter() - span._t0
+        span.end_ns = now_ns()
+        span.seconds = (span.end_ns - span.start_ns) / 1e9
+        self._ring.append(span)
         if error is not None:
             span.error = repr(error)
         token = getattr(span, "_token", None)
@@ -207,30 +250,18 @@ class Tracer:
         return span
 
     @contextlib.contextmanager
-    def span(self, name: str, *, parent=_UNSET, device: bool = False,
-             **attrs):
+    def span(self, name: str, *, parent=_UNSET, **attrs):
         """``with tracer.span("stage.fit", rows=n) as sp: ...``
 
         ``parent``: explicit parent Span (or None to force a new root) —
-        required when crossing a thread boundary. ``device=True`` also
-        annotates the region for XProf device traces."""
+        required when crossing a thread boundary."""
         span = self.start_span(name, parent=parent, **attrs)
-        ann = None
-        if device:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
         try:
             yield span
         except BaseException as e:
             self.end_span(span, error=e)
             raise
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self.end_span(span)
 
     # -- retroactive spans -------------------------------------------------
@@ -247,12 +278,16 @@ class Tracer:
         else:
             trace_id, parent_id = _new_id(), None
         seconds = max(float(seconds), 0.0)
+        if start_wall is None:
+            start_wall = wall_now() - seconds
+        start_ns = int(start_wall * 1e9)
         span = Span(name=name, trace_id=trace_id, span_id=_new_id(),
-                    parent_id=parent_id, attrs=dict(attrs),
-                    start_wall=(wall_now() - seconds
-                                if start_wall is None else start_wall),
-                    seconds=seconds, error=error, proc=_PROC)
+                    parent_id=parent_id, attrs=attrs,
+                    start_wall=start_wall, seconds=seconds, error=error,
+                    proc=_PROC, start_ns=start_ns,
+                    end_ns=start_ns + int(seconds * 1e9))
         span._done = True
+        self._ring.append(span)
         self._emit(span)
         if self.metric is not None:
             self.registry.histogram(

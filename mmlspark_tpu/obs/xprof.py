@@ -1,8 +1,9 @@
 """On-demand device profiler capture behind the serving debug surface.
 
 ``POST /debug/xprof?duration_ms=500`` on either serving front captures
-a bounded-duration device+host trace (``jax.profiler.start_trace`` /
-``stop_trace``) into a rank-suffixed directory under the capture root;
+a bounded-duration device trace (``profile.start_device_trace``: host
+and Python tracers off, the one setting usable on the TPU) into a
+rank-suffixed directory under the capture root;
 ``GET /debug/xprof`` lists finished captures and
 ``GET /debug/xprof?fetch=<name>`` returns one as a zip archive. The
 distributed server adds pod fanout on top (one POST captures every
@@ -112,10 +113,10 @@ class XprofCaptures:
             self._active = name
         log_dir = os.path.join(self._root, name)
         import jax
+        from .profile import start_device_trace
         try:
             os.makedirs(log_dir, exist_ok=True)
-            jax.profiler.start_trace(log_dir,
-                                     create_perfetto_link=False)
+            start_device_trace(log_dir)
             try:
                 time.sleep(duration_ms / 1e3)
             finally:

@@ -14,9 +14,11 @@ these helpers run at TRACE time, the counters measure distinct traced
 call sites × retraces, not per-step executions (XLA replays the
 compiled program without re-entering Python) — the right number for
 "what collectives does this program issue, and how big are they".
-Per-execution device time comes from the paired ``named_scope``: capture
-with ``utils.profiling.profile_trace`` and the op shows up labeled in
-XProf, the TPU equivalent of wrapping a socket allreduce in a stopwatch.
+Per-execution device time comes from the paired ``named_scope``: the
+compiler carries the scope into the op's name, so in a capture taken
+with ``obs.profile.profile_trace`` (device planes only) the op shows up
+labeled — the TPU equivalent of wrapping a socket allreduce in a
+stopwatch.
 """
 
 from __future__ import annotations

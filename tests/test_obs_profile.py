@@ -500,19 +500,56 @@ class TestDeprecationShim:
 
 
 class TestOverheadGuard:
+    #: CPU microseconds one started-and-ended span may cost this thread:
+    #: ten times the 3.3 measured on the builder's box (PR 26), so only
+    #: a span path grown by an order of magnitude trips it
+    SPAN_COST_LIMIT_US = 35.0
+
     def test_tracing_profiler_overhead_within_5pct(self):
-        """ISSUE 8 satellite: serving p99 with tracing+profiler ON
-        within 5% of OFF. One bounded re-measure absorbs a noisy
-        scheduler rep — persistent overhead still fails both."""
+        """What tracing costs a served request, by what does not depend
+        on the box. The test used to compare two p99 wall times (ON
+        within 5 % of OFF) and failed whenever six xdist workers shared
+        the cores. It now guards (a) that the ON run of the serving
+        scenario really traced: per request one ``serving.request``, one
+        ``sched.queue`` and one ``serving.execute`` span and one
+        feature-log record, and (b) the cost of a span itself: 20,000
+        spans in a tight loop, on this thread's CPU clock
+        (``time.thread_time`` does not run while the thread waits for a
+        core), under ten times the measured cost. A request of the
+        scenario takes 5 ms and finishes 5 spans, so the limit bounds
+        tracing at 3.5 % of it. The wall-time ratio is still banked by
+        ``bench.py`` and not asserted here."""
+        from mmlspark_tpu.obs import feature_log
+        from mmlspark_tpu.obs.tracing import Tracer, now_ns
         from mmlspark_tpu.testing.benchmarks import \
             tracing_overhead_scenario
 
-        r = tracing_overhead_scenario()
-        if not r["within_bound"]:
-            r = tracing_overhead_scenario()
-        assert r["within_bound"], r
+        n = 40
+        mark, records = now_ns(), len(feature_log)
+        r = tracing_overhead_scenario(service="tracing-guard",
+                                      n_requests=n, reps=1)
         assert r["p99_on_s"] > 0 and r["p99_off_s"] > 0
-        assert r["feature_records"] > 0  # the ON runs really traced
+        assert len(feature_log) - records == n  # the ON run really traced
+        spans = tracer.recent(since=mark)
+        traces = {s.trace_id for s in spans if s.name == "serving.request"
+                  and s.attrs.get("service") == "tracing-guard"}
+        names = [s.name for s in spans if s.trace_id in traces]
+        for name in ("serving.request", "sched.queue", "serving.execute"):
+            assert names.count(name) == n, (name, names.count(name))
+
+        tr = Tracer()                       # no sink, telemetry above INFO
+
+        def per_span_us(count=20_000):
+            t0 = time.thread_time()
+            for i in range(count):
+                tr.end_span(tr.start_span("guard", parent=None,
+                                          current=False, i=i))
+            return (time.thread_time() - t0) / count * 1e6
+
+        cost = min(per_span_us() for _ in range(3))
+        assert 0 < cost < self.SPAN_COST_LIMIT_US, cost
+        assert 5 * self.SPAN_COST_LIMIT_US * 1e-6 <= \
+            0.05 * r["item_service_s"]
 
 
 class TestChaosTraceAcceptance:
