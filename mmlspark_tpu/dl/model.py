@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,9 +71,13 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         "more of them overlap — at the cost of holding that many batches' outputs in device memory",
         TC.toInt, default=2, has_default=True)
 
-    # class-level fallback: the serializer reconstructs instances
+    # class-level fallbacks: the serializer reconstructs instances
     # without running __init__
     _run_cache = None
+    # (buffer, dirty): one zero-padded host minibatch for the tail, whose
+    # rows from ``dirty`` on are zero. A transform takes it off the
+    # instance while it runs (``_padded_tail``), so no two share it.
+    _tail_buffer = None
     # per-transform timing breakdown, so transfer and device wait can be
     # told from framework overhead in e2e numbers. Keys:
     # prep_ms (host coercion), dispatch_ms (batch slicing + async
@@ -86,7 +91,14 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         super().__init__(**kwargs)
         self._setDefault(inputCol="features", outputCol="output")
         self._run_cache = None
+        self._tail_buffer = None
         self.last_stats = None
+
+    def copy(self, extra=None):
+        out = super().copy(extra)
+        # the shallow copy would share the held buffer between instances
+        out.__dict__.pop("_tail_buffer", None)
+        return out
 
     # ------------------------------------------------------------------
     def _loaded(self) -> tuple:
@@ -116,8 +128,9 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         """One transform as a tree of spans on the tracer's ring:
         ``tpu_model.transform`` ⊃ ``prep``, then per minibatch ``stage``
         (slice, tail pad), ``put`` (host-to-device call), ``launch`` (the
-        jitted call) and ``drain`` (wait for the device + copy out), then
-        ``collect``. The children name the root as their parent and leave
+        jitted call) and ``drain`` (wait for the device, copy out and write
+        the rows into the output column), then ``collect`` (the columns
+        join the frame). The children name the root as their parent and leave
         the ambient context alone (the drain of minibatch k runs inside
         iteration k+1)."""
         root = _tracer.start_span("tpu_model.transform")
@@ -151,28 +164,63 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         }
         return df
 
+    def _padded_tail(self, piece: np.ndarray, bs: int):
+        """``piece`` zero-padded to ``bs`` rows, in the held buffer when
+        it fits (else a new one), and which of the two it was. The caller
+        owns the buffer until it hands it back through ``_tail_buffer``,
+        and does so only once every transfer out of it has ended: the
+        host-to-device copy reads it after the ``put`` call returns."""
+        real = piece.shape[0]
+        shape = (bs,) + piece.shape[1:]
+        # dict.pop is one atomic step: of two concurrent transforms one
+        # gets the buffer and the other allocates
+        held = self.__dict__.pop("_tail_buffer", None)
+        if (held is not None and held[0].shape == shape
+                and held[0].dtype == piece.dtype):
+            buf, dirty = held
+            # padded rows are zeros, always: a model may read the whole
+            # batch (the int8 path's batch-wide activation scale)
+            buf[real:dirty] = 0
+            how = "held"
+        else:
+            buf, how = np.zeros(shape, piece.dtype), "new"
+        buf[:real] = piece
+        return buf, how
+
     def _transform_spanned(self, df, root, child):
         with child("prep"):
             x = self._coerce_input(df[self.getInputCol()])
         n = x.shape[0]
+        if n == 0:
+            raise ValueError("TPUModel.transform: the input column has "
+                             "no rows")
         bs = self.get("minibatchSize")
         run = self._apply_fn()
 
         fetch = self.get("fetchDict") or {
             self.get("outputNode"): self.getOutputCol()}
+        flatten = self.get("convertOutputToDenseVector")
 
-        chunks: dict[str, list[np.ndarray]] = {k: [] for k in fetch}
+        # the output columns, one array per endpoint: allocated when
+        # minibatch 0 is launched (its shapes are known without waiting)
+        # and written by each drain while the next minibatch computes
+        columns: dict[str, np.ndarray] = {}
+        tail, tail_how = None, "none"
         bytes_in = bytes_out = 0
 
         def drain(entry):
             nonlocal bytes_out
             k, real, out = entry
+            start = k * bs
             with child("drain", minibatch=k) as span:
                 pulled = 0
                 for endpoint in fetch:
                     host = np.asarray(out[endpoint])
                     pulled += host.nbytes
-                    chunks[endpoint].append(host[:real])
+                    dst = columns[endpoint]
+                    # the assignment casts to the column's float32
+                    dst[start:start + real] = host[:real].reshape(
+                        (real,) + dst.shape[1:])
                 span.set_attr("bytes", pulled)
             bytes_out += pulled
 
@@ -192,9 +240,8 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                 piece = x[start:start + bs]
                 real = piece.shape[0]
                 if real < bs:  # pad tail to the compiled shape
-                    pad = np.zeros((bs - real,) + piece.shape[1:],
-                                   piece.dtype)
-                    piece = np.concatenate([piece, pad])
+                    piece, tail_how = self._padded_tail(piece, bs)
+                    tail = piece
             with child("put", minibatch=k, bytes=piece.nbytes):
                 batch = jnp.asarray(piece)
             with child("launch", minibatch=k):
@@ -207,23 +254,33 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
                         raise KeyError(
                             f"endpoint {endpoint!r} not in model outputs "
                             f"{sorted(out)}")
+                    if k == 0:
+                        row = out[endpoint].shape[1:]
+                        if flatten and len(row) > 1:
+                            row = (math.prod(row),)
+                        columns[endpoint] = np.empty((n,) + row, np.float32)
             bytes_in += piece.nbytes
             inflight.append((k, real, out))
             if len(inflight) >= depth:
                 drain(inflight.pop(0))
         for entry in inflight:
             drain(entry)
+        if tail is not None:
+            # the tail was the last minibatch and is drained: nothing
+            # reads the buffer any more (an error above drops it instead)
+            self._tail_buffer = (tail, n % bs)
 
+        copied = 0
         with child("collect"):
             for endpoint, out_col in fetch.items():
-                val = np.concatenate(chunks[endpoint])
-                if self.get("convertOutputToDenseVector") and val.ndim > 2:
-                    val = val.reshape(val.shape[0], -1)
-                df = df.with_column(out_col, val.astype(np.float32))
+                df = df.with_column(out_col, columns[endpoint])
+                if not np.may_share_memory(df[out_col], columns[endpoint]):
+                    copied += columns[endpoint].nbytes
         minibatches = -(-n // bs)
         root.attrs.update(rows=n, minibatches=minibatches,
                           padded_rows=minibatches * bs,
-                          bytes_in=bytes_in, bytes_out=bytes_out)
+                          bytes_in=bytes_in, bytes_out=bytes_out,
+                          collect_copied_bytes=copied, tail_buffer=tail_how)
         return df
 
     def _coerce_input(self, col) -> np.ndarray:

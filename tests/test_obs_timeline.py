@@ -6,6 +6,9 @@ captures — plain lists, no profiler."""
 import math
 import tempfile
 
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -73,18 +76,24 @@ class TestRing:
 
 
 # --------------------------------------------------- spans of the loops
-def _tiny_model():
-    import flax.linen as nn
-    import jax
+class BatchNet(nn.Module):
+    """Endpoints of every kind the column writer meets (at module level:
+    a saved ``TPUModel`` pickles its module)."""
+    @nn.compact
+    def __call__(self, x, train=False):
+        x = x.astype(jnp.float32)
+        h = nn.Dense(4)(x)
+        return {"pooled": h,
+                "maps": jnp.tanh(h).reshape(-1, 2, 2, 1),
+                "half": (h * 3).astype(jnp.bfloat16),
+                "count": jnp.sum(x > 1, axis=1).astype(jnp.int32),
+                # reads the whole minibatch, padded rows included
+                "shifted": h + jnp.max(x)}
 
-    class Net(nn.Module):
-        @nn.compact
-        def __call__(self, x, train=False):
-            return {"pooled": nn.Dense(4)(x.astype(np.float32))}
 
-    module = Net()
-    variables = module.init(jax.random.PRNGKey(0), np.zeros((1, 6)))
-    return module, variables
+def _batch_net():
+    module = BatchNet()
+    return module, module.init(jax.random.PRNGKey(1), np.zeros((1, 6)))
 
 
 def _children(root, since):
@@ -98,7 +107,7 @@ class TestTPUModelSpans:
     def _transform(self):
         from mmlspark_tpu.core import DataFrame
         from mmlspark_tpu.dl.model import TPUModel
-        model = TPUModel(model=_tiny_model(), minibatchSize=self.BS,
+        model = TPUModel(model=_batch_net(), minibatchSize=self.BS,
                          inputCol="x", outputCol="y")
         x = np.arange(self.N * 6, dtype=np.float32).reshape(self.N, 6)
         mark = now_ns()
@@ -169,6 +178,193 @@ class TestTPUModelSpans:
             ms("tpu_model.drain"), abs=1e-3)
         assert stats["total_ms"] == pytest.approx(
             1e3 * root.seconds, abs=1e-3)
+
+
+# ------------------------------------ the transform's host buffers (ISSUE 27)
+def _plain_columns(model, x, bs, fetch, flatten=True):
+    """What the parent commit's transform made, written out plainly: the
+    jitted apply a zero-padded minibatch at a time, the real rows of each
+    concatenated, flattened and cast."""
+    module, variables = model
+    run = jax.jit(lambda b: module.apply(variables, b, False))
+    chunks = {e: [] for e in fetch}
+    for start in range(0, len(x), bs):
+        piece = x[start:start + bs]
+        real = len(piece)
+        pad = np.zeros((bs - real,) + piece.shape[1:], piece.dtype)
+        out = run(np.concatenate([piece, pad]))
+        for e in fetch:
+            chunks[e].append(np.asarray(out[e])[:real])
+    cols = {}
+    for e, col in fetch.items():
+        val = np.concatenate(chunks[e])
+        if flatten and val.ndim > 2:
+            val = val.reshape(val.shape[0], -1)
+        cols[col] = val.astype(np.float32)
+    return cols
+
+
+def _rows(n, scale=1.0, seed=0):
+    rng = np.random.default_rng(seed)
+    return (scale * rng.normal(size=(n, 6))).astype(np.float32)
+
+
+class TestTPUModelBuffers:
+    BS = 8
+
+    def _model(self, fetch=None, **kw):
+        from mmlspark_tpu.dl.model import TPUModel
+        return TPUModel(model=_batch_net(), minibatchSize=self.BS,
+                        inputCol="x", outputCol="y", fetchDict=fetch, **kw)
+
+    def _run(self, model, x):
+        from mmlspark_tpu.core import DataFrame
+        mark = now_ns()
+        out = model.transform(DataFrame({"x": x}))
+        root = tracer.recent(name="tpu_model.transform", since=mark)[-1]
+        return out, root
+
+    @pytest.mark.parametrize("n", [16, 20, 5],
+                             ids=["multiple", "tail", "under_one"])
+    @pytest.mark.parametrize("fetch,flatten,shape", [
+        (None, True, (4,)),
+        ({"pooled": "p", "maps": "m"}, True, (4,)),
+        ({"maps": "m"}, False, (2, 2, 1)),
+        ({"half": "h"}, True, (4,)),
+        ({"count": "c", "shifted": "s"}, True, ()),
+    ], ids=["one", "two", "four_d_kept", "bfloat16", "int32_1d"])
+    def test_columns_equal_the_plain_per_minibatch_apply(
+            self, n, fetch, flatten, shape):
+        model = self._model(fetch, convertOutputToDenseVector=flatten)
+        x = _rows(n)
+        out, root = self._run(model, x)
+        want = _plain_columns(model.get("model"), x, self.BS,
+                              fetch or {"pooled": "y"}, flatten)
+        for col, ref in want.items():
+            got = out[col]
+            assert got.dtype == np.float32 and got.shape == ref.shape
+            np.testing.assert_array_equal(got, ref)
+        first = next(iter(want.values()))
+        assert first.shape == (n,) + shape
+        assert root.attrs["collect_copied_bytes"] == 0
+
+    def test_each_transform_returns_its_own_array(self):
+        model = self._model()
+        a, b = _rows(20, seed=1), _rows(20, seed=2)
+        first = self._run(model, a)[0]["y"]
+        kept = first.copy()
+        second = self._run(model, b)[0]["y"]
+        assert not np.shares_memory(first, second)
+        np.testing.assert_array_equal(first, kept)
+        assert not np.array_equal(first, second)
+
+    def test_root_attrs_say_which_tail_buffer_served(self):
+        model = self._model()
+        said = [self._run(model, _rows(n))[1].attrs for n in (20, 21, 16, 3)]
+        assert [a["tail_buffer"] for a in said] == \
+            ["new", "held", "none", "held"]
+        assert [a["collect_copied_bytes"] for a in said] == [0] * 4
+
+    def test_no_rows_raise_as_before(self):
+        with pytest.raises(ValueError):
+            self._run(self._model(), np.zeros((0, 6), np.float32))
+
+    def test_a_shorter_tail_after_a_longer_reads_zero_padding(self):
+        """``shifted`` adds the padded minibatch's max: rows a longer
+        tail left in the held buffer would show in a shorter one's."""
+        fetch = {"shifted": "s"}
+        used = self._model(fetch)
+        self._run(used, np.abs(_rows(7, scale=100.0)) + 50)
+        short = -np.abs(_rows(3, seed=3)) - 1       # max over the batch: 0
+        got, root = self._run(used, short)
+        assert root.attrs["tail_buffer"] == "held"
+        fresh = self._run(self._model(fetch), short)[0]
+        np.testing.assert_array_equal(got["s"], fresh["s"])
+        np.testing.assert_array_equal(
+            got["s"], _plain_columns(used.get("model"), short, self.BS,
+                                     fetch)["s"])
+        buf, dirty = used._tail_buffer
+        assert dirty == 3 and not buf[3:].any()
+
+    @pytest.mark.parametrize("change", ["dtype", "minibatch"])
+    def test_another_minibatch_shape_or_dtype_drops_the_buffer(self, change):
+        model = self._model()
+        self._run(model, _rows(5))
+        held = model._tail_buffer[0]
+        x = _rows(5, scale=3.0)
+        if change == "dtype":
+            x = np.abs(x).astype(np.uint8)
+        else:
+            model.set("minibatchSize", 4)
+        out, root = self._run(model, x)
+        assert root.attrs["tail_buffer"] == "new"
+        assert model._tail_buffer[0] is not held
+        assert model._tail_buffer[0].dtype == x.dtype
+        np.testing.assert_array_equal(
+            out["y"], _plain_columns(model.get("model"), x,
+                                     model.get("minibatchSize"),
+                                     {"pooled": "y"})["y"])
+
+    def test_an_error_hands_no_buffer_back(self):
+        model = self._model()
+        self._run(model, _rows(5))
+        assert model._tail_buffer is not None
+        model.set("inputShape", (1, 2, 3))          # Dense(4) was built for 6
+        with pytest.raises(Exception):
+            self._run(model, _rows(5))
+        assert model._tail_buffer is None
+
+    def test_a_copy_does_not_share_the_buffer(self):
+        model = self._model()
+        self._run(model, _rows(5))
+        assert model._tail_buffer is not None
+        assert model.copy()._tail_buffer is None
+
+    def test_threads_on_one_model_agree_with_the_serial_result(self):
+        import sys
+        import threading
+        model = self._model({"shifted": "s"})
+        inputs = [_rows(3 + i % 6, scale=1.0 + i, seed=i) for i in range(12)]
+        serial = [self._run(model, x)[0]["s"] for x in inputs]
+        got = [None] * len(inputs)
+        errors = []
+
+        def work(i):
+            try:
+                from mmlspark_tpu.core import DataFrame
+                for _ in range(5):
+                    got[i] = model.transform(DataFrame({"x": inputs[i]}))["s"]
+            except BaseException as e:              # read in the test below
+                errors.append(e)
+
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(len(inputs))]
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(was)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        for want, have in zip(serial, got):
+            np.testing.assert_array_equal(have, want)
+
+    def test_a_loaded_model_transforms(self, tmp_path):
+        from mmlspark_tpu.core.serialize import load_stage
+        model = self._model()
+        x = _rows(13)
+        before = self._run(model, x)[0]["y"]
+        model.save(str(tmp_path / "m"))
+        loaded = load_stage(str(tmp_path / "m"))
+        assert "_tail_buffer" not in loaded.__dict__   # __new__, no __init__
+        for said in ("new", "held"):
+            out, root = self._run(loaded, x)
+            assert root.attrs["tail_buffer"] == said
+            np.testing.assert_array_equal(out["y"], before)
 
 
 class TestTrainEpochSpans:
