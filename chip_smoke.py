@@ -580,7 +580,7 @@ def phase_llm(rec: dict, *, vocab: int = 32768, width: int = 512,
                        registry=reg)        # num_blocks: the allocator's
     pools = jax.tree.leaves(engine.pools.target)
     rec.update(num_blocks=int(engine.kv.num_blocks),
-               block_bytes_priced=pool_block_bytes(module.encoder,
+               block_bytes_priced=pool_block_bytes(module.cache_spec(),
                                                    block_len),
                pool_bytes=int(sum(p.nbytes for p in pools)),
                pool_shape=list(pools[0].shape),

@@ -46,6 +46,8 @@ _EXPORTS = {
     "SequenceHandle": ".paged_kv",
     "paged_attention": ".pallas_paged_attention",
     "paged_window_attention": ".pallas_paged_attention",
+    "paged_latent_attention": ".pallas_paged_attention",
+    "LatentMoEDecoder": ".latent_moe_decoder",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -122,31 +122,41 @@ def _chunk_hash(prev: str, tokens) -> str:
     return h.hexdigest()
 
 
-def pool_block_bytes(encoder, block_len: int) -> int:
-    """Bytes ONE block is priced at when ``encoder``'s per-layer k and v
-    pools are sized from an HBM budget. Every array a step can hold is
-    counted in the tiled layout the TPU kernel reads a
-    ``[block_len, heads, head_dim]`` block in — the minor dim rounds up
-    to 128 lanes, the one before it to the dtype's sublane count (8
-    rows of 32 bits; a dim below that to its power of two), so 8 heads
-    of 64 cost 2x their logical bytes and 2 heads of 16 cost 8x:
-    ``2 * depth`` arrays at rest, plus the four (one layer's k and v,
-    coming and going) that XLA materializes in that layout around the
-    attention kernel, because it keeps a pool with a narrow minor dim
+LANES = 128     # the minor dimension of a TPU tile
+
+
+def _tiled_bytes(block_len: int, shape: tuple, dtype) -> int:
+    """Bytes of one ``[block_len, *shape]`` block in the tiled layout
+    the TPU kernel reads it in: the minor dim rounds up to 128 lanes,
+    the one before it to the dtype's sublane count (8 rows of 32 bits; a
+    dim below that to its power of two)."""
+    itemsize = np.dtype(dtype).itemsize
+    sublanes = 8 * max(4 // itemsize, 1)
+    dims = [int(block_len), *(int(n) for n in shape)]
+    dims[-1] = -(-dims[-1] // LANES) * LANES
+    if dims[-2] < sublanes:
+        dims[-2] = 1 << (dims[-2] - 1).bit_length()
+    else:
+        dims[-2] = -(-dims[-2] // sublanes) * sublanes
+    return int(np.prod(dims)) * itemsize
+
+
+def pool_block_bytes(spec, block_len: int) -> int:
+    """Bytes ONE block is priced at when the pools of a decoder's
+    ``cache_spec()`` (per layer, the arrays one token takes as
+    ``(trailing shape, dtype)``) are sized from an HBM budget. Every
+    array a step can hold is counted in the tiled layout the TPU kernel
+    reads a ``[block_len, *shape]`` block in (:func:`_tiled_bytes`), so
+    8 heads of 64 cost 2x their logical bytes and 2 heads of 16 cost 8x:
+    every layer's arrays at rest, plus the widest layer's twice more
+    (coming and going), which XLA materializes in that layout around
+    the attention kernel where it keeps a pool with a narrow minor dim
     compact at rest and re-lays it out on every step (first chip run,
     PR 23: sized from logical bytes, 8M blocks of [4, 2, 16] asked for
     one 32.9 GB padded copy). Pure shape arithmetic — no JAX."""
-    itemsize = np.dtype(encoder.dtype).itemsize
-    hd = encoder.width // encoder.heads
-    sublanes = 8 * max(4 // itemsize, 1)
-    heads = encoder.heads
-    if heads < sublanes:
-        heads = 1 << (heads - 1).bit_length()
-    else:
-        heads = -(-heads // sublanes) * sublanes
-    lanes = -(-hd // 128) * 128
-    return ((2 * encoder.depth + 4) * int(block_len) * heads * lanes
-            * itemsize)
+    layers = [sum(_tiled_bytes(block_len, shape, dtype)
+                  for shape, dtype in layer) for layer in spec]
+    return sum(layers) + 2 * max(layers)
 
 
 def blocks_for_hbm_budget(block_bytes: int, *, fraction: float = 0.5,
@@ -511,15 +521,19 @@ class PagedKVManager:
 # Everything below imports jax lazily: the bookkeeping half above must
 # stay importable (and CI-smoked) with no backend in the process.
 
-def init_pools(encoder, num_blocks: int, block_len: int):
-    """Per-layer ``([num_blocks, block_len, heads, head_dim]`` k, same v)
-    device pools for ``encoder`` (a ``TextEncoder``)."""
+def init_pools(spec, num_blocks: int, block_len: int):
+    """Device pools for a decoder's ``cache_spec()``: per layer a tuple
+    of ``[num_blocks, block_len, *trailing]`` arrays of zeros, one for
+    each array a token takes there (k and v of ``[heads, head_dim]`` for
+    a ``TextEncoder``; one latent for latent attention). Pools are
+    allocated exactly as the decoder states them: a decoder that wants
+    its minor axis padded to whole lanes says so in its spec."""
     import jax.numpy as jnp
-    hd = encoder.width // encoder.heads
-    shape = (int(num_blocks), int(block_len), encoder.heads, hd)
     return tuple(
-        (jnp.zeros(shape, encoder.dtype), jnp.zeros(shape, encoder.dtype))
-        for _ in range(encoder.depth))
+        tuple(jnp.zeros((int(num_blocks), int(block_len),
+                         *(int(n) for n in shape)), dtype)
+              for shape, dtype in layer)
+        for layer in spec)
 
 
 def _flat_positions(rows, pos, block_len: int):
@@ -584,22 +598,23 @@ def take_positions(dense, pos):
 
 
 def scatter_positions(pools, rows, pos, new_kv, valid=None):
-    """Write per-layer ``[S, w, H, hd]`` kv into the pools at absolute
-    positions ``pos`` ([S, w]) through the block table. Positions with
-    ``valid`` ([S, w] bool) false — padded prefill rows, inactive decode
-    slots — are redirected into the trash block's first row, so every
-    program instance writes a fixed index set (shape-stable) without
-    ever touching a live chain. Live chains are disjoint, so the
-    scatter has no real-block collisions and stays deterministic."""
+    """Write per-layer ``[S, w, *trailing]`` arrays (k and v of ``[H,
+    hd]``, or one latent) into the pools at absolute positions ``pos``
+    ([S, w]) through the block table. Positions with ``valid`` ([S, w]
+    bool) false — padded prefill rows, inactive decode slots — are
+    redirected into the trash block's first row, so every program
+    instance writes a fixed index set (shape-stable) without ever
+    touching a live chain. Live chains are disjoint, so the scatter has
+    no real-block collisions and stays deterministic."""
     import jax.numpy as jnp
     out = []
-    for (k_pool, v_pool), (kw, vw) in zip(pools, new_kv):
-        NB, BL, H, hd = k_pool.shape
+    for layer_pools, layer_new in zip(pools, new_kv):
+        NB, BL = layer_pools[0].shape[:2]
         fidx = _flat_positions(rows, pos, BL)           # [S, w]
         if valid is not None:
             fidx = jnp.where(valid, fidx, TRASH_BLOCK * BL)
-        flat_k = k_pool.reshape(NB * BL, H, hd).at[fidx].set(kw)
-        flat_v = v_pool.reshape(NB * BL, H, hd).at[fidx].set(vw)
-        out.append((flat_k.reshape(NB, BL, H, hd),
-                    flat_v.reshape(NB, BL, H, hd)))
+        out.append(tuple(
+            pool.reshape(NB * BL, *pool.shape[2:]).at[fidx].set(new)
+            .reshape(pool.shape)
+            for pool, new in zip(layer_pools, layer_new)))
     return tuple(out)

@@ -325,3 +325,219 @@ def paged_attention(q, k_pool, v_pool, rows, pos, *,
                                  slots_tile=slots_tile, impl=impl,
                                  interpret=interpret)
     return out[:, :, 0, :]
+
+
+# ====================================================== latent attention
+# Paged attention over a LATENT pool (multi-head latent attention in its
+# absorbed form): a layer caches ONE array, ``[num_blocks, block_len,
+# value_dim + rope_dim]`` — the normalised key-value latent and the
+# rotated key all heads share — and every head of a slot scores against
+# the same numbers. So a slot's step over one block is two products with
+# the heads batched into the rows: ``[heads*w, C+R] x [C+R, block_len]``
+# for the scores and ``[heads*w, block_len] x [block_len, C]`` for the
+# weighted sum, whose values are the first ``C`` of the numbers already
+# read for the scores (one read serves both).
+
+#: the kernel's own name in a device trace (``_paged_latent_pallas``
+#: is the jitted function the events carry)
+LATENT_KERNEL_NAME = "paged_latent"
+#: fast memory the latent kernel asks the compiler for, and the part of
+#: it one tile of query rows may claim (the block, the score tile and
+#: Mosaic's temporaries share the rest)
+_LATENT_VMEM_LIMIT = 48 << 20
+_LATENT_TILE_BYTES = 16 << 20
+#: HBM one slot's window may hold as temporaries of the walk around the
+#: kernel: its query rows and output rows, ``heads * w`` of each
+_LATENT_WINDOW_HBM_BYTES = 64 << 20
+
+
+def latent_row_tile(heads: int, width: int, value_dim: int, dtype) -> int:
+    """Query positions (of ``heads`` rows each) one grid cell of the
+    latent kernel holds in fast memory: q and the output block
+    double-buffered, the float32 accumulator, running max and
+    denominator, and three score-tile temporaries of a 512-wide block. A
+    power of two, at least 1."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-int(width) // 128) * 128
+    per_row = (2 * lanes * item + 2 * int(value_dim) * item
+               + 4 * int(value_dim) + 2 * 128 * 4 + 3 * 512 * 4)
+    w = max(_LATENT_TILE_BYTES // (int(heads) * per_row), 1)
+    return 1 << (w.bit_length() - 1)
+
+
+def latent_max_window(heads: int, width: int, value_dim: int,
+                      dtype) -> int:
+    """The widest query window (rows per slot) the latent walk takes.
+    The kernel tiles a window's rows (:func:`latent_row_tile`), so fast
+    memory does not bound it; what does is the window's query and output
+    rows in HBM, ``heads * w * (width + value_dim)`` numbers a slot,
+    held under ``_LATENT_WINDOW_HBM_BYTES``. Floored to the prefill
+    window ladder as :func:`max_window` is; a longer prompt suffix is
+    prefilled in chunks of this width."""
+    per_row = int(heads) * (int(width) + int(value_dim)) \
+        * jnp.dtype(dtype).itemsize
+    w = max(_LATENT_WINDOW_HBM_BYTES // per_row, 1)
+    return w // 64 * 64 if w >= 64 else 1 << (w.bit_length() - 1)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim"))
+def _latent_reference(q, pool, rows, pos, *, scale: float,
+                      value_dim: int):
+    """Pure-lax twin of the latent kernel: ``jnp.take`` each slot's
+    chained blocks through the table, scores of every head against the
+    same ``[L, C+R]`` numbers in float32, ``-inf`` outside ``t <= pos +
+    i``, softmax, NaN→0 for fully-masked rows, the weights in the pool's
+    type against the first ``value_dim`` numbers."""
+    S, w, H, _ = q.shape
+    NB, BL, width = pool.shape
+    L = rows.shape[1] * BL
+    idx = (rows[:, :, None] * BL
+           + jnp.arange(BL)[None, None, :]).reshape(S, L)
+    c = jnp.take(pool.reshape(NB * BL, width), idx, axis=0)   # [S, L, C+R]
+    s = jnp.einsum("swhd,sld->swhl", q, c,
+                   preferred_element_type=jnp.float32) * scale
+    allowed = (jnp.arange(L)[None, None, :]
+               <= (pos[:, None] + jnp.arange(w)[None, :])[:, :, None])
+    s = jnp.where(allowed[:, :, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(jnp.isnan(p), 0.0, p)
+    return jnp.einsum("swhl,slc->swhc", p.astype(pool.dtype),
+                      c[..., :value_dim],
+                      preferred_element_type=jnp.float32).astype(pool.dtype)
+
+
+def _latent_kernel(rows_ref, pos_ref, q_ref, c_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, scale: float, heads: int, tile_w: int,
+                   block_len: int, value_dim: int):
+    """One (slot, row tile, chain block) grid cell: ``tile_w`` query
+    positions of ``heads`` rows each (position-major) against the pool
+    block the table named."""
+    s_idx = pl.program_id(0)
+    t = pl.program_id(1)
+    j = pl.program_id(2)
+    nj = pl.num_programs(2)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    block_id = rows_ref[s_idx, j]
+    first_q = pos_ref[s_idx, 0] + t * tile_w
+
+    # the trash block pads a chain; a block past the tile's last query
+    # position holds nothing any of its rows may see
+    @pl.when((block_id != TRASH_BLOCK)
+             & (j * block_len <= first_q + tile_w - 1))
+    def _compute():
+        R = heads * tile_w
+        q = q_ref[0]                       # [R, C+R]
+        c = c_ref[0]                       # [block_len, C+R]
+        s = jax.lax.dot_general(           # [R, block_len] f32, one dot
+            q, c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        tpos = j * block_len + jax.lax.broadcasted_iota(
+            jnp.int32, (R, block_len), 1)
+        qpos = first_q + jax.lax.broadcasted_iota(
+            jnp.int32, (R, block_len), 0) // heads
+        allowed = tpos <= qpos
+        s = jnp.where(allowed, s, _NEG)
+        m_prev = m_scr[:R, :1]
+        l_prev = l_scr[:R, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[:R, :1] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[:R, :1] = m_new
+        acc_scr[:R, :] = acc_scr[:R, :] * corr + jax.lax.dot_general(
+            p.astype(c.dtype), c[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(j == nj - 1)
+    def _emit():
+        R = heads * tile_w
+        l = jnp.maximum(l_scr[:R, :1], 1e-35)
+        o_ref[0] = (acc_scr[:R] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim",
+                                             "tile_w", "interpret"))
+def _paged_latent_pallas(q, pool, rows, pos, *, scale: float,
+                         value_dim: int, tile_w: int, interpret: bool):
+    S, w, H, width = q.shape
+    BL = pool.shape[1]
+    MB = rows.shape[1]
+    tw = max(min(int(tile_w), w), 1)
+    wp = -(-w // tw) * tw                 # rows past w: computed, dropped
+    R = H * tw
+    Rp = max(R, 8)
+    qf = jnp.pad(q, ((0, 0), (0, wp - w), (0, 0), (0, 0))).reshape(
+        S, wp * H, width)
+    kern = functools.partial(_latent_kernel, scale=scale, heads=H,
+                             tile_w=tw, block_len=BL, value_dim=value_dim)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, wp // tw, MB),
+        in_specs=[
+            pl.BlockSpec((1, R, width),
+                         lambda s, t, j, rt, pt: (s, t, 0)),
+            # the zero-copy read: the table entry IS the block index
+            pl.BlockSpec((1, BL, width),
+                         lambda s, t, j, rt, pt: (rt[s, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, R, value_dim),
+                               lambda s, t, j, rt, pt: (s, t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Rp, 128), jnp.float32),       # running max
+            pltpu.VMEM((Rp, 128), jnp.float32),       # running denominator
+            pltpu.VMEM((Rp, value_dim), jnp.float32),  # output accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, wp * H, value_dim), pool.dtype),
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT),
+        interpret=interpret,
+        name=LATENT_KERNEL_NAME,
+    )(rows.astype(jnp.int32), pos.astype(jnp.int32)[:, None], qf, pool)
+    return out.reshape(S, wp, H, value_dim)[:, :w]
+
+
+def paged_latent_attention(q, pool, rows, pos, *, scale: float,
+                           value_dim: int, impl: str | None = None,
+                           interpret: bool | None = None):
+    """Windowed paged attention over a latent pool. ``q`` [S, w, H, C+R]
+    holds, for each of a slot's ``w`` new rows at global positions
+    ``pos[s] + i`` and each head, the query absorbed into the latent's
+    space (``C`` numbers) and its rotated part (``R``); ``pool`` is ONE
+    layer's ``[num_blocks, block_len, C+R]``; ``rows`` [S, max_blocks] is
+    the block table (TRASH_BLOCK padding); ``pos`` [S] int32. Row ``i``
+    attends pool positions ``t <= pos + i`` through the slot's chain (the
+    window's own entries must already be scattered) with scores ``q . c *
+    scale`` and returns the weighted sum of the first ``value_dim``
+    numbers: [S, w, H, value_dim] in the pool's type.
+
+    ``impl``: "pallas" | "lax" | None (the platform switch of
+    :func:`paged_window_attention`); ``interpret`` forces the Pallas
+    interpreter (tests)."""
+    plat = target_platform()
+    if impl is None:
+        impl = "pallas" if plat == "tpu" else "lax"
+    rows = jnp.asarray(rows, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    if impl == "lax":
+        return _latent_reference(q, pool, rows, pos, scale=float(scale),
+                                 value_dim=int(value_dim))
+    if impl != "pallas":
+        raise ValueError(f"impl={impl!r} is not one of pallas|lax")
+    if interpret is None:
+        interpret = plat != "tpu"
+    tile_w = latent_row_tile(int(q.shape[2]), int(q.shape[3]),
+                             int(value_dim), pool.dtype)
+    return _paged_latent_pallas(q, pool, rows, pos, scale=float(scale),
+                                value_dim=int(value_dim), tile_w=tile_w,
+                                interpret=bool(interpret))

@@ -76,6 +76,80 @@ class MaskedLMModel(nn.Module):
         return self.lm_head(x), caches
 
 
+    # -- the decoder interface of ``serving.llm.LLMEngine`` ----------------
+    #: counts a walk returns beside its logits (none for this decoder)
+    walk_stats = ()
+
+    def cache_spec(self) -> tuple:
+        """For each layer, the arrays one token takes in the paged cache
+        as ``(trailing shape, dtype)``: a key and a value of ``[heads,
+        head_dim]``."""
+        enc = self.encoder
+        kv = ((enc.heads, enc.width // enc.heads), enc.dtype)
+        return ((kv, kv),) * enc.depth
+
+    def max_window(self) -> int:
+        """The widest window :meth:`walk` takes (the paged kernel holds
+        a slot's whole window in fast memory)."""
+        from .pallas_paged_attention import max_window
+        enc = self.encoder
+        return max_window(enc.heads, enc.width // enc.heads, enc.dtype)
+
+    def program_key(self) -> dict:
+        """Everything that changes a compiled walk besides the batch
+        shapes: the static fragment of the engine's AOT fingerprints."""
+        enc = self.encoder
+        return {"vocab": enc.vocab, "width": enc.width,
+                "depth": enc.depth, "heads": enc.heads,
+                "mlp_dim": enc.mlp_dim, "dtype": np.dtype(enc.dtype).name}
+
+    def walk(self, toks, pools, rows, pos, valid):
+        """The paged decode forward: [S, w] token ids at per-slot global
+        positions ``[pos[s], pos[s]+w)`` → ([S, w, V] logits, updated
+        pools, None), reading/writing the pools IN PLACE through the
+        block table.
+
+        Per block: project qkv, scatter the window's kv through the table
+        (write-then-attend, the order ``decode_step``/``decode_window``
+        keep; ``valid`` False redirects a row's writes to the trash
+        block), then ``dl.paged_window_attention`` over each slot's own
+        chain — no dense gather anywhere. The embed/projection/attention/
+        ffn math is element-for-element the ``embed_window →
+        decode_window_blocks → lm_head`` composition (the lax attention
+        path shares ``decode_window``'s exact formulation), so greedy
+        tokens stay byte-identical to ``dl.generate`` on CPU tier-1.
+
+        Runs under ``module.apply(..., method="walk")``."""
+        from .paged_kv import scatter_positions
+        from .pallas_paged_attention import paged_window_attention
+
+        enc = self.encoder
+        w = toks.shape[1]
+        # batched embed_window: same constants/ops per element, positions
+        # per slot instead of one traced scalar
+        x = enc.embed_layer(toks)                           # [S, w, W]
+        dim = jnp.arange(enc.width // 2)[None, None, :]
+        p = (pos[:, None] + jnp.arange(w)[None, :]
+             ).astype(jnp.float32)[:, :, None]
+        ang = p / (10000.0 ** (2 * dim / enc.width))
+        pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
+        x = x + pe.astype(enc.dtype)
+        wrote = pos[:, None] + jnp.arange(w)[None]          # [S, w]
+        new_pools = []
+        for blk, (kp, vp) in zip(enc.blocks, pools):
+            q, k, v = blk._project_qkv(x)                   # [S, H, w, hd]
+            (kp, vp), = scatter_positions(
+                ((kp, vp),), rows, wrote,
+                ((k.transpose(0, 2, 1, 3).astype(kp.dtype),
+                  v.transpose(0, 2, 1, 3).astype(vp.dtype)),),
+                valid=valid)
+            o = paged_window_attention(q, kp, vp, rows, pos)
+            x = blk.ffn(x + blk._merge_out(o))
+            new_pools.append((kp, vp))
+        x = enc.final_ln(x)
+        return self.lm_head(x), tuple(new_pools), None
+
+
 # Partition rules for the pretraining LM: the encoder trunk's rules
 # (paths under ``encoder/`` still hit them — re.search is unanchored)
 # plus the LM head, column-parallel like every other vocab-sized

@@ -103,6 +103,86 @@ def _capacity_ffn(x, eid, pos, keep, w_in, w_out, C: int):
     return jnp.where(keep[:, None], out, 0.0)
 
 
+# ------------------------------------------------------------- serving
+# Dropless top-k routing and a grouped product over the experts HELD: what
+# a serving decoder's expert layer needs (``dl.latent_moe_decoder``). The
+# training encoder below keeps its top-1 argmax over logits.
+
+#: names of the four numbers :func:`dropless_moe` counts, in order
+MOE_STATS = ("pairs_held", "pairs_absent", "experts_touched",
+             "expert_load_max")
+
+
+def route_top_k(probs, *, top_k: int, groups: int = 1,
+                keep_groups: int = 1):
+    """Group-limited greedy top-k over router probabilities ``[T, E]``:
+    a group's score is the largest probability of its ``E / groups``
+    experts, the ``keep_groups`` best groups stay, and the ``top_k`` best
+    experts among them are chosen (ties: the lower index). With one group
+    it is plain top-k. Returns ``(experts [T, top_k] int32, their
+    probabilities [T, top_k])``, not renormalised."""
+    T, E = probs.shape
+    masked = probs
+    if groups > 1:
+        best = jnp.max(probs.reshape(T, groups, E // groups), axis=-1)
+        _, kept = jax.lax.top_k(best, keep_groups)
+        in_kept = jnp.zeros((T, groups), bool).at[
+            jnp.arange(T)[:, None], kept].set(True)
+        masked = jnp.where(jnp.repeat(in_kept, E // groups, axis=1),
+                           probs, 0.0)
+    _, experts = jax.lax.top_k(masked, top_k)
+    return experts.astype(jnp.int32), jnp.take_along_axis(
+        probs, experts, axis=1)
+
+
+def dropless_moe(x, experts, weights, w_gate, w_up, w_down, *,
+                 held: tuple, valid=None):
+    """The held experts' part of a gated-SiLU expert layer, dropless.
+
+    ``x`` [T, D] tokens; ``experts``/``weights`` [T, k] each token's
+    chosen experts (ids over ALL the layer's experts) and combine
+    weights; ``w_gate``/``w_up`` [n, D, F] and ``w_down`` [n, F, D] the
+    experts ``held = (lo, hi)``, ``n = hi - lo`` of them. Every
+    token-expert pair whose expert is held is computed — no capacity, no
+    token left out — and pairs of absent experts are left out: the
+    result is this holder's partial sum ``Σ_{e held} weight_e E_e(x)``
+    ([T, D] float32), which is what expert parallelism asks of one
+    holder before its exchange.
+
+    The pairs are sorted by expert and the three products are grouped
+    (``jax.lax.ragged_dot``: one pass over the held experts' weights,
+    rows only where an expert has pairs). ``valid`` [T] bool leaves
+    padding tokens out of the products and the counts. Also returns the
+    counts :data:`MOE_STATS` as int32 [4]."""
+    T, k = experts.shape
+    lo, hi = int(held[0]), int(held[1])
+    n = hi - lo
+    flat = experts.reshape(-1)
+    real = jnp.ones((T * k,), bool) if valid is None \
+        else jnp.repeat(valid, k)
+    mine = real & (flat >= lo) & (flat < hi)
+    local = jnp.where(mine, flat - lo, n)          # absent pairs sort last
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[local].add(1)[:n]
+    token = jnp.arange(T * k, dtype=jnp.int32) // k
+    xs = x[token[order]]                           # [T*k, D], by expert
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    ys = grouped(h.astype(x.dtype), w_down)        # [T*k, D] float32
+    # rows past the held pairs belong to no group: whatever they hold
+    scale = jnp.where(mine, weights.reshape(-1), 0.0)[order]
+    ys = jnp.where(mine[order][:, None], ys * scale[:, None], 0.0)
+    out = ys[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    stats = jnp.stack([
+        jnp.sum(mine), jnp.sum(real & ~mine), jnp.sum(sizes > 0),
+        jnp.max(sizes)]).astype(jnp.int32)
+    return out, stats
+
+
 def moe_forward(params, x, *, return_aux: bool = False,
                 capacity_factor: float | None = None, valid=None):
     """Single-device reference: x [T, D] → [T, D], top-1 routing.

@@ -53,7 +53,26 @@ writes its kv into the buffers it read from, so steady-state decode
 allocates nothing per step (donation is skipped off-TPU, where XLA
 ignores it with a warning).
 
-Obs: ``gen_ttft_seconds{reuse=cold|warm}``, ``gen_tokens_total``,
+The engine knows nothing of a model's layers. It asks a DECODER MODULE
+for what one token takes in each layer's cache (``cache_spec()``: the
+arrays, as trailing shape and dtype — ``paged_kv.init_pools`` /
+``pool_block_bytes`` / ``scatter_positions`` take that spec) and for a
+walk over a window of tokens through the paged pools
+(``module.apply({"params": ...}, toks, pools, rows, pos, valid,
+method="walk") -> (logits, pools, counts)``, prefill windows and the
+decode step alike; with it ``max_window()``, ``program_key()`` and
+``walk_stats``, the names of the counts). The model's type picks the
+path — ``dl.MaskedLMModel`` (per-head k and v pools) or
+``dl.LatentMoEDecoder`` (one latent array a layer, dropless experts) —
+and no flag does.
+
+Obs: every boundary is an ``llm.step`` span on the tracer's ring with
+``llm.prefill`` and ``llm.decode`` children; a decoder's walk counts
+land on the registry inside the step's one fetch (``<name>_total``
+counters, ``*_max`` gauges: ``moe_pairs_held_total``,
+``moe_pairs_absent_total``, ``moe_experts_touched_total``,
+``moe_expert_load_max``); ``gen_ttft_seconds{reuse=cold|warm}``,
+``gen_tokens_total``,
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
 ``gen_decode_attn_seconds{phase}`` and the dense-fallback odometer
 ``kv_dense_gather_bytes_total`` here, the ``kv_*`` families in
@@ -81,6 +100,7 @@ from ..dl.paged_kv import (OutOfBlocks, PagedKVManager,
 from ..obs import registry as _default_registry
 from ..obs.attribution import cost_attribution
 from ..obs.profile import compile_tracker, feature_log
+from ..obs.tracing import tracer as _tracer
 from ..sched.continuous import SlotScheduler
 
 __all__ = ["LLMEngine", "PrefillExecutor", "DecodeExecutor",
@@ -129,15 +149,6 @@ def _attribute_warm(prog, service: str, *args) -> None:
     cost_attribution.record_compiled(name, compiled, service=service)
 
 
-def _encoder_key(module) -> dict:
-    """Static fingerprint fragment for a causal-LM module: everything
-    that changes the compiled program besides the batch shapes."""
-    enc = module.encoder
-    return {"vocab": enc.vocab, "width": enc.width, "depth": enc.depth,
-            "heads": enc.heads, "mlp_dim": enc.mlp_dim,
-            "dtype": np.dtype(enc.dtype).name}
-
-
 def _donate_pools_kwargs() -> dict:
     """``donate_argnums`` for the pool arguments (positions 2/3 of
     every executor program) on backends where donation is real — each
@@ -155,58 +166,40 @@ def _dense_gather_bytes(module, n_rows: int, max_blocks: int,
     """Bytes ONE ``gather_dense`` over ``n_rows`` chains materializes
     for ``module``'s pools — what the ``MMLSPARK_TPU_PAGED_ATTN=0``
     fallback moves per call and the paged path doesn't."""
-    enc = module.encoder
-    hd = enc.width // enc.heads
-    return int(2 * enc.depth * n_rows * max_blocks * block_len
-               * enc.heads * hd * np.dtype(enc.dtype).itemsize)
+    per_token = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+                    for layer in module.cache_spec()
+                    for shape, dtype in layer)
+    return int(n_rows * max_blocks * block_len * per_token)
 
 
-def _paged_window_walk(mod, toks, pools, rows, pos, valid):
-    """The paged decode forward: [S, w] token ids at per-slot global
-    positions ``[pos[s], pos[s]+w)`` → ([S, w, V] logits, updated
-    pools), reading/writing the pools IN PLACE through the block table.
+class _WalkStats:
+    """The counts a decoder's walk returns beside its logits
+    (``module.walk_stats`` names them), on the engine's registry: a name
+    that ends in ``_max`` is a gauge of that name (the last call's
+    value), every other a counter ``<name>_total``. The executors fetch
+    the counts inside the fetch of the tokens they return."""
 
-    Per block: project qkv, scatter the window's kv through the table
-    (write-then-attend, the order ``decode_step``/``decode_window``
-    keep; ``valid`` False redirects a row's writes to the trash block),
-    then ``dl.paged_window_attention`` over each slot's own chain — no
-    dense gather anywhere. The embed/projection/attention/ffn math is
-    element-for-element the ``embed_window → decode_window_blocks →
-    lm_head`` composition (the lax attention path shares
-    ``decode_window``'s exact formulation), so greedy tokens stay
-    byte-identical to ``dl.generate`` on CPU tier-1.
+    def __init__(self, module, reg, service: str):
+        self.service = service
+        self._sinks = []
+        for name in getattr(module, "walk_stats", ()):
+            if name.endswith("_max"):
+                gauge = reg.gauge(
+                    name, f"decoder walk count {name}, last call, "
+                    "by service")
+                self._sinks.append(gauge.set)
+            else:
+                counter = reg.counter(
+                    f"{name}_total", f"decoder walk count {name}, summed "
+                    "over layers and calls, by service")
+                self._sinks.append(counter.inc)
 
-    Runs under ``module.apply(..., method=_paged_window_walk)`` —
-    ``mod`` is the bound ``MaskedLMModel``."""
-    import jax.numpy as jnp
+    def __bool__(self) -> bool:
+        return bool(self._sinks)
 
-    from ..dl.pallas_paged_attention import paged_window_attention
-
-    enc = mod.encoder
-    w = toks.shape[1]
-    # batched embed_window: same constants/ops per element, positions
-    # per slot instead of one traced scalar
-    x = enc.embed_layer(toks)                           # [S, w, W]
-    dim = jnp.arange(enc.width // 2)[None, None, :]
-    p = (pos[:, None] + jnp.arange(w)[None, :]
-         ).astype(jnp.float32)[:, :, None]
-    ang = p / (10000.0 ** (2 * dim / enc.width))
-    pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-    x = x + pe.astype(enc.dtype)
-    wrote = pos[:, None] + jnp.arange(w)[None]          # [S, w]
-    new_pools = []
-    for blk, (kp, vp) in zip(enc.blocks, pools):
-        q, k, v = blk._project_qkv(x)                   # [S, H, w, hd]
-        (kp, vp), = scatter_positions(
-            ((kp, vp),), rows, wrote,
-            ((k.transpose(0, 2, 1, 3).astype(kp.dtype),
-              v.transpose(0, 2, 1, 3).astype(vp.dtype)),),
-            valid=valid)
-        o = paged_window_attention(q, kp, vp, rows, pos)
-        x = blk.ffn(x + blk._merge_out(o))
-        new_pools.append((kp, vp))
-    x = enc.final_ln(x)
-    return mod.lm_head(x), tuple(new_pools)
+    def record(self, counts) -> None:
+        for sink, value in zip(self._sinks, np.asarray(counts)):
+            sink(float(value), service=self.service)
 
 
 # ----------------------------------------------------------------- handoff
@@ -262,7 +255,7 @@ class PrefillExecutor:
     """Fills KV blocks for admitted prompts in padding-bucketed batches.
 
     One compiled program per window bucket ``w``: run the paged window
-    walk (:func:`_paged_window_walk`) over the prompt SUFFIX
+    walk (the decoder's ``walk``) over the prompt SUFFIX
     (everything past the prefix-reused blocks) at per-row start
     positions — SCATTER-ONLY: each block's kv writes through the table
     as it is computed and attention reads the pools in place, no
@@ -289,14 +282,11 @@ class PrefillExecutor:
         self.pad_id = int(pad_id)
         self.service = service
         self.paged = paged_attention_enabled()
-        from ..dl.pallas_paged_attention import max_window
-        # the widest window every model's kernel holds in fast memory:
-        # a longer suffix is fed in chunks of this width
+        # the widest window every model's walk takes: a longer suffix
+        # is fed in chunks of this width
         self.max_window = min(
-            max_window(m.encoder.heads,
-                       m.encoder.width // m.encoder.heads,
-                       m.encoder.dtype)
-            for m in (module, draft_module) if m is not None)
+            m.max_window() for m in (module, draft_module)
+            if m is not None)
         reg = registry if registry is not None else _default_registry
         self._h_attn = reg.histogram(
             "gen_decode_attn_seconds",
@@ -312,6 +302,7 @@ class PrefillExecutor:
         if draft_module is not None:
             self._gather_bytes += _dense_gather_bytes(
                 draft_module, self.batch, self.max_blocks, kv.block_len)
+        self._walk_stats = _WalkStats(module, reg, service)
         self._programs: dict[int, object] = {}
         self._fps: dict[str, tuple[str, str]] = {}
 
@@ -330,13 +321,13 @@ class PrefillExecutor:
                     lens):
                 valid = (jnp.arange(w)[None] < lens[:, None]) & \
                     (lens[:, None] > 0)
-                logits, pools_t = module.apply(
+                logits, pools_t, counts = module.apply(
                     {"params": params}, toks, pools_t, rows, pos,
-                    valid, method=_paged_window_walk)   # [P, w, V]
+                    valid, method="walk")               # [P, w, V]
                 if draft is not None:
-                    _, pools_d = draft.apply(
+                    _, pools_d, _ = draft.apply(
                         {"params": dparams}, toks, pools_d, rows, pos,
-                        valid, method=_paged_window_walk)
+                        valid, method="walk")
                 logits = logits.at[:, :, pad_id].set(-jnp.inf)
                 last = jnp.clip(lens - 1, 0, w - 1)
                 row_logits = jnp.take_along_axis(
@@ -344,7 +335,7 @@ class PrefillExecutor:
                     last[:, None, None].repeat(logits.shape[-1], 2),
                     axis=1)[:, 0]                       # [P, V]
                 first = jnp.argmax(row_logits, -1).astype(jnp.int32)
-                return pools_t, pools_d, first
+                return pools_t, pools_d, first, counts
         else:
             def run(params, dparams, pools_t, pools_d, rows, toks, pos,
                     lens):
@@ -380,7 +371,7 @@ class PrefillExecutor:
                     last[:, None, None].repeat(logits.shape[-1], 2),
                     axis=1)[:, 0]                       # [P, V]
                 first = jnp.argmax(row_logits, -1).astype(jnp.int32)
-                return pools_t, pools_d, first
+                return pools_t, pools_d, first, None
 
         name = f"llm_prefill_{self.service}_w{w}_b{P}"
         prog = compile_tracker.jit(run, name=name,
@@ -391,8 +382,8 @@ class PrefillExecutor:
                "attn": "paged" if self.paged else "dense",
                "max_blocks": self.max_blocks,
                "block_len": self.kv.block_len,
-               "encoder": _encoder_key(self.module),
-               "draft": None if draft is None else _encoder_key(draft),
+               "encoder": self.module.program_key(),
+               "draft": None if draft is None else draft.program_key(),
                "versions": aot.runtime_versions()}
         self._fps[name] = aot.fingerprints(key, [], [])
         return prog
@@ -418,6 +409,7 @@ class PrefillExecutor:
         attending what the chunks before it wrote — commits lengths
         (``kv.advance`` + ``kv.publish``), returns
         ``seq_id -> (first_token, suffix_len)``."""
+        import jax
         import jax.numpy as jnp
         out: dict = {}
         P = self.batch
@@ -435,6 +427,7 @@ class PrefillExecutor:
             rows = jnp.asarray(self.kv.block_rows(
                 ids + [None] * (P - len(ids)), self.max_blocks))
             firsts: dict = {}
+            counts: list = []           # each call's walk counts
             done = 0                    # suffix tokens fed so far
             for w in self.windows_for(max(m[3] for m in metas)):
                 # a row whose suffix ended in an earlier chunk rides
@@ -450,13 +443,15 @@ class PrefillExecutor:
                         lens[i] = k
                 prog = self._program(w)
                 t0 = time.perf_counter()
-                pools_t, pools_d, first = prog(
+                pools_t, pools_d, first, count = prog(
                     self.variables["params"],
                     None if self.draft_module is None
                     else self.draft_variables["params"],
                     self.pools.target, self.pools.draft,
                     rows, jnp.asarray(toks),
                     jnp.asarray(pos), jnp.asarray(lens))
+                if self._walk_stats:
+                    counts.append(count)
                 self._h_attn.observe(time.perf_counter() - t0,
                                      service=self.service,
                                      phase="prefill")
@@ -471,11 +466,15 @@ class PrefillExecutor:
                     if done < n <= done + w:   # its last token is here
                         firsts[i] = first
                 done += w
+            # ONE fetch: the first tokens and the calls' counts together
+            firsts, counts = jax.device_get((firsts, counts))
+            for count in counts:
+                self._walk_stats.record(count)
             for i, (seq_id, _, _, n) in enumerate(metas):
                 h = self.kv.handle(seq_id)
                 self.kv.advance(seq_id, h.prompt_len - h.length)
                 self.kv.publish(seq_id)
-                out[seq_id] = (int(np.asarray(firsts[i])[i]), int(n))
+                out[seq_id] = (int(firsts[i][i]), int(n))
         return out
 
     def warm(self, windows=(1,)) -> None:
@@ -498,7 +497,7 @@ class PrefillExecutor:
             # attribution must lower BEFORE the call: donation
             # invalidates the pool buffers the args reference
             _attribute_warm(prog, self.service, *args)
-            pools_t, pools_d, _ = prog(*args)
+            pools_t, pools_d, *_ = prog(*args)
             self.pools.target = pools_t
             if self.draft_module is not None:
                 self.pools.draft = pools_d
@@ -557,6 +556,7 @@ class DecodeExecutor:
             self._gather_bytes += _dense_gather_bytes(
                 draft_module, int(slots), int(max_blocks),
                 kv.block_len)
+        self._walk_stats = _WalkStats(module, reg, service)
         # host-side slot state (the engine owns seq metadata)
         self.seq_ids: list = [None] * self.slots
         self.ptr = np.ones(self.slots, np.int32)   # committed tokens
@@ -613,15 +613,14 @@ class DecodeExecutor:
         if self.paged and k == 0:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
-                logits, pools_t = module.apply(
+                logits, pools_t, counts = module.apply(
                     {"params": params}, last[:, None], pools_t, rows,
-                    ptr - 1, active[:, None],
-                    method=_paged_window_walk)          # [S, 1, V]
+                    ptr - 1, active[:, None], method="walk")  # [S, 1, V]
                 logits = logits[:, 0].at[:, pad_id].set(-jnp.inf)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 committed = nxt[:, None]                # [S, 1]
                 n_new = jnp.where(active, 1, 0)
-                return pools_t, pools_d, committed, n_new, n_new
+                return pools_t, pools_d, committed, n_new, n_new, counts
         elif self.paged:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
@@ -630,24 +629,24 @@ class DecodeExecutor:
                 tok = last[:, None]                     # [S, 1]
                 drafts = []
                 for j in range(k):
-                    ld, pools_d = draft.apply(
+                    ld, pools_d, _ = draft.apply(
                         {"params": dparams}, tok, pools_d, rows,
-                        pos + j, av, method=_paged_window_walk)
+                        pos + j, av, method="walk")
                     ld = ld[:, 0].at[:, pad_id].set(-jnp.inf)
                     tok = jnp.argmax(ld, -1).astype(jnp.int32)[:, None]
                     drafts.append(tok[:, 0])
                 # extra cache-fill step: d_k's kv, or the next round's
                 # draft attends a zero hole after a full accept (same
                 # fix as dl.speculative)
-                _, pools_d = draft.apply(
+                _, pools_d, _ = draft.apply(
                     {"params": dparams}, tok, pools_d, rows, pos + k,
-                    av, method=_paged_window_walk)
+                    av, method="walk")
                 d = jnp.stack(drafts, 1)                # [S, k]
                 window = jnp.concatenate([last[:, None], d], 1)
-                lt, pools_t = module.apply(
+                lt, pools_t, counts = module.apply(
                     {"params": params}, window, pools_t, rows, pos,
                     av & jnp.ones((S, k + 1), bool),
-                    method=_paged_window_walk)          # [S, k+1, V]
+                    method="walk")                      # [S, k+1, V]
                 lt = lt.at[:, :, pad_id].set(-jnp.inf)
                 t = jnp.argmax(lt, -1).astype(jnp.int32)
                 agree = jnp.cumprod(
@@ -668,7 +667,7 @@ class DecodeExecutor:
                                  jnp.maximum(end - ptr, 1))
                 n_new = jnp.where(active, n_new, 0)
                 return pools_t, pools_d, committed, n_new, \
-                    jnp.where(active, n_acc, 0)
+                    jnp.where(active, n_acc, 0), counts
         elif k == 0:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
@@ -689,7 +688,7 @@ class DecodeExecutor:
                     valid=active[:, None])
                 committed = nxt[:, None]                # [S, 1]
                 n_new = jnp.where(active, 1, 0)
-                return pools_t, pools_d, committed, n_new, n_new
+                return pools_t, pools_d, committed, n_new, n_new, None
         else:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
@@ -749,7 +748,7 @@ class DecodeExecutor:
                     pools_d, rows, wrote,
                     take_positions(dense_d, wrote), valid=valid)
                 return pools_t, pools_d, committed, n_new, \
-                    jnp.where(active, n_acc, 0)
+                    jnp.where(active, n_acc, 0), None
 
         attn = "paged" if self.paged else "dense"
         name = f"llm_decode_{attn}_{self.service}_S{S}_k{k}"
@@ -759,8 +758,8 @@ class DecodeExecutor:
                "spec_k": k, "attn": attn,
                "max_blocks": self.max_blocks,
                "block_len": self.kv.block_len,
-               "encoder": _encoder_key(self.module),
-               "draft": None if draft is None else _encoder_key(draft),
+               "encoder": self.module.program_key(),
+               "draft": None if draft is None else draft.program_key(),
                "versions": aot.runtime_versions()}
         self._fps[name] = aot.fingerprints(key, [], [])
         return self._program
@@ -780,6 +779,7 @@ class DecodeExecutor:
         ``slot -> (tokens_committed list, n_accepted)``; the caller
         commits tokens, advances the block table, and retires finished
         sequences."""
+        import jax
         import jax.numpy as jnp
         runnable = self.runnable
         if not runnable.any():
@@ -794,7 +794,7 @@ class DecodeExecutor:
              for i, sid in enumerate(self.seq_ids)], self.max_blocks)
         prog = self._build()
         t0 = time.perf_counter()
-        pools_t, pools_d, committed, n_new, n_acc = prog(
+        pools_t, pools_d, committed, n_new, n_acc, counts = prog(
             self.variables["params"],
             None if self.draft_module is None
             else self.draft_variables["params"],
@@ -811,9 +811,11 @@ class DecodeExecutor:
         self.pools.target = pools_t
         if self.draft_module is not None:
             self.pools.draft = pools_d
-        committed = np.asarray(committed)
-        n_new = np.asarray(n_new)
-        n_acc = np.asarray(n_acc)
+        # ONE fetch a step: the tokens and the walk's counts together
+        committed, n_new, n_acc, counts = jax.device_get(
+            (committed, n_new, n_acc, counts))
+        if self._walk_stats:
+            self._walk_stats.record(counts)
         out = {}
         for s in range(self.slots):
             if not runnable[s]:
@@ -898,12 +900,14 @@ class LLMEngine:
         self.max_seq_len = int(max_seq_len)
         self.block_len = int(block_len)
         self.max_blocks = -(-self.max_seq_len // self.block_len)
-        enc = module.encoder
-        # target and draft pools share the chains, so one block costs
-        # both models' bytes and the two pools share ONE hbm_fraction
-        block_bytes = pool_block_bytes(enc, self.block_len)
+        # what a token takes in each layer's cache is the decoder's to
+        # state; target and draft pools share the chains, so one block
+        # costs both models' bytes and the two pools share ONE
+        # hbm_fraction
+        spec = module.cache_spec()
+        block_bytes = pool_block_bytes(spec, self.block_len)
         if draft_module is not None:
-            block_bytes += pool_block_bytes(draft_module.encoder,
+            block_bytes += pool_block_bytes(draft_module.cache_spec(),
                                             self.block_len)
         if num_blocks is None:
             # HBM-derived sizing with a host/CPU fallback generous
@@ -918,9 +922,9 @@ class LLMEngine:
                 default=num_blocks - 1),
             service=service, registry=reg)
         self.pools = _PoolState(
-            init_pools(enc, num_blocks, self.block_len),
+            init_pools(spec, num_blocks, self.block_len),
             None if draft_module is None else init_pools(
-                draft_module.encoder, num_blocks, self.block_len))
+                draft_module.cache_spec(), num_blocks, self.block_len))
         self.sched = SlotScheduler(slots, service=service,
                                    registry=reg, clock=clock)
         self.prefiller = PrefillExecutor(
@@ -980,12 +984,18 @@ class LLMEngine:
         """Admit → prefill → handoff → decode. Returns ``(seq_id,
         tokens)`` pairs (full sequence: prompt then generated) finished
         at this boundary."""
+        with _tracer.span("llm.step") as root:
+            return self._step(root)
+
+    def _step(self, root) -> list:
         for a in self.sched.admit():
             self._to_prefill.append(a)
         for seq_id in self.sched.drain_expired():
             self._meta.pop(seq_id, None)
             self.expired.append(seq_id)
-        self._run_prefill()
+        if self._to_prefill:
+            with _tracer.span("llm.prefill", parent=root):
+                self._run_prefill()
         for payload in self.handoff.pull(self.decoder.free_slots):
             meta = self._meta[payload["seq"]["seq_id"]]
             slot = self.decoder.activate(meta.slot, payload)
@@ -995,7 +1005,8 @@ class LLMEngine:
             # budget; credit it at this boundary's scheduler step
             self._first_credit[slot] = 1
         finished = []
-        results = self.decoder.step()
+        with _tracer.span("llm.decode", parent=root):
+            results = self.decoder.step()
         if results:
             self._c_steps.inc(1, service=self.service)
         tokens_by_slot = dict(self._first_credit)

@@ -210,8 +210,9 @@ def test_llm_engine_programs(one_chip, steer_tpu, config):
                        num_blocks=4, registry=MetricsRegistry(),
                        service="chip-compile", **kw)
     budget = _FREE_HBM // 2
-    num_blocks = budget // (pool_block_bytes(enc, engine.block_len)
-                            * (2 if spec else 1))
+    num_blocks = budget // (
+        pool_block_bytes(module.cache_spec(), engine.block_len)
+        * (2 if spec else 1))
     pools = jax.tree.map(
         lambda a: _sds((num_blocks,) + a.shape[1:], a.dtype, one_chip),
         engine.pools.target)
@@ -242,6 +243,74 @@ def test_llm_engine_programs(one_chip, steer_tpu, config):
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
         weights = mem.argument_size_in_bytes - mem.alias_size_in_bytes
         assert held - weights <= budget, (name, held, budget)
+
+
+def test_latent_moe_engine_programs(one_chip, steer_tpu):
+    """The engine's decode program and its widest prefill program for
+    the latent-attention / expert decoder at the benchmark cell's own
+    sizes (``benchmark/configs/deepseek-v2.json``, 128 slots, chains of
+    34 blocks of 512, a pool of 384): five latent kernels each, the pools
+    donated and NOT copied (the latent's 576 numbers rest padded to 640
+    lanes; unpadded, XLA keeps the pool transposed and copies 252 MB a
+    layer in and out of every program), weights and pools inside the
+    chip's memory with temporaries under a tenth of a gigabyte."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run
+    from benchmark.references import deepseek_v2 as ref
+    from mmlspark_tpu.obs.metrics import MetricsRegistry
+
+    _, wl, cfg, params = run.load_cell("deepseek-v2.doc-qa",
+                                       run.load_bench())
+    driver = run._load_module("drivers", wl["driver"])
+
+    def leaf(entry):
+        return _sds(entry[1], jnp.bfloat16, one_chip)
+
+    weights = {k: leaf(v) for k, v in ref.top_shapes(cfg).items()}
+    weights["layers"] = [
+        {k: leaf(v) for k, v in ref.layer_shapes(cfg, i).items()}
+        for i in range(int(cfg["num_hidden_layers"]))]
+    num_blocks = int(params["engine"]["num_blocks"])
+    small = {**params, "engine": {**params["engine"], "num_blocks": 4}}
+    engine = driver.build_engine(cfg, small, weights, MetricsRegistry())
+    pools = jax.tree.map(
+        lambda a: _sds((num_blocks,) + a.shape[1:], a.dtype, one_chip),
+        engine.pools.target)
+    S, MB, P = engine.decoder.slots, engine.max_blocks, \
+        engine.prefiller.batch
+    w = engine.prefiller.max_window
+    assert (S, MB, w) == (128, 34, 192)
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    programs = {
+        "decode": engine.decoder._build().lower(
+            weights, None, pools, None, i32(S, MB), i32(S), i32(S),
+            i32(S), _sds((S,), jnp.bool_, one_chip)),
+        f"prefill_w{w}": engine.prefiller._program(w).lower(
+            weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
+            i32(P))}
+    at_rest = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree.leaves(pools))
+    assert at_rest == num_blocks * 512 * 640 * 2 * 5
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert text.count("paged_latent") >= 5, name
+        assert text.count("ragged-dot") >= 12, name
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= at_rest, name
+        assert mem.temp_size_in_bytes < 100e6, (name,
+                                                mem.temp_size_in_bytes)
+        assert mem.argument_size_in_bytes < 11.7e9, name
 
 
 def test_gbdt_boosting_step(one_chip, steer_tpu, monkeypatch):

@@ -214,9 +214,9 @@ class TestPoolSizing:
         from mmlspark_tpu.dl.paged_kv import pool_block_bytes
 
         def price(heads, hd, dtype, depth=8, block_len=16):
-            return pool_block_bytes(TextEncoder(
+            return pool_block_bytes(MaskedLMModel(TextEncoder(
                 vocab=8, width=heads * hd, depth=depth, heads=heads,
-                mlp_dim=8, dtype=dtype), block_len)
+                mlp_dim=8, dtype=dtype)).cache_spec(), block_len)
         # 2*depth arrays at rest + 4 in flight around the kernel, each
         # [block_len, heads, 128 lanes]
         assert price(8, 128, jnp.bfloat16) == 20 * 16 * 8 * 128 * 2
@@ -238,7 +238,7 @@ class TestPoolSizing:
             lambda: [{"bytes_limit": free + 1000, "bytes_in_use": 1000}])
         kw = dict(slots=1, block_len=4, max_seq_len=16,
                   registry=MetricsRegistry())
-        one = pool_block_bytes(module.encoder, 4)
+        one = pool_block_bytes(module.cache_spec(), 4)
         plain = LLMEngine(module, variables, **kw)
         spec = LLMEngine(module, variables, draft_module=module,
                          draft_variables=variables, spec_k=1, **kw)
