@@ -31,9 +31,10 @@ and the residual stream kept in float32:
   one holder's share of an expert-parallel layer, without the exchange.
 
 The interface the engine asks of a decoder (``serving.llm``):
-``cache_spec()``, ``max_window()``, ``program_key()`` and ``walk`` through
-``apply(variables, ..., method="walk")``; ``walk_stats`` names the counts
-a walk returns beside its logits.
+``cache_spec()``, ``max_window()``, ``program_key()``, and through
+``apply(variables, ..., method=...)`` ``walk`` (a window's hidden rows, no
+head) and ``logits`` (the head over the rows the caller picked);
+``walk_stats`` names the counts a walk returns beside its hidden rows.
 
 Parameters are a plain dict (no flax): ``embed`` [V, D], ``head`` [D, V],
 ``final_norm`` [D] and ``layers``, a list of dicts — ``attn_norm``,
@@ -197,13 +198,15 @@ class LatentMoEDecoder:
     # -- the walk -------------------------------------------------------------
     def walk(self, params, toks, pools, rows, pos, valid):
         """[S, w] token ids at per-slot global positions ``[pos[s],
-        pos[s] + w)`` → ``([S, w, V] float32 logits, updated pools, int32
-        counts named by ``walk_stats``)``, reading and writing the latent
-        pools IN PLACE through the block table: per layer the window's
-        ``[c_kv, k_rope]`` is scattered first (``valid`` False sends a
-        row's write to the trash block), then every row attends its
-        slot's chain up to itself. Prefill windows and the decode step
-        (``w`` = 1) alike."""
+        pos[s] + w)`` → ``([S, w, D] float32 hidden rows after the last
+        block, updated pools, int32 counts named by ``walk_stats``)``,
+        reading and writing the latent pools IN PLACE through the block
+        table: per layer the window's ``[c_kv, k_rope]`` is scattered
+        first (``valid`` False sends a row's write to the trash block),
+        then every row attends its slot's chain up to itself. Prefill
+        windows and the decode step (``w`` = 1) alike. No head: the
+        caller picks the rows a token is sampled from and asks
+        :meth:`logits` for those alone."""
         S, w = toks.shape
         H, C, R = self.heads, self.latent, self.rope
         pad = self.cache_width - (C + R)
@@ -252,6 +255,11 @@ class LatentMoEDecoder:
                 u.reshape(S * w, self.width), lw, token_valid)
             x = h + ffn.reshape(S, w, self.width)
             stats = stats.at[:3].add(counts[:3]).at[3].max(counts[3])
-        logits = self._mm(self._rms(x, params["final_norm"]),
-                          params["head"])
-        return logits, tuple(new_pools), stats
+        return x, tuple(new_pools), stats
+
+    def logits(self, params, hidden):
+        """The head over the rows the caller picked out of a walk's
+        hidden rows: [..., D] → [..., V] float32 logits (the final norm,
+        per row, then the untied head)."""
+        return self._mm(self._rms(hidden, params["final_norm"]),
+                        params["head"])

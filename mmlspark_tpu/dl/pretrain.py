@@ -77,7 +77,7 @@ class MaskedLMModel(nn.Module):
 
 
     # -- the decoder interface of ``serving.llm.LLMEngine`` ----------------
-    #: counts a walk returns beside its logits (none for this decoder)
+    #: counts a walk returns beside its hidden rows (none for this decoder)
     walk_stats = ()
 
     def cache_spec(self) -> tuple:
@@ -104,20 +104,25 @@ class MaskedLMModel(nn.Module):
                 "mlp_dim": enc.mlp_dim, "dtype": np.dtype(enc.dtype).name}
 
     def walk(self, toks, pools, rows, pos, valid):
-        """The paged decode forward: [S, w] token ids at per-slot global
-        positions ``[pos[s], pos[s]+w)`` → ([S, w, V] logits, updated
-        pools, None), reading/writing the pools IN PLACE through the
-        block table.
+        """The paged decode forward WITHOUT the head: [S, w] token ids at
+        per-slot global positions ``[pos[s], pos[s]+w)`` → ([S, w, width]
+        hidden rows after the last block, updated pools, None),
+        reading/writing the pools IN PLACE through the block table. The
+        caller picks the rows a token is sampled from and asks
+        :meth:`logits` for those alone (the engine: one row a prompt in
+        prefill, none in a chunk where no prompt ends, every row of a
+        decode or verify window).
 
         Per block: project qkv, scatter the window's kv through the table
         (write-then-attend, the order ``decode_step``/``decode_window``
         keep; ``valid`` False redirects a row's writes to the trash
         block), then ``dl.paged_window_attention`` over each slot's own
         chain — no dense gather anywhere. The embed/projection/attention/
-        ffn math is element-for-element the ``embed_window →
-        decode_window_blocks → lm_head`` composition (the lax attention
-        path shares ``decode_window``'s exact formulation), so greedy
-        tokens stay byte-identical to ``dl.generate`` on CPU tier-1.
+        ffn math, with :meth:`logits` after it, is element-for-element
+        the ``embed_window → decode_window_blocks → lm_head`` composition
+        (the lax attention path shares ``decode_window``'s exact
+        formulation), so greedy tokens stay byte-identical to
+        ``dl.generate`` on CPU tier-1.
 
         Runs under ``module.apply(..., method="walk")``."""
         from .paged_kv import scatter_positions
@@ -146,8 +151,14 @@ class MaskedLMModel(nn.Module):
             o = paged_window_attention(q, kp, vp, rows, pos)
             x = blk.ffn(x + blk._merge_out(o))
             new_pools.append((kp, vp))
-        x = enc.final_ln(x)
-        return self.lm_head(x), tuple(new_pools), None
+        return x, tuple(new_pools), None
+
+    def logits(self, hidden):
+        """The head over the rows the caller picked out of a walk's
+        hidden rows: [..., width] → [..., V] float32 logits (the final
+        norm, per row, then ``lm_head``). Runs under
+        ``module.apply(..., method="logits")``."""
+        return self.lm_head(self.encoder.final_ln(hidden))
 
 
 # Partition rules for the pretraining LM: the encoder trunk's rules

@@ -56,13 +56,21 @@ ignores it with a warning).
 The engine knows nothing of a model's layers. It asks a DECODER MODULE
 for what one token takes in each layer's cache (``cache_spec()``: the
 arrays, as trailing shape and dtype — ``paged_kv.init_pools`` /
-``pool_block_bytes`` / ``scatter_positions`` take that spec) and for a
+``pool_block_bytes`` / ``scatter_positions`` take that spec), for a
 walk over a window of tokens through the paged pools
 (``module.apply({"params": ...}, toks, pools, rows, pos, valid,
-method="walk") -> (logits, pools, counts)``, prefill windows and the
-decode step alike; with it ``max_window()``, ``program_key()`` and
-``walk_stats``, the names of the counts). The model's type picks the
-path — ``dl.MaskedLMModel`` (per-head k and v pools) or
+method="walk") -> (hidden, pools, counts)``: the window's ``[S, w,
+width]`` hidden rows after the last block and NO head; prefill windows
+and the decode step alike) and for the head over the rows it names
+(``module.apply({"params": ...}, hidden_rows, method="logits") ->
+[..., V]``); with them ``max_window()``, ``program_key()`` and
+``walk_stats``, the names of the counts. Logits exist only for rows a
+token is sampled from, and each caller says which from the shapes it
+already holds: a prefill program asks for ONE row a prompt (the last
+prompt row of the chunk in which the prompt ends) and for none in a
+chunk where no prompt ends; the decode step for its one row a slot; the
+speculative verify for all ``k + 1`` rows of its window. The model's
+type picks the path — ``dl.MaskedLMModel`` (per-head k and v pools) or
 ``dl.LatentMoEDecoder`` (one latent array a layer, dropless experts) —
 and no flag does.
 
@@ -72,7 +80,8 @@ land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
 ``moe_expert_load_max``); ``gen_ttft_seconds{reuse=cold|warm}``,
-``gen_tokens_total``,
+``gen_tokens_total``, ``gen_prefill_calls_total{head=row|none}``
+(prefill program calls by what they emit),
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
 ``gen_decode_attn_seconds{phase}`` and the dense-fallback odometer
 ``kv_dense_gather_bytes_total`` here, the ``kv_*`` families in
@@ -173,7 +182,7 @@ def _dense_gather_bytes(module, n_rows: int, max_blocks: int,
 
 
 class _WalkStats:
-    """The counts a decoder's walk returns beside its logits
+    """The counts a decoder's walk returns beside its hidden rows
     (``module.walk_stats`` names them), on the engine's registry: a name
     that ends in ``_max`` is a gauge of that name (the last call's
     value), every other a counter ``<name>_total``. The executors fetch
@@ -254,18 +263,27 @@ class _PoolState:
 class PrefillExecutor:
     """Fills KV blocks for admitted prompts in padding-bucketed batches.
 
-    One compiled program per window bucket ``w``: run the paged window
-    walk (the decoder's ``walk``) over the prompt SUFFIX
-    (everything past the prefix-reused blocks) at per-row start
+    Two compiled programs per window bucket ``w``, keyed by (window,
+    emits a token). Both run the paged window walk (the decoder's
+    ``walk``, which returns hidden rows and no logits) over the prompt
+    SUFFIX (everything past the prefix-reused blocks) at per-row start
     positions — SCATTER-ONLY: each block's kv writes through the table
     as it is computed and attention reads the pools in place, no
-    ``gather_dense``/``take_positions`` round trip — and emit each
-    row's first generated token (the logits at its last prompt
-    position — TTFT is measured here). With a draft model the same
-    window also fills the DRAFT pools, so prefix-reused blocks hold
-    both models' kv consistently. ``MMLSPARK_TPU_PAGED_ATTN=0`` keeps
-    the old gather→vmapped-``decode_window``→scatter program callable
-    (every gathered byte counted ``kv_dense_gather_bytes_total``)."""
+    ``gather_dense``/``take_positions`` round trip. The one that emits
+    picks each prompt's last row of the window out of the hidden rows,
+    asks the decoder's ``logits`` for those ``[P, width]`` rows alone
+    and returns each row's first generated token (TTFT is measured
+    here): no ``[P, w, V]`` value exists. The other runs no head at
+    all and returns the pools and the walk's counts; :meth:`prefill`
+    calls it for a chunk in which no prompt of the batch ends, which it
+    knows on the host before the call
+    (``gen_prefill_calls_total{head="row"|"none"}`` counts both kinds).
+    With a draft model the same window also fills the DRAFT pools, so
+    prefix-reused blocks hold both models' kv consistently.
+    ``MMLSPARK_TPU_PAGED_ATTN=0`` keeps the old
+    gather→vmapped-``decode_window``→scatter program callable (every
+    gathered byte counted ``kv_dense_gather_bytes_total``); it has one
+    kind, which emits."""
 
     def __init__(self, module, variables, kv: PagedKVManager,
                  pools: _PoolState, *, draft_module=None,
@@ -302,13 +320,21 @@ class PrefillExecutor:
         if draft_module is not None:
             self._gather_bytes += _dense_gather_bytes(
                 draft_module, self.batch, self.max_blocks, kv.block_len)
+        self._c_calls = reg.counter(
+            "gen_prefill_calls_total",
+            "prefill program calls, by service and what the program "
+            "emits: head=row one token a prompt, head=none no head")
         self._walk_stats = _WalkStats(module, reg, service)
-        self._programs: dict[int, object] = {}
+        self._programs: dict[tuple[int, bool], object] = {}
         self._fps: dict[str, tuple[str, str]] = {}
 
-    # -- compiled program per window bucket --------------------------------
-    def _program(self, w: int):
-        prog = self._programs.get(w)
+    # -- compiled programs per window bucket -------------------------------
+    def _program(self, w: int, head: bool):
+        """The program of window ``w`` that emits each prompt's first
+        token (``head``), or the one that runs no head."""
+        # the dense fallback has one kind, which emits
+        head = head or not self.paged
+        prog = self._programs.get((w, head))
         if prog is not None:
             return prog
         import jax
@@ -321,20 +347,23 @@ class PrefillExecutor:
                     lens):
                 valid = (jnp.arange(w)[None] < lens[:, None]) & \
                     (lens[:, None] > 0)
-                logits, pools_t, counts = module.apply(
+                hidden, pools_t, counts = module.apply(
                     {"params": params}, toks, pools_t, rows, pos,
-                    valid, method="walk")               # [P, w, V]
+                    valid, method="walk")               # [P, w, W]
                 if draft is not None:
                     _, pools_d, _ = draft.apply(
                         {"params": dparams}, toks, pools_d, rows, pos,
                         valid, method="walk")
-                logits = logits.at[:, :, pad_id].set(-jnp.inf)
+                if not head:
+                    return pools_t, pools_d, None, counts
+                # the head AFTER the pick: one row a prompt
                 last = jnp.clip(lens - 1, 0, w - 1)
-                row_logits = jnp.take_along_axis(
-                    logits,
-                    last[:, None, None].repeat(logits.shape[-1], 2),
-                    axis=1)[:, 0]                       # [P, V]
-                first = jnp.argmax(row_logits, -1).astype(jnp.int32)
+                row = jnp.take_along_axis(
+                    hidden, last[:, None, None], axis=1)[:, 0]  # [P, W]
+                logits = module.apply({"params": params}, row,
+                                      method="logits")  # [P, V]
+                logits = logits.at[:, pad_id].set(-jnp.inf)
+                first = jnp.argmax(logits, -1).astype(jnp.int32)
                 return pools_t, pools_d, first, counts
         else:
             def run(params, dparams, pools_t, pools_d, rows, toks, pos,
@@ -373,12 +402,13 @@ class PrefillExecutor:
                 first = jnp.argmax(row_logits, -1).astype(jnp.int32)
                 return pools_t, pools_d, first, None
 
-        name = f"llm_prefill_{self.service}_w{w}_b{P}"
+        name = f"llm_prefill_{self.service}_w{w}_b{P}" \
+            + ("" if head else "_nohead")
         prog = compile_tracker.jit(run, name=name,
                                    **_donate_pools_kwargs())
-        self._programs[w] = prog
+        self._programs[(w, head)] = prog
         key = {"phase": "prefill", "service": self.service,
-               "window": w, "batch": P,
+               "window": w, "batch": P, "head": head,
                "attn": "paged" if self.paged else "dense",
                "max_blocks": self.max_blocks,
                "block_len": self.kv.block_len,
@@ -406,8 +436,9 @@ class PrefillExecutor:
         """``jobs``: list of ``(seq_id, prompt_tokens)`` whose chains
         are already allocated in ``kv``. Runs bucketed batches — a
         suffix wider than ``max_window`` in consecutive chunks, each
-        attending what the chunks before it wrote — commits lengths
-        (``kv.advance`` + ``kv.publish``), returns
+        attending what the chunks before it wrote, and a chunk in which
+        no prompt of the batch ends through the program with no head —
+        commits lengths (``kv.advance`` + ``kv.publish``), returns
         ``seq_id -> (first_token, suffix_len)``."""
         import jax
         import jax.numpy as jnp
@@ -441,7 +472,13 @@ class PrefillExecutor:
                         toks[i, :k] = prompt[s0 + done:s0 + done + k]
                         pos[i] = s0 + done
                         lens[i] = k
-                prog = self._program(w)
+                # known on the host before the call: whose last token
+                # is in this chunk
+                ends = [i for i, m in enumerate(metas)
+                        if done < m[3] <= done + w]
+                prog = self._program(w, bool(ends))
+                self._c_calls.inc(1, service=self.service,
+                                  head="row" if ends else "none")
                 t0 = time.perf_counter()
                 pools_t, pools_d, first, count = prog(
                     self.variables["params"],
@@ -462,9 +499,8 @@ class PrefillExecutor:
                 self.pools.target = pools_t
                 if self.draft_module is not None:
                     self.pools.draft = pools_d
-                for i, (_, _, _, n) in enumerate(metas):
-                    if done < n <= done + w:   # its last token is here
-                        firsts[i] = first
+                for i in ends:
+                    firsts[i] = first
                 done += w
             # ONE fetch: the first tokens and the calls' counts together
             firsts, counts = jax.device_get((firsts, counts))
@@ -480,13 +516,21 @@ class PrefillExecutor:
     def warm(self, windows=(1,)) -> None:
         """Compile (and run, against the trash block only) the programs
         a suffix of each given length is fed through — the warmup sweep
-        before ``compile_tracker.mark_steady()``."""
+        before ``compile_tracker.mark_steady()``. Every window gets the
+        program that emits (a prompt of the batch can end in any chunk);
+        a chunk before the last is ``max_window`` wide and may hold no
+        prompt's end, so a suffix of several chunks adds that window's
+        program with no head."""
         import jax.numpy as jnp
         P = self.batch
-        for w in sorted({w for n in windows
-                         for w in self.windows_for(n)}):
+        kinds = set()
+        for n in windows:
+            chunks = self.windows_for(n)
+            kinds.update((w, True) for w in chunks)
+            kinds.update((w, False) for w in chunks[:-1])
+        for w, head in sorted(kinds):
             rows = jnp.zeros((P, self.max_blocks), jnp.int32)
-            prog = self._program(w)
+            prog = self._program(w, head)
             args = (
                 self.variables["params"],
                 None if self.draft_module is None
@@ -511,11 +555,13 @@ class DecodeExecutor:
 
     Plain mode: ONE paged window walk of width 1 — embed the slots'
     last tokens, scatter kv through the table, paged attention over
-    each chain in place, greedy ``argmax`` with pad masked — the
+    each chain in place — then the decoder's ``logits`` over its one
+    row a slot and a greedy ``argmax`` with pad masked — the
     numerics of ``dl.generate``'s cached path with zero dense
     gathers. Spec mode (draft present): ``dl.speculative``'s
     draft/verify runs as k width-1 draft walks plus one width-(k+1)
-    target walk (the kernel's windowed variant); each slot accepts its
+    target walk (the kernel's windowed variant) whose ``k + 1`` rows
+    all get logits; each slot accepts its
     own longest agreeing prefix — no batch sync-on-min, block chains
     advance independently. ``MMLSPARK_TPU_PAGED_ATTN=0`` keeps the
     old gather→vmapped-``decode_step``→scatter program callable
@@ -613,9 +659,11 @@ class DecodeExecutor:
         if self.paged and k == 0:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
-                logits, pools_t, counts = module.apply(
+                hidden, pools_t, counts = module.apply(
                     {"params": params}, last[:, None], pools_t, rows,
-                    ptr - 1, active[:, None], method="walk")  # [S, 1, V]
+                    ptr - 1, active[:, None], method="walk")  # [S, 1, W]
+                logits = module.apply({"params": params}, hidden,
+                                      method="logits")  # every row: w = 1
                 logits = logits[:, 0].at[:, pad_id].set(-jnp.inf)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 committed = nxt[:, None]                # [S, 1]
@@ -629,9 +677,11 @@ class DecodeExecutor:
                 tok = last[:, None]                     # [S, 1]
                 drafts = []
                 for j in range(k):
-                    ld, pools_d, _ = draft.apply(
+                    hd, pools_d, _ = draft.apply(
                         {"params": dparams}, tok, pools_d, rows,
                         pos + j, av, method="walk")
+                    ld = draft.apply({"params": dparams}, hd,
+                                     method="logits")
                     ld = ld[:, 0].at[:, pad_id].set(-jnp.inf)
                     tok = jnp.argmax(ld, -1).astype(jnp.int32)[:, None]
                     drafts.append(tok[:, 0])
@@ -643,10 +693,13 @@ class DecodeExecutor:
                     av, method="walk")
                 d = jnp.stack(drafts, 1)                # [S, k]
                 window = jnp.concatenate([last[:, None], d], 1)
-                lt, pools_t, counts = module.apply(
+                ht, pools_t, counts = module.apply(
                     {"params": params}, window, pools_t, rows, pos,
                     av & jnp.ones((S, k + 1), bool),
-                    method="walk")                      # [S, k+1, V]
+                    method="walk")                      # [S, k+1, W]
+                # the verify samples from every row of its window
+                lt = module.apply({"params": params}, ht,
+                                  method="logits")      # [S, k+1, V]
                 lt = lt.at[:, :, pad_id].set(-jnp.inf)
                 t = jnp.argmax(lt, -1).astype(jnp.int32)
                 agree = jnp.cumprod(

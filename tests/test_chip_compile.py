@@ -163,8 +163,9 @@ _FREE_HBM = 16_670_000_000
 @pytest.mark.parametrize("config", ["bench_gen", "toy-serving",
                                     "toy-serving-spec", "toy-decode"])
 def test_llm_engine_programs(one_chip, steer_tpu, config):
-    """The engine's decode program and its widest prefill program, pools
-    donated and sized as the engine sizes them on the chip: half of
+    """The engine's decode program and its widest prefill programs (the
+    one that emits a token and the one with no head), pools donated and
+    sized as the engine sizes them on the chip: half of
     free HBM at ``pool_block_bytes``. ``bench_gen`` is ``chip_smoke.py``'s
     engine; the toys (1 layer, 2 heads of 16, float32) are ``bench.py``'s
     two scenarios, whose narrow blocks XLA pads 8x around the kernel —
@@ -227,15 +228,20 @@ def test_llm_engine_programs(one_chip, steer_tpu, config):
     programs = {
         "decode": engine.decoder._build().lower(
             params, draft[0], pools, draft[1], i32(S, MB), i32(S),
-            i32(S), i32(S), _sds((S,), jnp.bool_, one_chip)),
-        f"prefill_w{w}": engine.prefiller._program(w).lower(
-            params, draft[0], pools, draft[1], i32(P, MB), i32(P, w),
-            i32(P), i32(P))}
+            i32(S), i32(S), _sds((S,), jnp.bool_, one_chip))}
+    for head in (True, False):
+        programs[f"prefill_w{w}_head{head:d}"] = \
+            engine.prefiller._program(w, head).lower(
+                params, draft[0], pools, draft[1], i32(P, MB), i32(P, w),
+                i32(P), i32(P))
     at_rest = sum(np.prod(a.shape) * a.dtype.itemsize
                   for a in jax.tree.leaves(pools)) * (2 if spec else 1)
     for name, lowered in programs.items():
         compiled = lowered.compile()
-        assert compiled.as_text().count(KERNEL) >= enc.depth, name
+        # with no head nothing reads the last block's attention: the
+        # compiler drops that kernel with the block's feed-forward
+        kernels = enc.depth - name.endswith("head0")
+        assert compiled.as_text().count(KERNEL) >= kernels, name
         mem = compiled.memory_analysis()
         # donated: the pools come back in the buffers they arrived in
         assert mem.alias_size_in_bytes >= at_rest, name
@@ -246,7 +252,8 @@ def test_llm_engine_programs(one_chip, steer_tpu, config):
 
 
 def test_latent_moe_engine_programs(one_chip, steer_tpu):
-    """The engine's decode program and its widest prefill program for
+    """The engine's decode program and its widest prefill programs (the
+    one that emits a token and the one with no head) for
     the latent-attention / expert decoder at the benchmark cell's own
     sizes (``benchmark/configs/deepseek-v2.json``, 128 slots, chains of
     34 blocks of 512, a pool of 384): five latent kernels each, the pools
@@ -294,10 +301,12 @@ def test_latent_moe_engine_programs(one_chip, steer_tpu):
     programs = {
         "decode": engine.decoder._build().lower(
             weights, None, pools, None, i32(S, MB), i32(S), i32(S),
-            i32(S), _sds((S,), jnp.bool_, one_chip)),
-        f"prefill_w{w}": engine.prefiller._program(w).lower(
-            weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
-            i32(P))}
+            i32(S), _sds((S,), jnp.bool_, one_chip))}
+    for head in (True, False):
+        programs[f"prefill_w{w}_head{head:d}"] = \
+            engine.prefiller._program(w, head).lower(
+                weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
+                i32(P))
     at_rest = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                   for a in jax.tree.leaves(pools))
     assert at_rest == num_blocks * 512 * 640 * 2 * 5
@@ -311,6 +320,66 @@ def test_latent_moe_engine_programs(one_chip, steer_tpu):
         assert mem.temp_size_in_bytes < 100e6, (name,
                                                 mem.temp_size_in_bytes)
         assert mem.argument_size_in_bytes < 11.7e9, name
+
+
+def test_xglm_prefill_programs_hold_no_window_of_logits(one_chip,
+                                                       steer_tpu):
+    """The widest prefill programs of the benchmark's XGLM-1.7B engine
+    (``benchmark/configs/xglm-1.7b.json``: 24 blocks, a 256,008-row head,
+    one prompt a call, 192 rows): the one that emits computes the head
+    for ONE row, so its temporaries stay far under the 197 MB that
+    ``[192, 256008]`` float32 logits take (with the head over every row
+    they were 226 MB), and the one with no head does not even take the
+    head's matrix."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run
+    from mmlspark_tpu.obs.metrics import MetricsRegistry
+
+    _, wl, cfg, params = run.load_cell("xglm-1.7b.generate",
+                                       run.load_bench())
+    driver = run._load_module("drivers", wl["driver"])
+    num_blocks = int(params["engine"]["num_blocks"])
+    small = {**params, "engine": {**params["engine"], "num_blocks": 4}}
+    engine = driver.build_engine(cfg, small, {"params": None},
+                                 MetricsRegistry())
+    dtype = jnp.dtype(cfg["cache_dtype"])
+    shapes = jax.eval_shape(lambda: engine.module.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))
+    weights = jax.tree.map(lambda a: _sds(a.shape, dtype, one_chip),
+                           shapes["params"])
+    pools = jax.tree.map(
+        lambda a: _sds((num_blocks,) + a.shape[1:], a.dtype, one_chip),
+        engine.pools.target)
+    MB, P, w = engine.max_blocks, engine.prefiller.batch, \
+        engine.prefiller.max_window
+    assert (MB, P, w) == (14, 1, 192)
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    vocab = int(cfg["vocab_size"])
+    head_bytes = int(cfg["d_model"]) * vocab * dtype.itemsize
+    mem = {}
+    for head in (True, False):
+        compiled = engine.prefiller._program(w, head).lower(
+            weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
+            i32(P)).compile()
+        # with no head nothing reads the last block's attention: the
+        # compiler drops that kernel with the block's feed-forward
+        assert compiled.as_text().count(KERNEL) == 23 + head, head
+        mem[head] = compiled.memory_analysis()
+    window_of_logits = w * vocab * 4
+    assert mem[True].temp_size_in_bytes < window_of_logits // 2
+    assert mem[False].temp_size_in_bytes <= mem[True].temp_size_in_bytes
+    assert mem[False].argument_size_in_bytes \
+        <= mem[True].argument_size_in_bytes - head_bytes
 
 
 def test_gbdt_boosting_step(one_chip, steer_tpu, monkeypatch):

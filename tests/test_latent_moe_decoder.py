@@ -55,7 +55,8 @@ def _rows_for(chains, max_blocks):
 def test_prefill_then_decode_through_the_pool_matches_the_reference(model):
     """Two sequences of different lengths: a prefill window each (one
     program call, the shorter padded), then decode steps of width 1, the
-    logits of every fed row against the reference's full forward pass.
+    logits of every fed row (``logits`` over every hidden row a ``walk``
+    returns) against the reference's full forward pass.
     The walk computes attention absorbed, the reference published: in
     float32 the two agree to rounding."""
     cfg, weights, module = model
@@ -71,17 +72,24 @@ def test_prefill_then_decode_through_the_pool_matches_the_reference(model):
         toks[i, :p] = s[:p]
     lens = jnp.asarray(prefix)
     valid = jnp.arange(w)[None] < lens[:, None]
-    logits, pools, counts = module.apply(
+
+    def head(hidden):
+        return module.apply({"params": weights}, hidden, method="logits")
+
+    hidden, pools, counts = module.apply(
         {"params": weights}, jnp.asarray(toks), pools, rows,
         jnp.zeros(2, jnp.int32), valid)
+    assert hidden.shape == (2, w, 64)        # hidden rows, no head
+    logits = head(hidden)
     assert counts.shape == (4,) and int(counts[0]) > 0
     got = [[np.asarray(logits[i, :p])] for i, p in enumerate(prefix)]
     for step in range(5):
         pos = jnp.asarray([p + step for p in prefix], jnp.int32)
         tok = jnp.asarray([[s[p + step]] for s, p in zip(seqs, prefix)])
-        logits, pools, _ = module.apply(
+        hidden, pools, _ = module.apply(
             {"params": weights}, tok, pools, rows, pos,
             jnp.ones((2, 1), bool))
+        logits = head(hidden)
         for i in range(2):
             got[i].append(np.asarray(logits[i]))
     for i, (s, p) in enumerate(zip(seqs, prefix)):

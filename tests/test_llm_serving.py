@@ -181,7 +181,7 @@ class TestChunkedPrefill:
             np.testing.assert_array_equal(got[i],
                                           ref[i][:len(p) + MAXNEW])
         # no window wider than the cap was ever built
-        assert max(eng.prefiller._programs) <= 8
+        assert max(w for w, _ in eng.prefiller._programs) <= 8
 
     def test_chunk_after_reused_prefix_and_steady_state(self, lm,
                                                         narrow):
@@ -204,9 +204,163 @@ class TestChunkedPrefill:
             compile_tracker.unmark_steady()
         np.testing.assert_array_equal(cold, ref[0][:len(p) + MAXNEW])
         np.testing.assert_array_equal(warm, cold)
+        # a chunk before the last may hold no prompt's end: the widest
+        # window also has its program with no head
         assert {n for n in fps if "prefill" in n} == {
             "llm_prefill_llmchunk_w1_b2", "llm_prefill_llmchunk_w4_b2",
-            "llm_prefill_llmchunk_w8_b2"}
+            "llm_prefill_llmchunk_w8_b2",
+            "llm_prefill_llmchunk_w8_b2_nohead"}
+
+
+# -- the head runs only at the row a token is sampled from ------------------
+
+HEAD_VOCAB = {"per_head": 37, "latent_moe": 251}   # no other size of theirs
+
+
+@pytest.fixture(scope="module", params=sorted(HEAD_VOCAB))
+def decoder(request):
+    """``(module, variables, vocabulary)`` of each decoder the engine
+    takes, tiny, with a vocabulary no other of its sizes equals (so a
+    shape that holds it is a shape of logits)."""
+    vocab = HEAD_VOCAB[request.param]
+    if request.param == "per_head":
+        module = MaskedLMModel(TextEncoder(
+            vocab=vocab, width=16, depth=2, heads=2, mlp_dim=24,
+            dtype=jnp.float32,
+            attention_fn=make_attention_fn("dense", causal=True)))
+        variables = module.init(jax.random.PRNGKey(1),
+                                np.zeros((1, 8), np.int32))
+        return module, variables, vocab
+    from test_latent_moe_decoder import ref, small_cfg
+    from mmlspark_tpu.dl.latent_moe_decoder import LatentMoEDecoder
+    cfg = small_cfg(vocab_size=vocab)
+    return (LatentMoEDecoder(cfg, dtype=jnp.float32),
+            {"params": ref.make_weights(cfg, 5)}, vocab)
+
+
+def _head_engine(decoder, reg, **kw):
+    """Blocks of 4, and the widest window steered down to 8 (it is
+    VMEM-bound on the chip) so that tiny prompts chunk."""
+    module, variables, _ = decoder
+    eng = LLMEngine(module, variables, block_len=4, max_seq_len=32,
+                    num_blocks=24, hbm_fraction=1.0, service="llmhead",
+                    registry=reg, **kw)
+    eng.prefiller.max_window = 8
+    return eng
+
+
+def _last_row_argmax(decoder, prompt):
+    """Argmax (pad masked) of the last prompt row of the ALL-ROWS
+    logits: the whole prompt in one window through a fresh pool, the
+    head over every row."""
+    from mmlspark_tpu.dl.paged_kv import init_pools
+    module, variables, _ = decoder
+    n = len(prompt)
+    blocks = -(-n // 4)
+    hidden, _, _ = module.apply(
+        variables, jnp.asarray(prompt)[None],
+        init_pools(module.cache_spec(), blocks + 1, 4),
+        jnp.arange(1, blocks + 1, dtype=jnp.int32)[None],
+        jnp.zeros(1, jnp.int32), jnp.ones((1, n), bool), method="walk")
+    logits = np.array(module.apply(variables, hidden, method="logits"))
+    assert logits.shape[:2] == (1, n)
+    logits[..., 0] = -np.inf
+    return int(logits[0, n - 1].argmax())
+
+
+def _prefill_calls(reg, service="llmhead"):
+    """``(calls that emit, calls with no head)`` of the prefill programs."""
+    calls = next(m for m in reg.metrics("gen_prefill_calls_total")
+                 if m.name == "gen_prefill_calls_total")
+    return (calls.value(service=service, head="row"),
+            calls.value(service=service, head="none"))
+
+
+#: case -> (prompt lengths of one prefill batch, program calls that emit,
+#: program calls with no head); windows are 8 wide
+HEAD_CASES = {
+    "ends_mid_chunk": ((13,), 1, 1),
+    "ends_on_a_chunks_last_row": ((16,), 1, 1),
+    "three_chunks": ((21,), 1, 2),
+    "batch_of_two_ending_in_different_chunks": ((5, 21), 2, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_first_token_is_the_argmax_of_the_last_prompt_row(decoder, case):
+    sizes, n_row, n_none = HEAD_CASES[case]
+    vocab = decoder[2]
+    rng = np.random.default_rng(23)
+    prompts = [rng.integers(1, vocab, size=n).astype(np.int32)
+               for n in sizes]
+    reg = MetricsRegistry()
+    eng = _head_engine(decoder, reg, slots=2, prefill_batch=2)
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, 2)
+    got = eng.run_until_drained()
+    for i, p in enumerate(prompts):
+        assert int(got[i][len(p)]) == _last_row_argmax(decoder, p)
+    assert _prefill_calls(reg) == (n_row, n_none)
+
+
+def test_first_token_after_a_fully_reused_prefix(decoder):
+    """The second time the whole prompt is in the prefix index and its
+    last token alone is fed again (``s0 = prompt_len - 1``): one call of
+    the 1-row program, which emits."""
+    vocab = decoder[2]
+    p = np.random.default_rng(29).integers(1, vocab, 16).astype(np.int32)
+    reg = MetricsRegistry()
+    eng = _head_engine(decoder, reg, slots=1, prefill_batch=1)
+    want = _last_row_argmax(decoder, p)
+    eng.submit("cold", p, 2)
+    assert int(eng.run_until_drained()["cold"][16]) == want
+    assert _prefill_calls(reg) == (1, 1)
+    eng.submit("warm", p, 2)
+    assert int(eng.run_until_drained()["warm"][16]) == want
+    assert _prefill_calls(reg) == (2, 1)
+    assert (1, True) in eng.prefiller._programs
+
+
+def _made_shapes(jaxpr):
+    """The shape of every value an equation makes, nested programs
+    included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(getattr(var.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _made_shapes(sub)
+
+
+def test_no_prefill_program_holds_a_window_of_logits(decoder):
+    """The program that emits makes ``[P, V]`` logits and nothing with
+    both the window and the vocabulary among its sizes; the program with
+    no head makes and returns nothing vocabulary-sized at all."""
+    module, variables, vocab = decoder
+    eng = _head_engine(decoder, MetricsRegistry(), slots=1,
+                       prefill_batch=2)
+    w, P = 8, 2
+    args = (variables["params"], None, eng.pools.target, None,
+            jnp.zeros((P, eng.max_blocks), jnp.int32),
+            jnp.zeros((P, w), jnp.int32), jnp.zeros(P, jnp.int32),
+            jnp.ones(P, jnp.int32))
+    emits = eng.prefiller._program(w, True).trace(*args).jaxpr
+    made = set(_made_shapes(emits.jaxpr))
+    assert (P, vocab) in made
+    assert not [s for s in made if vocab in s and w in s]
+    # one row of logits a prompt, no more
+    assert all(np.prod(s) <= P * vocab for s in made if vocab in s)
+    quiet = eng.prefiller._program(w, False).trace(*args).jaxpr
+    assert not [s for s in _made_shapes(quiet.jaxpr) if vocab in s]
+    assert not [v for v in quiet.jaxpr.outvars
+                if vocab in getattr(v.aval, "shape", ())]
+    # and the decode step keeps its one row a slot
+    S = eng.decoder.slots
+    step = eng.decoder._build().trace(
+        variables["params"], None, eng.pools.target, None,
+        jnp.zeros((S, eng.max_blocks), jnp.int32), jnp.zeros(S, jnp.int32),
+        jnp.ones(S, jnp.int32), jnp.full(S, 2, jnp.int32),
+        jnp.zeros(S, bool)).jaxpr
+    assert (S, 1, vocab) in set(_made_shapes(step.jaxpr))
 
 
 class TestPoolSizing:
@@ -321,6 +475,39 @@ class TestSteadyState:
                             "llm_prefill_llmsteady_w8_b2"}
         for static_fp, full_fp in fps.values():
             assert static_fp and full_fp
+
+    def test_warmed_worker_serves_chunked_prompts_with_zero_compiles(
+            self, lm):
+        """A prompt of three chunks: ``warm`` built both kinds of the
+        widest window's program, nothing compiles afterwards, and the
+        calls split as the host knew before each call."""
+        module, variables = lm
+        reg = MetricsRegistry()
+        eng = LLMEngine(module, variables, slots=1, block_len=4,
+                        max_seq_len=32, prefill_batch=1,
+                        service="llmsteady3", registry=reg)
+        eng.prefiller.max_window = 8     # VMEM-bound on the chip
+        # 21 -> none, none, row; 5 -> row; 16 -> none, row
+        prompts = _prompts(seed=31, sizes=(21, 5, 16))
+        ref = _ref(lm, prompts)
+        fps = eng.warm(prefill_windows=(21, 5, 16), mark_steady=True)
+        try:
+            assert {(8, True), (8, False)} <= set(eng.prefiller._programs)
+            for i, p in enumerate(prompts):
+                eng.submit(i, p, MAXNEW)
+            got = eng.run_until_drained()
+            compile_tracker.assert_steady_state()
+        finally:
+            compile_tracker.unmark_steady()
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(got[i],
+                                          ref[i][:len(p) + MAXNEW])
+        assert set(fps) == {"llm_decode_paged_llmsteady3_S1_k0",
+                            "llm_prefill_llmsteady3_w8_b1",
+                            "llm_prefill_llmsteady3_w8_b1_nohead"}
+        assert fps["llm_prefill_llmsteady3_w8_b1"] != \
+            fps["llm_prefill_llmsteady3_w8_b1_nohead"]
+        assert _prefill_calls(reg, "llmsteady3") == (3, 3)
 
 
 class TestScenarioAndLoadgen:
