@@ -1762,12 +1762,8 @@ def bench_llm_decode(extras: dict) -> None:
     """Long-context decode throughput of the paged kernel, in-process on
     the acquired device (``testing.benchmarks.llm_decode_scenario``:
     >=4k tokens of resident KV, decode-only timed window,
-    CompileTracker steady state). ``llm_decode_paged_gather_bytes``
-    must be exactly 0 — steady paged decode never re-materialises the
-    dense cache. The dense re-gather comparison needed a second process
-    with ``MMLSPARK_TPU_PAGED_ATTN=0`` and went with it: the chip
-    belongs to one process, and a path chosen by a switch is ROADMAP
-    D2's to delete. The platform rides in ``llm_decode_platform``."""
+    CompileTracker steady state). The platform rides in
+    ``llm_decode_platform``."""
     import jax
 
     from mmlspark_tpu.obs.metrics import MetricsRegistry
@@ -1779,8 +1775,6 @@ def bench_llm_decode(extras: dict) -> None:
     extras["llm_decode_context_tokens"] = paged["context_tokens"]
     extras["llm_decode_tokens_per_sec"] = round(
         paged["tokens_per_s"], 1)
-    extras["llm_decode_paged_gather_bytes"] = paged[
-        "dense_gather_bytes"]
     extras["llm_decode_attn_ms_per_step"] = round(
         paged["attn_ms_per_step"], 3)
     extras["llm_decode_steady_state_ok"] = bool(

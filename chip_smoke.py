@@ -623,14 +623,9 @@ def phase_llm(rec: dict, *, vocab: int = 32768, width: int = 512,
                decode_kernel_calls=decode_text.count(KERNEL),
                decode_steps=int(total("gen_decode_steps_total")),
                prefix_hits=int(total("kv_prefix_hits_total")),
-               tokens_reused=int(total("kv_prefix_tokens_reused_total")),
-               dense_gather_bytes=int(
-                   total("kv_dense_gather_bytes_total")))
+               tokens_reused=int(total("kv_prefix_tokens_reused_total")))
     _require(set(done) == set(range(n_prompts)),
              f"engine finished {sorted(done)} of {n_prompts} sequences")
-    _require(rec["dense_gather_bytes"] == 0,
-             "the dense re-gather fallback ran "
-             f"({rec['dense_gather_bytes']} bytes)")
     _require(rec["prefix_hits"] > 0, "no prefix-cache hit")
     if _on_tpu():
         _require(rec["decode_kernel_calls"] >= 1,

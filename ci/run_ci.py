@@ -265,11 +265,6 @@ def style() -> int:
         "assert m.allocate('b', list(range(1, 9))).reused_tokens == 8\n"
         "assert m.block_rows(['b', None], 3).shape == (2, 3)\n"
         "assert blocks_for_hbm_budget(1024, default=5) >= 0\n"
-        # the paged-attention kill switch is control-plane too: the
-        # executors read it at init on machines with no device, and
-        # consulting it must not drag in the Pallas kernel module
-        "from mmlspark_tpu.dl.paged_kv import paged_attention_enabled\n"
-        "assert paged_attention_enabled() in (True, False)\n"
         "assert 'mmlspark_tpu.dl.pallas_paged_attention' not in "
         "sys.modules, 'paged kernel imported eagerly'\n"
         "assert 'jax' not in sys.modules, 'kv bookkeeping pulled jax'\n"

@@ -22,16 +22,15 @@ Two halves, same split as continuous batching
   a block budget derived from the live HBM headroom (``obs.memory``).
   Importable and testable with no device — the serving control plane
   runs it from handler threads (CI style smoke asserts no jax).
-- **Device half (lazy jax imports)**: pool init plus the gather/scatter
-  bridges the prefill/decode executors (``serving.llm``) jit around the
-  existing ``MaskedLMModel.prefill/decode_step/decode_window`` numerics
-  — the paged path reuses the exact attention math ``dl.generate`` is
-  equivalence-tested against, so paged decode stays greedy-identical.
+- **Device half (lazy jax imports)**: pool init from a decoder's
+  ``cache_spec()``, what a block of it costs at rest, and the scatter
+  of a window's new cache rows through the block table. Attention reads
+  the pools in place (``dl.pallas_paged_attention``).
 
 Block 0 is RESERVED as the trash block: padded batch rows and inactive
 slots point their block-table entries at it, so fixed-shape device
 programs can always write "somewhere" without corrupting a live
-sequence (gathers from it are masked by sequence length).
+sequence (reads of it are masked by sequence length).
 
 Obs families (federated fleet-wide, recorded by the history plane):
 ``kv_blocks_used`` / ``kv_blocks_free`` / ``kv_blocks_cached`` gauges,
@@ -42,7 +41,6 @@ Obs families (federated fleet-wide, recorded by the history plane):
 from __future__ import annotations
 
 import hashlib
-import os
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 
@@ -52,24 +50,11 @@ from ..obs import registry as _default_registry
 
 __all__ = ["PagedKVManager", "SequenceHandle", "OutOfBlocks",
            "blocks_for_hbm_budget", "pool_block_bytes", "init_pools",
-           "gather_dense", "paged_attention_enabled",
-           "scatter_positions", "take_positions"]
+           "scatter_positions"]
 
 #: the reserved trash block — device programs route padded/inactive
 #: writes here; the host half never hands it to a sequence
 TRASH_BLOCK = 0
-
-
-def paged_attention_enabled() -> bool:
-    """Kill switch for the paged-attention decode kernel
-    (``dl.pallas_paged_attention``): ``MMLSPARK_TPU_PAGED_ATTN=0``
-    routes the serving executors back through the dense
-    ``gather_dense`` round-trip (same escape-hatch pattern as
-    ``MMLSPARK_TPU_COSTMODEL=0``). The fallback is loud:
-    ``kv_dense_gather_bytes_total`` counts every byte it re-gathers,
-    and reads 0 when the kernel path is live. JAX-free on purpose —
-    the bookkeeping half stays importable without a backend."""
-    return os.environ.get("MMLSPARK_TPU_PAGED_ATTN", "1") != "0"
 
 
 class OutOfBlocks(RuntimeError):
@@ -544,57 +529,6 @@ def _flat_positions(rows, pos, block_len: int):
     bi = jnp.clip(pos // block_len, 0, rows.shape[1] - 1)   # [S, w]
     block = jnp.take_along_axis(rows, bi, axis=1)           # [S, w]
     return block * block_len + pos % block_len
-
-
-def gather_dense(pools, rows):
-    """Gather each slot's chained blocks into dense per-layer caches
-    ``[S, heads, max_blocks*block_len, head_dim]`` — the exact cache
-    layout ``MaskedLMModel.decode_step/decode_window`` run over, so the
-    paged path reuses their (equivalence-tested) attention math
-    unchanged. Positions ≥ the slot's length hold stale/trash data; the
-    decode mask (``arange < pos``) never attends them.
-
-    DEPRECATION SEAM: the serving executors no longer call this per
-    step — ``dl.pallas_paged_attention`` reads the pools in place. It
-    stays callable behind ``MMLSPARK_TPU_PAGED_ATTN=0``
-    (:func:`paged_attention_enabled`), where every re-gathered byte is
-    counted in ``kv_dense_gather_bytes_total``."""
-    import jax.numpy as jnp
-    S, MB = rows.shape
-    out = []
-    for k_pool, v_pool in pools:
-        NB, BL, H, hd = k_pool.shape
-        flat_k = k_pool.reshape(NB * BL, H, hd)
-        flat_v = v_pool.reshape(NB * BL, H, hd)
-        idx = (rows[:, :, None] * BL
-               + jnp.arange(BL)[None, None, :]).reshape(S, MB * BL)
-        k = jnp.transpose(flat_k[idx], (0, 2, 1, 3))   # [S, H, L, hd]
-        v = jnp.transpose(flat_v[idx], (0, 2, 1, 3))
-        out.append((k, v))
-    return tuple(out)
-
-
-def take_positions(dense, pos):
-    """Extract the kv written at absolute positions ``pos`` ([S, w])
-    from dense caches ``[S, H, L, hd]`` -> per-layer ``[S, w, H, hd]``
-    (the delta the device step scatters back into the pools).
-
-    DEPRECATION SEAM: only the ``MMLSPARK_TPU_PAGED_ATTN=0`` fallback
-    executors still round-trip through this — the paged-attention path
-    computes layer kv directly and scatters once."""
-    import jax.numpy as jnp
-    out = []
-    for k, v in dense:
-        idx = pos[:, None, :, None]                     # [S, 1, w, 1]
-        kw = jnp.take_along_axis(
-            k, jnp.broadcast_to(idx, (k.shape[0], k.shape[1],
-                                      pos.shape[1], k.shape[3])), axis=2)
-        vw = jnp.take_along_axis(
-            v, jnp.broadcast_to(idx, (v.shape[0], v.shape[1],
-                                      pos.shape[1], v.shape[3])), axis=2)
-        out.append((jnp.transpose(kw, (0, 2, 1, 3)),
-                    jnp.transpose(vw, (0, 2, 1, 3))))   # [S, w, H, hd]
-    return tuple(out)
 
 
 def scatter_positions(pools, rows, pos, new_kv, valid=None):

@@ -1,10 +1,10 @@
 """Pallas TPU kernel: paged decode attention over the block table.
 
-The LLM serving engine's hot op. The PR 15 executors materialize each
-slot's whole KV history with ``paged_kv.gather_dense`` before every
-decode step — O(context) HBM traffic per generated token and a second
-resident copy of the KV working set, exactly the bandwidth the paged
-pool exists to save. This kernel reads the fixed per-layer pools
+The LLM serving engine's hot op: attention for a window of query rows
+per slot over that slot's chain of KV blocks, read where they rest. The
+KV working set is resident once and a step's HBM traffic is the chains'
+own blocks: no dense copy of a slot's history is made, before the step
+or inside it. The kernel reads the fixed per-layer pools
 ``[num_blocks, block_len, heads, head_dim]`` IN PLACE:
 
     grid = (slots/slots_tile, slots_tile, max_blocks), blocks innermost
@@ -49,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.compat import tpu_compiler_params as _CompilerParams
 from ..utils.platform import target_platform
-from .paged_kv import TRASH_BLOCK, paged_attention_enabled  # noqa: F401
+from .paged_kv import TRASH_BLOCK
 
 _NEG = -1e30  # additive mask value; -inf breaks the running-max algebra
 

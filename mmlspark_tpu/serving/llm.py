@@ -22,12 +22,9 @@ over the paged KV pool (``dl.paged_kv``):
   block table (``dl.pallas_paged_attention`` — the Pallas kernel on
   TPU, its bit-exact lax reference on CPU): each step embeds the
   slots' tokens, scatters the new kv through the table, and attends
-  each slot's own chain with no dense gather — the
-  ``gather_dense``-per-step round trip of the first cut is gone
-  (``MMLSPARK_TPU_PAGED_ATTN=0`` brings it back, loudly:
-  ``kv_dense_gather_bytes_total`` counts every re-gathered byte and
-  reads 0 on the paged path). Greedy output stays token-identical to
-  ``dl.generate`` (pinned by test). With a draft model,
+  each slot's own chain: no dense copy of a chain exists. Greedy
+  output stays token-identical to ``dl.generate`` (pinned by test).
+  With a draft model,
   ``dl.speculative``'s draft/verify window runs PER SLOT: each slot
   accepts its own longest agreeing prefix (no batch sync-on-min —
   block chains advance independently), so accepted bursts move a slot
@@ -83,8 +80,7 @@ counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``gen_tokens_total``, ``gen_prefill_calls_total{head=row|none}``
 (prefill program calls by what they emit),
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
-``gen_decode_attn_seconds{phase}`` and the dense-fallback odometer
-``kv_dense_gather_bytes_total`` here, the ``kv_*`` families in
+``gen_decode_attn_seconds{phase}`` here, the ``kv_*`` families in
 ``dl.paged_kv`` — all federated fleet-wide and recorded by the
 telemetry history plane. Completions land FeatureLog rows with
 ``decode_steps``/``prefill_tokens``/``context_blocks`` so the cost
@@ -102,10 +98,8 @@ import numpy as np
 
 from ..core import aot
 from ..dl.paged_kv import (OutOfBlocks, PagedKVManager,
-                           blocks_for_hbm_budget, gather_dense,
-                           init_pools, paged_attention_enabled,
-                           pool_block_bytes, scatter_positions,
-                           take_positions)
+                           blocks_for_hbm_budget, init_pools,
+                           pool_block_bytes)
 from ..obs import registry as _default_registry
 from ..obs.attribution import cost_attribution
 from ..obs.profile import compile_tracker, feature_log
@@ -170,15 +164,12 @@ def _donate_pools_kwargs() -> dict:
     return {}
 
 
-def _dense_gather_bytes(module, n_rows: int, max_blocks: int,
-                        block_len: int) -> int:
-    """Bytes ONE ``gather_dense`` over ``n_rows`` chains materializes
-    for ``module``'s pools — what the ``MMLSPARK_TPU_PAGED_ATTN=0``
-    fallback moves per call and the paged path doesn't."""
-    per_token = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
-                    for layer in module.cache_spec()
-                    for shape, dtype in layer)
-    return int(n_rows * max_blocks * block_len * per_token)
+def _greedy(logits, pad_id: int):
+    """The greedy pick every program samples with: ``argmax`` over the
+    vocabulary with the pad column masked out, as int32."""
+    import jax.numpy as jnp
+    logits = logits.at[..., pad_id].set(-jnp.inf)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class _WalkStats:
@@ -268,22 +259,17 @@ class PrefillExecutor:
     ``walk``, which returns hidden rows and no logits) over the prompt
     SUFFIX (everything past the prefix-reused blocks) at per-row start
     positions — SCATTER-ONLY: each block's kv writes through the table
-    as it is computed and attention reads the pools in place, no
-    ``gather_dense``/``take_positions`` round trip. The one that emits
-    picks each prompt's last row of the window out of the hidden rows,
-    asks the decoder's ``logits`` for those ``[P, width]`` rows alone
-    and returns each row's first generated token (TTFT is measured
-    here): no ``[P, w, V]`` value exists. The other runs no head at
+    as it is computed and attention reads the pools in place. The one
+    that emits picks each prompt's last row of the window out of the
+    hidden rows, asks the decoder's ``logits`` for those ``[P, width]``
+    rows alone and returns each row's first generated token (TTFT is
+    measured here): no ``[P, w, V]`` value exists. The other runs no head at
     all and returns the pools and the walk's counts; :meth:`prefill`
     calls it for a chunk in which no prompt of the batch ends, which it
     knows on the host before the call
     (``gen_prefill_calls_total{head="row"|"none"}`` counts both kinds).
     With a draft model the same window also fills the DRAFT pools, so
-    prefix-reused blocks hold both models' kv consistently.
-    ``MMLSPARK_TPU_PAGED_ATTN=0`` keeps the old
-    gather→vmapped-``decode_window``→scatter program callable (every
-    gathered byte counted ``kv_dense_gather_bytes_total``); it has one
-    kind, which emits."""
+    prefix-reused blocks hold both models' kv consistently."""
 
     def __init__(self, module, variables, kv: PagedKVManager,
                  pools: _PoolState, *, draft_module=None,
@@ -299,7 +285,6 @@ class PrefillExecutor:
         self.batch = max(int(batch), 1)
         self.pad_id = int(pad_id)
         self.service = service
-        self.paged = paged_attention_enabled()
         # the widest window every model's walk takes: a longer suffix
         # is fed in chunks of this width
         self.max_window = min(
@@ -311,15 +296,6 @@ class PrefillExecutor:
             "attention-program wall time, by service and phase",
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1,
                      .25, .5, 1., 2.5))
-        self._c_gather = reg.counter(
-            "kv_dense_gather_bytes_total",
-            "bytes materialized by gather_dense in the dense-attention "
-            "fallback (0 on the paged-kernel path), by service/phase")
-        self._gather_bytes = _dense_gather_bytes(
-            module, self.batch, self.max_blocks, kv.block_len)
-        if draft_module is not None:
-            self._gather_bytes += _dense_gather_bytes(
-                draft_module, self.batch, self.max_blocks, kv.block_len)
         self._c_calls = reg.counter(
             "gen_prefill_calls_total",
             "prefill program calls, by service and what the program "
@@ -332,75 +308,32 @@ class PrefillExecutor:
     def _program(self, w: int, head: bool):
         """The program of window ``w`` that emits each prompt's first
         token (``head``), or the one that runs no head."""
-        # the dense fallback has one kind, which emits
-        head = head or not self.paged
         prog = self._programs.get((w, head))
         if prog is not None:
             return prog
-        import jax
         import jax.numpy as jnp
         module, draft = self.module, self.draft_module
         pad_id, P = self.pad_id, self.batch
 
-        if self.paged:
-            def run(params, dparams, pools_t, pools_d, rows, toks, pos,
-                    lens):
-                valid = (jnp.arange(w)[None] < lens[:, None]) & \
-                    (lens[:, None] > 0)
-                hidden, pools_t, counts = module.apply(
-                    {"params": params}, toks, pools_t, rows, pos,
-                    valid, method="walk")               # [P, w, W]
-                if draft is not None:
-                    _, pools_d, _ = draft.apply(
-                        {"params": dparams}, toks, pools_d, rows, pos,
-                        valid, method="walk")
-                if not head:
-                    return pools_t, pools_d, None, counts
-                # the head AFTER the pick: one row a prompt
-                last = jnp.clip(lens - 1, 0, w - 1)
-                row = jnp.take_along_axis(
-                    hidden, last[:, None, None], axis=1)[:, 0]  # [P, W]
-                logits = module.apply({"params": params}, row,
-                                      method="logits")  # [P, V]
-                logits = logits.at[:, pad_id].set(-jnp.inf)
-                first = jnp.argmax(logits, -1).astype(jnp.int32)
-                return pools_t, pools_d, first, counts
-        else:
-            def run(params, dparams, pools_t, pools_d, rows, toks, pos,
-                    lens):
-                dense_t = gather_dense(pools_t, rows)
-
-                def one(mod, prm, tk, cache, p):
-                    c = jax.tree.map(lambda a: a[None], cache)
-                    logits, c = mod.apply({"params": prm}, tk[None], c,
-                                          p, method="decode_window")
-                    return logits[0], jax.tree.map(lambda a: a[0], c)
-
-                logits, dense_t = jax.vmap(
-                    lambda tk, c, p: one(module, params, tk, c, p)
-                )(toks, dense_t, pos)                   # [P, w, V]
-                wrote = pos[:, None] + jnp.arange(w)[None]  # [P, w]
-                valid = (jnp.arange(w)[None] < lens[:, None]) & \
-                    (lens[:, None] > 0)
-                new_kv = take_positions(dense_t, wrote)
-                pools_t = scatter_positions(pools_t, rows, wrote,
-                                            new_kv, valid=valid)
-                if draft is not None:
-                    dense_d = gather_dense(pools_d, rows)
-                    _, dense_d = jax.vmap(
-                        lambda tk, c, p: one(draft, dparams, tk, c, p)
-                    )(toks, dense_d, pos)
-                    pools_d = scatter_positions(
-                        pools_d, rows, wrote,
-                        take_positions(dense_d, wrote), valid=valid)
-                logits = logits.at[:, :, pad_id].set(-jnp.inf)
-                last = jnp.clip(lens - 1, 0, w - 1)
-                row_logits = jnp.take_along_axis(
-                    logits,
-                    last[:, None, None].repeat(logits.shape[-1], 2),
-                    axis=1)[:, 0]                       # [P, V]
-                first = jnp.argmax(row_logits, -1).astype(jnp.int32)
-                return pools_t, pools_d, first, None
+        def run(params, dparams, pools_t, pools_d, rows, toks, pos, lens):
+            valid = (jnp.arange(w)[None] < lens[:, None]) & \
+                (lens[:, None] > 0)
+            hidden, pools_t, counts = module.apply(
+                {"params": params}, toks, pools_t, rows, pos, valid,
+                method="walk")                          # [P, w, W]
+            if draft is not None:
+                _, pools_d, _ = draft.apply(
+                    {"params": dparams}, toks, pools_d, rows, pos, valid,
+                    method="walk")
+            if not head:
+                return pools_t, pools_d, None, counts
+            # the head AFTER the pick: one row a prompt
+            last = jnp.clip(lens - 1, 0, w - 1)
+            row = jnp.take_along_axis(
+                hidden, last[:, None, None], axis=1)[:, 0]      # [P, W]
+            logits = module.apply({"params": params}, row,
+                                  method="logits")      # [P, V]
+            return pools_t, pools_d, _greedy(logits, pad_id), counts
 
         name = f"llm_prefill_{self.service}_w{w}_b{P}" \
             + ("" if head else "_nohead")
@@ -409,7 +342,7 @@ class PrefillExecutor:
         self._programs[(w, head)] = prog
         key = {"phase": "prefill", "service": self.service,
                "window": w, "batch": P, "head": head,
-               "attn": "paged" if self.paged else "dense",
+               "attn": "paged",
                "max_blocks": self.max_blocks,
                "block_len": self.kv.block_len,
                "encoder": self.module.program_key(),
@@ -492,10 +425,6 @@ class PrefillExecutor:
                 self._h_attn.observe(time.perf_counter() - t0,
                                      service=self.service,
                                      phase="prefill")
-                if not self.paged:
-                    self._c_gather.inc(self._gather_bytes,
-                                       service=self.service,
-                                       phase="prefill")
                 self.pools.target = pools_t
                 if self.draft_module is not None:
                     self.pools.draft = pools_d
@@ -563,9 +492,7 @@ class DecodeExecutor:
     target walk (the kernel's windowed variant) whose ``k + 1`` rows
     all get logits; each slot accepts its
     own longest agreeing prefix — no batch sync-on-min, block chains
-    advance independently. ``MMLSPARK_TPU_PAGED_ATTN=0`` keeps the
-    old gather→vmapped-``decode_step``→scatter program callable
-    (``kv_dense_gather_bytes_total`` counts what it moves)."""
+    advance independently."""
 
     def __init__(self, module, variables, kv: PagedKVManager,
                  pools: _PoolState, *, draft_module=None,
@@ -585,23 +512,12 @@ class DecodeExecutor:
         self.spec_k = int(spec_k)
         self.pad_id = int(pad_id)
         self.service = service
-        self.paged = paged_attention_enabled()
         reg = registry if registry is not None else _default_registry
         self._h_attn = reg.histogram(
             "gen_decode_attn_seconds",
             "attention-program wall time, by service and phase",
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1,
                      .25, .5, 1., 2.5))
-        self._c_gather = reg.counter(
-            "kv_dense_gather_bytes_total",
-            "bytes materialized by gather_dense in the dense-attention "
-            "fallback (0 on the paged-kernel path), by service/phase")
-        self._gather_bytes = _dense_gather_bytes(
-            module, int(slots), int(max_blocks), kv.block_len)
-        if draft_module is not None:
-            self._gather_bytes += _dense_gather_bytes(
-                draft_module, int(slots), int(max_blocks),
-                kv.block_len)
         self._walk_stats = _WalkStats(module, reg, service)
         # host-side slot state (the engine owns seq metadata)
         self.seq_ids: list = [None] * self.slots
@@ -645,18 +561,11 @@ class DecodeExecutor:
     def _build(self):
         if self._program is not None:
             return self._program
-        import jax
         import jax.numpy as jnp
         module, draft = self.module, self.draft_module
         pad_id, k, S = self.pad_id, self.spec_k, self.slots
 
-        def expand(c):
-            return jax.tree.map(lambda a: a[None], c)
-
-        def strip(c):
-            return jax.tree.map(lambda a: a[0], c)
-
-        if self.paged and k == 0:
+        if k == 0:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
                 hidden, pools_t, counts = module.apply(
@@ -664,12 +573,10 @@ class DecodeExecutor:
                     ptr - 1, active[:, None], method="walk")  # [S, 1, W]
                 logits = module.apply({"params": params}, hidden,
                                       method="logits")  # every row: w = 1
-                logits = logits[:, 0].at[:, pad_id].set(-jnp.inf)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                committed = nxt[:, None]                # [S, 1]
+                committed = _greedy(logits[:, 0], pad_id)[:, None]  # [S, 1]
                 n_new = jnp.where(active, 1, 0)
                 return pools_t, pools_d, committed, n_new, n_new, counts
-        elif self.paged:
+        else:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
                 pos = ptr - 1
@@ -682,8 +589,7 @@ class DecodeExecutor:
                         pos + j, av, method="walk")
                     ld = draft.apply({"params": dparams}, hd,
                                      method="logits")
-                    ld = ld[:, 0].at[:, pad_id].set(-jnp.inf)
-                    tok = jnp.argmax(ld, -1).astype(jnp.int32)[:, None]
+                    tok = _greedy(ld[:, 0], pad_id)[:, None]
                     drafts.append(tok[:, 0])
                 # extra cache-fill step: d_k's kv, or the next round's
                 # draft attends a zero hole after a full accept (same
@@ -700,8 +606,7 @@ class DecodeExecutor:
                 # the verify samples from every row of its window
                 lt = module.apply({"params": params}, ht,
                                   method="logits")      # [S, k+1, V]
-                lt = lt.at[:, :, pad_id].set(-jnp.inf)
-                t = jnp.argmax(lt, -1).astype(jnp.int32)
+                t = _greedy(lt, pad_id)
                 agree = jnp.cumprod(
                     (d == t[:, :k]).astype(jnp.int32), axis=1)
                 n_acc = agree.sum(axis=1)               # PER-SLOT
@@ -721,94 +626,12 @@ class DecodeExecutor:
                 n_new = jnp.where(active, n_new, 0)
                 return pools_t, pools_d, committed, n_new, \
                     jnp.where(active, n_acc, 0), counts
-        elif k == 0:
-            def run(params, dparams, pools_t, pools_d, rows, last, ptr,
-                    end, active):
-                dense = gather_dense(pools_t, rows)
 
-                def one(tk, cache, p):
-                    logits, c = module.apply(
-                        {"params": params}, tk[None], expand(cache),
-                        p - 1, method="decode_step")
-                    return logits[0], strip(c)
-
-                logits, dense = jax.vmap(one)(last, dense, ptr)
-                logits = logits.at[:, pad_id].set(-jnp.inf)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                wrote = (ptr - 1)[:, None]              # [S, 1]
-                pools_t = scatter_positions(
-                    pools_t, rows, wrote, take_positions(dense, wrote),
-                    valid=active[:, None])
-                committed = nxt[:, None]                # [S, 1]
-                n_new = jnp.where(active, 1, 0)
-                return pools_t, pools_d, committed, n_new, n_new, None
-        else:
-            def run(params, dparams, pools_t, pools_d, rows, last, ptr,
-                    end, active):
-                dense_t = gather_dense(pools_t, rows)
-                dense_d = gather_dense(pools_d, rows)
-
-                def one(tk, ct, cd, p):
-                    ct, cd = expand(ct), expand(cd)
-                    tok = tk[None]
-                    drafts = []
-                    for j in range(k):
-                        ld, cd = draft.apply(
-                            {"params": dparams}, tok, cd, p - 1 + j,
-                            method="decode_step")
-                        ld = ld.at[:, pad_id].set(-jnp.inf)
-                        tok = jnp.argmax(ld, -1).astype(jnp.int32)
-                        drafts.append(tok)
-                    # extra cache-fill step: d_k's kv, or the next
-                    # round's draft attends a zero hole after a full
-                    # accept (same fix as dl.speculative)
-                    _, cd = draft.apply(
-                        {"params": dparams}, tok, cd, p - 1 + k,
-                        method="decode_step")
-                    d = jnp.stack(drafts, 1)            # [1, k]
-                    window = jnp.concatenate([tk[None][:, None], d], 1)
-                    lt, ct = module.apply(
-                        {"params": params}, window, ct, p - 1,
-                        method="decode_window")         # [1, k+1, V]
-                    lt = lt.at[:, :, pad_id].set(-jnp.inf)
-                    t = jnp.argmax(lt, -1).astype(jnp.int32)
-                    agree = jnp.cumprod(
-                        (d == t[:, :k]).astype(jnp.int32), axis=1)
-                    n_acc = agree.sum(axis=1)[0]        # PER-SLOT
-                    bonus = t[0, n_acc]
-                    return (d[0], n_acc, bonus, strip(ct), strip(cd))
-
-                d, n_acc, bonus, dense_t, dense_d = jax.vmap(one)(
-                    last, dense_t, dense_d, ptr)
-                ar = jnp.arange(k + 1)[None]            # [1, k+1]
-                d_ext = jnp.concatenate(
-                    [d, jnp.zeros((S, 1), jnp.int32)], 1)
-                committed = jnp.where(
-                    ar < n_acc[:, None], d_ext,
-                    jnp.where(ar == n_acc[:, None], bonus[:, None],
-                              pad_id))                  # [S, k+1]
-                # never commit past the slot's budget (end - ptr
-                # tokens remain; runnable slots have at least 1)
-                n_new = jnp.clip(n_acc + 1, 1,
-                                 jnp.maximum(end - ptr, 1))
-                n_new = jnp.where(active, n_new, 0)
-                wrote = (ptr - 1)[:, None] + ar         # [S, k+1]
-                valid = active[:, None] & jnp.ones_like(wrote, bool)
-                pools_t = scatter_positions(
-                    pools_t, rows, wrote,
-                    take_positions(dense_t, wrote), valid=valid)
-                pools_d = scatter_positions(
-                    pools_d, rows, wrote,
-                    take_positions(dense_d, wrote), valid=valid)
-                return pools_t, pools_d, committed, n_new, \
-                    jnp.where(active, n_acc, 0), None
-
-        attn = "paged" if self.paged else "dense"
-        name = f"llm_decode_{attn}_{self.service}_S{S}_k{k}"
+        name = f"llm_decode_paged_{self.service}_S{S}_k{k}"
         self._program = compile_tracker.jit(run, name=name,
                                             **_donate_pools_kwargs())
         key = {"phase": "decode", "service": self.service, "slots": S,
-               "spec_k": k, "attn": attn,
+               "spec_k": k, "attn": "paged",
                "max_blocks": self.max_blocks,
                "block_len": self.kv.block_len,
                "encoder": self.module.program_key(),
@@ -856,11 +679,6 @@ class DecodeExecutor:
             jnp.asarray(self.end), jnp.asarray(runnable))
         self._h_attn.observe(time.perf_counter() - t0,
                              service=self.service, phase="decode")
-        if not self.paged:
-            # the fallback's whole cost, made loud: these bytes are
-            # exactly what the paged kernel does not move
-            self._c_gather.inc(self._gather_bytes,
-                               service=self.service, phase="decode")
         self.pools.target = pools_t
         if self.draft_module is not None:
             self.pools.draft = pools_d
@@ -1004,6 +822,12 @@ class LLMEngine:
                      1., 2.5, 5., 10.))
         self._c_tokens = reg.counter(
             "gen_tokens_total", "generated tokens committed, by service")
+        # never incremented: benchmark/drivers look the name up at
+        # set-up; it goes with ROADMAP Q1
+        reg.counter(
+            "kv_dense_gather_bytes_total",
+            "always 0: no dense copy of a chain exists; kept registered "
+            "for the benchmark's reader until ROADMAP Q1 drops it")
         self._c_steps = reg.counter(
             "gen_decode_steps_total", "decode steps executed, by service")
         self._g_accept = reg.gauge(

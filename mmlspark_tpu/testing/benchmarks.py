@@ -2152,25 +2152,18 @@ def llm_decode_scenario(*, service: str = "llm-decode-bench",
                         registry=None) -> dict:
     """Long-context decode-throughput bench (ISSUE 18 acceptance):
     steady-state tokens/sec of the decode executor at ``context_tokens``
-    of resident KV — the regime the paged-attention kernel exists for,
-    where the old path re-gathered the whole dense cache every step.
+    of resident KV — the regime the paged-attention kernel exists for.
 
     One sequence fills ``context_tokens - max_new_tokens`` prompt
     tokens, then the timed window covers ONLY the drained decode steps
     (the first engine boundary — prefill + first decode step — runs
     before the clock starts, so prefill cost never pollutes the decode
     number). Runs inside CompileTracker steady state: a runtime compile
-    mid-decode fails the scenario. The path's identity rides along in
-    the numbers — ``dense_gather_bytes`` is exactly 0 on the paged
-    path and the old path's per-step re-gather total behind
-    ``MMLSPARK_TPU_PAGED_ATTN=0`` — so the side-by-side bank
-    (``bench_llm_decode``) can prove which kernel produced which
-    column."""
+    mid-decode fails the scenario."""
     import jax.numpy as jnp
     import numpy as np
 
     from ..dl import MaskedLMModel, TextEncoder
-    from ..dl.paged_kv import paged_attention_enabled
     from ..dl.text_encoder import make_attention_fn
     from ..obs.metrics import registry as _default
     from ..obs.profile import compile_tracker
@@ -2217,7 +2210,6 @@ def llm_decode_scenario(*, service: str = "llm-decode-bench",
 
     snap = reg.snapshot()
     decode_tokens = _sum(snap, "gen_tokens_total") - tok0
-    gather_bytes = _sum(snap, "kv_dense_gather_bytes_total")
     attn_decode_s = sum(
         v for k, v in snap.items()
         if k.startswith("gen_decode_attn_seconds_sum")
@@ -2226,11 +2218,9 @@ def llm_decode_scenario(*, service: str = "llm-decode-bench",
     return {
         "context_tokens": int(context_tokens),
         "context_blocks": -(-int(context_tokens) // int(block_len)),
-        "paged_attention": bool(paged_attention_enabled()),
         "decode_tokens": int(decode_tokens),
         "decode_wall_s": decode_wall_s,
         "tokens_per_s": decode_tokens / max(decode_wall_s, 1e-9),
-        "dense_gather_bytes": int(gather_bytes),
         "attn_ms_per_step": (attn_decode_s / max(steps, 1)) * 1e3,
         "decode_steps": int(steps),
         "aot_fingerprints": len(fps),

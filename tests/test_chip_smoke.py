@@ -70,7 +70,7 @@ def test_phase_llm_tiny():
                   dtype=jnp.float32)
     assert rec["identical_to_generate"] == "4/4"
     assert rec["compiles_after_warm"] == 0
-    assert rec["prefix_hits"] > 0 and rec["dense_gather_bytes"] == 0
+    assert rec["prefix_hits"] > 0
     # no allocator stats on the CPU: the engine's default sizing
     assert rec["num_blocks"] == 1 + 2 * 4 * 16
 

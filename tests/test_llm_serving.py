@@ -510,6 +510,69 @@ class TestSteadyState:
         assert _prefill_calls(reg, "llmsteady3") == (3, 3)
 
 
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "spec"])
+def test_program_names_and_fingerprint_keys(lm, draft_lm, monkeypatch,
+                                            spec_k):
+    """What the persistent compile cache and ``serving/deploy.py``'s
+    static fingerprints key on: the programs' names and the fields of
+    the keys behind ``aot_fingerprints()``. A suffix of several chunks
+    gets the widest window's program with no head."""
+    from mmlspark_tpu.core import aot
+    key_of = {}                          # fingerprint pair -> its key
+    real = aot.fingerprints
+
+    def spy(key, donated, dropped):
+        fp = real(key, donated, dropped)
+        key_of[fp] = key
+        return fp
+
+    monkeypatch.setattr(aot, "fingerprints", spy)
+    module, variables = lm
+    svc = f"llmnames{spec_k}"
+    draft = dict(draft_module=draft_lm[0], draft_variables=draft_lm[1],
+                 spec_k=spec_k) if spec_k else {}
+    eng = LLMEngine(module, variables, slots=2, block_len=4,
+                    max_seq_len=32, prefill_batch=1, service=svc,
+                    registry=MetricsRegistry(), **draft)
+    eng.prefiller.max_window = 8         # VMEM-bound on the chip
+    fps = eng.warm(prefill_windows=(3, 21), mark_steady=False)
+    assert eng.prefiller.windows_for(21) == [8, 8, 8]
+    decode = f"llm_decode_paged_{svc}_S2_k{spec_k}"
+    assert set(fps) == {decode, f"llm_prefill_{svc}_w4_b1",
+                        f"llm_prefill_{svc}_w8_b1",
+                        f"llm_prefill_{svc}_w8_b1_nohead"}
+    by_name = {name: key_of[fp] for name, fp in fps.items()}
+    assert all(key["attn"] == "paged" for key in by_name.values())
+    assert by_name[decode]["phase"] == "decode"
+    assert by_name[decode]["spec_k"] == spec_k
+    assert by_name[decode]["slots"] == 2
+    for w, head in ((4, True), (8, True), (8, False)):
+        key = by_name[f"llm_prefill_{svc}_w{w}_b1"
+                      + ("" if head else "_nohead")]
+        assert (key["phase"], key["window"], key["head"],
+                key["batch"]) == ("prefill", w, head, 1)
+
+
+def test_dense_gather_counter_stays_registered_for_the_benchmark(lm):
+    """``benchmark/drivers`` look ``kv_dense_gather_bytes_total`` up at
+    set-up the way this test does: the engine registers it, once, and
+    nothing increments it."""
+    module, variables = lm
+    reg = MetricsRegistry()
+    name = "kv_dense_gather_bytes_total"
+    assert not reg.metrics(name)
+    eng = LLMEngine(module, variables, slots=2, block_len=4,
+                    max_seq_len=16, service="llmodo", registry=reg)
+    assert len(reg.metrics(name)) == 1
+    counter = next(m for m in reg.metrics(name) if m.name == name)
+    for i, p in enumerate(_prompts(seed=3, sizes=(3, 6))):
+        eng.submit(i, p, MAXNEW)
+    assert len(eng.run_until_drained()) == 2
+    for phase in ("prefill", "decode"):
+        assert counter.value(service="llmodo", phase=phase) == 0
+    assert not [k for k in reg.snapshot() if k.startswith(name)]
+
+
 class TestScenarioAndLoadgen:
     def test_llm_serving_scenario_smoke(self):
         from mmlspark_tpu.testing.benchmarks import llm_serving_scenario
@@ -535,10 +598,7 @@ class TestScenarioAndLoadgen:
                                   max_new_tokens=8,
                                   registry=MetricsRegistry())
         assert out["context_blocks"] == 16
-        assert out["paged_attention"] is True
         assert out["tokens_per_s"] > 0
-        # steady paged decode never re-materialises the dense cache
-        assert out["dense_gather_bytes"] == 0
         assert out["decode_tokens"] > 0
         assert out["steady_state_ok"]
 

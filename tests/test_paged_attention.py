@@ -3,10 +3,10 @@ block-table-indexed decode kernel behind the serving executors.
 
 Two layers of contract. Kernel-level: the pure-lax reference is
 bit-compatible with the dense ``decode_window`` formulation over
-``gather_dense`` caches, and the Pallas kernel (interpret mode on CPU)
-matches the reference across windows, ragged chains, and every
-``block_kv x slots_tile`` tiling. Engine-level: greedy / speculative /
-kill-switch serving over contexts spanning >= 8 pool blocks — with
+caches gathered whole in the test, and the Pallas kernel (interpret
+mode on CPU) matches the reference across windows, ragged chains, and
+every ``block_kv x slots_tile`` tiling. Engine-level: greedy /
+speculative serving over contexts spanning >= 8 pool blocks — with
 mid-generation eviction pressure and ragged per-slot lengths — stays
 byte-identical to ``dl.generate``.
 """
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from mmlspark_tpu.dl import (MaskedLMModel, TextEncoder, generate,
                              make_attention_fn, paged_attention,
                              paged_window_attention)
-from mmlspark_tpu.dl.paged_kv import TRASH_BLOCK, gather_dense
+from mmlspark_tpu.dl.paged_kv import TRASH_BLOCK
 from mmlspark_tpu.obs.metrics import MetricsRegistry
 from mmlspark_tpu.perf import autotune
 from mmlspark_tpu.serving.llm import LLMEngine
@@ -60,10 +60,17 @@ def _q(seed, s, heads, w, hd):
 
 
 def _dense_ref(q, k_pool, v_pool, rows, pos):
-    """The decode_window formulation over gather_dense caches — the
-    exact math the pre-paged executors ran."""
+    """The decode_window formulation over each slot's chain gathered
+    into a dense ``[S, H, L, hd]`` cache — the math ``dl.generate``'s
+    cached path runs."""
     s_, h_, w_, hd_ = q.shape
-    (k, v), = gather_dense(((k_pool, v_pool),), rows)   # [S, H, L, hd]
+
+    def dense(pool):
+        blocks = jnp.take(pool, rows, axis=0)       # [S, MB, BL, H, hd]
+        return jnp.transpose(
+            blocks.reshape(s_, -1, *pool.shape[2:]), (0, 2, 1, 3))
+
+    k, v = dense(k_pool), dense(v_pool)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * hd_**-0.5
     length = k.shape[2]
@@ -261,17 +268,18 @@ def _counter_sum(reg, name):
 
 
 class TestLongContextIdentity:
-    def test_greedy_ragged_matches_generate(self, lm):
-        prompts = _prompts()
+    @pytest.mark.parametrize("prompt_set", [
+        {}, dict(seed=5, sizes=(18, 11, 25))],
+        ids=["four-prompts", "three-prompts"])
+    def test_greedy_ragged_matches_generate(self, lm, prompt_set):
+        prompts = _prompts(**prompt_set)
         ref = _ref(lm, prompts)
         reg = MetricsRegistry()
         eng, got = _run(lm, prompts, registry=reg,
-                        service="llmlongg")
+                        service=f"llmlongg{len(prompts)}")
         for i, p in enumerate(prompts):
             np.testing.assert_array_equal(got[i],
                                           ref[i][:len(p) + MAXNEW])
-        # steady paged decode never re-gathers the dense caches
-        assert _counter_sum(reg, "kv_dense_gather_bytes_total") == 0
         assert _counter_sum(reg, "gen_decode_attn_seconds_count") > 0
 
     def test_speculative_disagreeing_draft(self, lm, draft_lm):
@@ -311,18 +319,3 @@ class TestLongContextIdentity:
             np.testing.assert_array_equal(got[i],
                                           ref[i][:len(p) + MAXNEW])
         assert _counter_sum(reg, "kv_evictions_total") > 0
-
-    def test_kill_switch_restores_dense_gather_path(self, lm,
-                                                    monkeypatch):
-        prompts = _prompts(seed=5, sizes=(18, 11, 25))
-        ref = _ref(lm, prompts)
-        monkeypatch.setenv("MMLSPARK_TPU_PAGED_ATTN", "0")
-        reg = MetricsRegistry()
-        eng, got = _run(lm, prompts, registry=reg,
-                        service="llmdense")
-        for i, p in enumerate(prompts):
-            np.testing.assert_array_equal(got[i],
-                                          ref[i][:len(p) + MAXNEW])
-        # the fallback pays the dense round-trip and says so
-        assert not eng.decoder.paged
-        assert _counter_sum(reg, "kv_dense_gather_bytes_total") > 0
