@@ -5,19 +5,31 @@ per slot over that slot's chain of KV blocks, read where they rest. The
 KV working set is resident once and a step's HBM traffic is the chains'
 own blocks: no dense copy of a slot's history is made, before the step
 or inside it. The kernel reads the fixed per-layer pools
-``[num_blocks, block_len, heads, head_dim]`` IN PLACE:
+``[num_blocks, block_len, heads*head_dim]`` IN PLACE — a token's heads
+side by side, so that in a block positions lie on sublanes and head
+``h`` is lanes ``h*hd … (h+1)*hd``: a head's keys are a lane-aligned
+slice of the block, loaded as they lie, and no cell shuffles a sublane:
 
     grid = (slots/slots_tile, slots_tile, max_blocks), blocks innermost
-    the int32 block table rides scalar prefetch (SMEM), so each grid
-    cell's BlockSpec index_map streams pool block ``rows[s, j]``
-    straight HBM→VMEM — the gather IS the block fetch, no dense copy
-    per (slot, chain-position) cell: per-head q·kᵀ on the MXU,
-        online max/denominator update in VMEM scratch (flash style),
+    the int32 block table and the positions ride scalar prefetch (SMEM),
+    so each grid cell's BlockSpec index_map streams pool block
+    ``rows[s, min(j, last live entry)]`` straight HBM→VMEM — the gather
+    IS the block fetch, no dense copy; past the chain's end the index
+    repeats, and the pipeline copies nothing (``chain_block``)
+    per (slot, chain-position) cell, by ``heads * w``:
+      up to ``_BATCHED_ROWS`` rows (a decode row of 16 heads): the
+        slot's query laid block-diagonally ``[heads*w, heads*hd]`` (built
+        once a slot), ONE q·kᵀ for every head, one online max/denominator
+        update over the ``[heads*w, block_kv]`` score tile, ONE
+        weights @ v whose diagonal blocks are the heads' outputs
+      above it (prefill, a wide verify window): per-head q·kᵀ over the
+        head's lane slice, the same update on the head's ``w`` rows,
         acc += softmax-weights @ v
     emit acc / l once per slot on the last chain block.
 
-Masking: table rows pad with ``TRASH_BLOCK`` — those cells are skipped
-outright (``pl.when``), and in-block key positions mask against each
+Masking: table rows pad with ``TRASH_BLOCK`` — those cells, and cells
+past the window's last position, are skipped outright (``pl.when``),
+and in-block key positions mask against each
 query row's global position (``t <= pos + i``), which also covers
 positions ≥ the slot's length inside the tail block. A windowed variant
 (q = k+1 rows per slot) serves speculative verify with the same kernel.
@@ -64,7 +76,9 @@ def _paged_reference(q, k_pool, v_pool, rows, pos):
     f32 einsum × hd^-0.5, -inf outside ``t <= pos + i``, softmax,
     NaN→0 for fully-masked rows, ``p.astype(v.dtype)`` before the
     value einsum. Bit-identical to the dense-cache decode math — the
-    byte-identity contract with ``dl.generate`` rides on it."""
+    byte-identity contract with ``dl.generate`` rides on it. The pools
+    rest ``[num_blocks, block_len, heads*hd]``; the gathered keys are
+    viewed ``[S, L, heads, hd]``."""
     S, H, w, hd = q.shape
     NB, BL = k_pool.shape[0], k_pool.shape[1]
     MB = rows.shape[1]
@@ -86,71 +100,138 @@ def _paged_reference(q, k_pool, v_pool, rows, pos):
 
 
 # ------------------------------------------------------------ pallas path
+#: query rows (``heads * w``) one grid cell may hold as ONE score tile:
+#: a bfloat16 tile's sublanes, two float32 registers of scores a chunk.
+#: Up to here every head of a slot shares one pair of products and one
+#: softmax update (a decode row of 16 heads fills it); above it a head's
+#: own ``w`` rows fill the tiles and the heads are looped.
+_BATCHED_ROWS = 16
+
+
+def _last_entry(pos, w: int, block_len: int):
+    """The table entry that holds a window's last position ``pos + w -
+    1``: no row of the window sees a block past it."""
+    return jnp.maximum(pos + (w - 1), 0) // block_len
+
+
+def chain_block(rows, pos, s, j, *, w: int, block_len: int):
+    """The pool block grid cell ``(s, j)`` is handed: table entry ``j``
+    of slot ``s`` up to the window's last entry, and that entry again
+    for every ``j`` past it, so that the pipeline, which copies only
+    when the index changes, fetches nothing for a cell past the chain's
+    end. ``rows`` [S, max_blocks] and ``pos`` [S, 1] are the
+    scalar-prefetched table and positions."""
+    return rows[s, jnp.minimum(j, _last_entry(pos[s, 0], w, block_len))]
+
+
+def _online_softmax(s, allowed, m_scr, l_scr, R: int):
+    """One flash-style update of the running max and denominator of rows
+    ``[:R]`` with a chunk's float32 scores ``s``; returns the chunk's
+    weights and the factor the accumulator is rescaled by."""
+    s = jnp.where(allowed, s, _NEG)
+    m_prev = m_scr[:R, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[:R, :1] = l_scr[:R, :1] * corr \
+        + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[:R, :1] = m_new
+    return p, corr
+
+
 def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, heads: int,
-                  w: int, block_len: int, block_kv: int,
+                  m_scr, l_scr, acc_scr, *qbd_scr, scale: float,
+                  heads: int, w: int, block_len: int, block_kv: int,
                   slots_tile: int):
     """One (slot-group, slot, chain-block) grid cell. The k/v refs
-    already hold pool block ``rows[s, j]`` — the scalar-prefetched
-    table drove the fetch; this body only ever sees one slot's own
-    chain (or the trash block, which it skips)."""
+    already hold pool block ``chain_block(s, j)`` as ``[block_len,
+    heads*hd]``: positions on sublanes, head ``h`` in lanes ``h*hd …``,
+    so a head's keys are a lane-aligned slice of the ref, loaded as they
+    lie. ``qbd_scr`` is there in the batched form (``heads * w <=
+    _BATCHED_ROWS``): the slot's query rows laid block-diagonally,
+    ``[heads*w, heads*hd]`` with row ``h*w + i`` holding ``q[h, i]`` in
+    head ``h``'s lanes and zeros elsewhere, so that ONE product scores
+    every head against the block and ONE weights the values."""
     g = pl.program_id(0)
     u = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
     s_idx = g * slots_tile + u
+    R = heads * w
+    hd = q_ref.shape[2]
+
+    def row_of(shape):
+        """Row ``h*w + i`` of the batched form, as (head, window row)."""
+        r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        return (r, 0) if w == 1 else (r // w, r % w)
 
     @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        if qbd_scr:
+            q = q_ref[0]                                # [R, hd]
+            own, _ = row_of(q.shape)
+            for h in range(heads):
+                qbd_scr[0][:, h * hd:(h + 1) * hd] = jnp.where(
+                    own == h, q, jnp.zeros_like(q))
 
     block_id = rows_ref[s_idx, j]
     pos = pos_ref[s_idx, 0]
 
-    @pl.when(block_id != TRASH_BLOCK)
+    def batched(lo, hi, allowed):
+        s = jax.lax.dot_general(           # [R, cw] f32: every head at once
+            qbd_scr[0][...], k_ref[0, lo:hi, :],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        p, corr = _online_softmax(s, allowed, m_scr, l_scr, R)
+        pv = jax.lax.dot_general(          # [R, heads*hd] f32
+            p.astype(v_ref.dtype), v_ref[0, lo:hi, :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        # a row's own head is the diagonal block of the product
+        own, _ = row_of((R, hd))
+        acc_scr[:R, :] = acc_scr[:R, :] * corr + sum(
+            jnp.where(own == h, pv[:, h * hd:(h + 1) * hd], 0.0)
+            for h in range(heads))
+
+    def by_head(lo, hi, allowed):
+        for h in range(heads):
+            rows_h = slice(h * w, (h + 1) * w)
+            lanes_h = slice(h * hd, (h + 1) * hd)
+            s = jax.lax.dot_general(       # [w, cw] f32 on the MXU
+                q_ref[0, rows_h, :], k_ref[0, lo:hi, lanes_h],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p, corr = _online_softmax(
+                s, allowed, m_scr.at[rows_h], l_scr.at[rows_h], w)
+            acc_scr[rows_h, :] = acc_scr[rows_h, :] * corr \
+                + jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, lo:hi, lanes_h],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+    # the trash block pads a chain; a block past the window's last
+    # position holds nothing any row may see (and was not fetched)
+    @pl.when((block_id != TRASH_BLOCK)
+             & (j <= _last_entry(pos, w, block_len)))
     def _compute():
-        q = q_ref[0]                       # [heads*w, hd]
-        k = k_ref[0]                       # [block_len, H, hd]
-        v = v_ref[0]
         for c in range(-(-block_len // block_kv)):
             lo = c * block_kv
             hi = min(block_len, lo + block_kv)
-            cw = hi - lo
+            # one score tile: every head's rows, or one head's
+            tile = (R if qbd_scr else w, hi - lo)
             # chain-logical key positions of this chunk vs each query
             # row's global position: covers causality AND length (the
             # tail block's unwritten positions are > pos + i)
             tpos = j * block_len + lo + jax.lax.broadcasted_iota(
-                jnp.int32, (w, cw), 1)
-            qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (w, cw), 0)
-            allowed = tpos <= qpos
-            for h in range(heads):
-                r0 = h * w
-                s = jax.lax.dot_general(   # [w, cw] f32 on the MXU
-                    q[r0:r0 + w], k[lo:hi, h, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(allowed, s, _NEG)
-                m_prev = m_scr[r0:r0 + w, :1]
-                l_prev = l_scr[r0:r0 + w, :1]
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(s, axis=-1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                p = jnp.where(allowed, p, 0.0)
-                corr = jnp.exp(m_prev - m_new)
-                l_scr[r0:r0 + w, :1] = l_prev * corr \
-                    + jnp.sum(p, axis=-1, keepdims=True)
-                m_scr[r0:r0 + w, :1] = m_new
-                acc_scr[r0:r0 + w, :] = acc_scr[r0:r0 + w, :] * corr \
-                    + jax.lax.dot_general(
-                        p.astype(v.dtype), v[lo:hi, h, :],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
+                jnp.int32, tile, 1)
+            i = row_of(tile)[1] if qbd_scr else \
+                jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+            (batched if qbd_scr else by_head)(lo, hi, tpos <= pos + i)
 
     @pl.when(j == nj - 1)
     def _emit():
-        R = heads * w
         l = jnp.maximum(l_scr[:R, :1], 1e-35)
         o_ref[0] = (acc_scr[:R] / l).astype(o_ref.dtype)
 
@@ -160,7 +241,7 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 def _paged_pallas(q, k_pool, v_pool, rows, pos, *, block_kv: int,
                   slots_tile: int, interpret: bool):
     S, H, w, hd = q.shape
-    BL = k_pool.shape[1]
+    BL, width = k_pool.shape[1], k_pool.shape[2]
     MB = rows.shape[1]
     st = max(min(int(slots_tile), max(S, 1)), 1)
     bkv = max(min(int(block_kv), BL), 1)
@@ -174,27 +255,31 @@ def _paged_pallas(q, k_pool, v_pool, rows, pos, *, block_kv: int,
     kern = functools.partial(_paged_kernel, scale=hd ** -0.5, heads=H,
                              w=w, block_len=BL, block_kv=bkv,
                              slots_tile=st)
+
+    def block_of(g, u, j, rt, pt):
+        # the zero-copy read: the table entry IS the block index
+        return (chain_block(rt, pt, g * st + u, j, w=w, block_len=BL),
+                0, 0)
+
+    scratch = [
+        pltpu.VMEM((Rp, 128), jnp.float32),   # running max
+        pltpu.VMEM((Rp, 128), jnp.float32),   # running denominator
+        pltpu.VMEM((Rp, hd), jnp.float32),    # output accumulator
+    ]
+    if R <= _BATCHED_ROWS:
+        scratch.append(pltpu.VMEM((R, width), q.dtype))  # block-diagonal q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Sp // st, st, MB),
         in_specs=[
             pl.BlockSpec((1, R, hd),
                          lambda g, u, j, rt, pt: (g * st + u, 0, 0)),
-            # the zero-copy read: the table entry IS the block index
-            pl.BlockSpec((1, BL, H, hd),
-                         lambda g, u, j, rt, pt:
-                         (rt[g * st + u, j], 0, 0, 0)),
-            pl.BlockSpec((1, BL, H, hd),
-                         lambda g, u, j, rt, pt:
-                         (rt[g * st + u, j], 0, 0, 0)),
+            pl.BlockSpec((1, BL, width), block_of),
+            pl.BlockSpec((1, BL, width), block_of),
         ],
         out_specs=pl.BlockSpec(
             (1, R, hd), lambda g, u, j, rt, pt: (g * st + u, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Rp, 128), jnp.float32),   # running max
-            pltpu.VMEM((Rp, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((Rp, hd), jnp.float32),    # output accumulator
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kern,
@@ -276,7 +361,8 @@ def paged_window_attention(q, k_pool, v_pool, rows, pos, *,
     """Windowed paged attention: ``q`` [S, H, w, hd] holds w query rows
     per slot at global positions ``pos[s] + i`` (speculative verify
     passes the k+1 draft window); ``k_pool``/``v_pool`` are ONE layer's
-    pools ``[num_blocks, block_len, H, hd]``; ``rows`` [S, max_blocks]
+    pools ``[num_blocks, block_len, H*hd]`` (a token's heads side by
+    side on the lanes); ``rows`` [S, max_blocks]
     is the ``PagedKVManager.block_rows`` table (TRASH_BLOCK padding);
     ``pos`` [S] int32. Query row i attends pool positions
     ``t <= pos + i`` through the slot's chain — the window's own k/v

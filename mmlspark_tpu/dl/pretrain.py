@@ -82,10 +82,12 @@ class MaskedLMModel(nn.Module):
 
     def cache_spec(self) -> tuple:
         """For each layer, the arrays one token takes in the paged cache
-        as ``(trailing shape, dtype)``: a key and a value of ``[heads,
-        head_dim]``."""
+        as ``(trailing shape, dtype)``: a key and a value of ``[heads *
+        head_dim]``, the heads side by side, which is how the paged
+        kernel reads a block (positions on sublanes, a head a
+        lane-aligned slice)."""
         enc = self.encoder
-        kv = ((enc.heads, enc.width // enc.heads), enc.dtype)
+        kv = ((enc.width,), enc.dtype)
         return ((kv, kv),) * enc.depth
 
     def max_window(self) -> int:
@@ -145,8 +147,9 @@ class MaskedLMModel(nn.Module):
             q, k, v = blk._project_qkv(x)                   # [S, H, w, hd]
             (kp, vp), = scatter_positions(
                 ((kp, vp),), rows, wrote,
-                ((k.transpose(0, 2, 1, 3).astype(kp.dtype),
-                  v.transpose(0, 2, 1, 3).astype(vp.dtype)),),
+                (tuple(a.transpose(0, 2, 1, 3).reshape(
+                    a.shape[0], w, enc.width).astype(kp.dtype)
+                    for a in (k, v)),),
                 valid=valid)
             o = paged_window_attention(q, kp, vp, rows, pos)
             x = blk.ffn(x + blk._merge_out(o))

@@ -337,9 +337,9 @@ def measure_paged_attention(config: dict, *, context: int,
     q = jnp.asarray(rng.normal(size=(slots, heads, w, head_dim)),
                     jnp.float32)
     kp = jnp.asarray(rng.normal(
-        size=(nb, block_len, heads, head_dim)), jnp.float32)
+        size=(nb, block_len, heads * head_dim)), jnp.float32)
     vp = jnp.asarray(rng.normal(
-        size=(nb, block_len, heads, head_dim)), jnp.float32)
+        size=(nb, block_len, heads * head_dim)), jnp.float32)
     rows = jnp.asarray(
         1 + np.arange(slots * mb).reshape(slots, mb), jnp.int32)
     pos = jnp.full((slots,), mb * int(block_len) - w, jnp.int32)

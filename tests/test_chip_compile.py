@@ -118,7 +118,7 @@ def test_paged_attention(one_chip, block_len, window):
     from mmlspark_tpu.dl.pallas_paged_attention import (
         paged_attention, paged_window_attention)
     S, H, hd, blocks = 8, 8, 64, 4096
-    pool = _sds((blocks, block_len, H, hd), jnp.bfloat16, one_chip)
+    pool = _sds((blocks, block_len, H * hd), jnp.bfloat16, one_chip)
     rows = _sds((S, 512 // block_len), jnp.int32, one_chip)
     pos = _sds((S,), jnp.int32, one_chip)
     if window == 1:
@@ -147,7 +147,7 @@ def test_widest_prefill_window(one_chip, H, hd, dtype, block_len):
     dtype = jnp.dtype(dtype)
     w = max_window(H, hd, dtype)
     assert w >= 64
-    pool = _sds((1024, block_len, H, hd), dtype, one_chip)
+    pool = _sds((1024, block_len, H * hd), dtype, one_chip)
     _compile(lambda q, k, v, r, p: paged_window_attention(
         q, k, v, r, p, impl="pallas", interpret=False),
         _sds((4, H, w, hd), dtype, one_chip), pool, pool,
@@ -322,19 +322,21 @@ def test_latent_moe_engine_programs(one_chip, steer_tpu):
         assert mem.argument_size_in_bytes < 11.7e9, name
 
 
-def test_xglm_prefill_programs_hold_no_window_of_logits(one_chip,
-                                                       steer_tpu):
-    """The widest prefill programs of the benchmark's XGLM-1.7B engine
-    (``benchmark/configs/xglm-1.7b.json``: 24 blocks, a 256,008-row head,
-    one prompt a call, 192 rows): the one that emits computes the head
-    for ONE row, so its temporaries stay far under the 197 MB that
-    ``[192, 256008]`` float32 logits take (with the head over every row
-    they were 226 MB), and the one with no head does not even take the
-    head's matrix."""
+@pytest.fixture(scope="module")
+def xglm_programs(one_chip):
+    """The decode program and both 192-row prefill programs of the
+    benchmark's XGLM-1.7B engine (``benchmark/configs/xglm-1.7b.json``:
+    24 blocks, 16 heads of 128, a 256,008-row head; 32 slots, chains of
+    14 blocks of 128, a pool of 320; one prompt a prefill call) compiled
+    once for the described chip: name -> (text, memory analysis), with
+    the sizes the tests compare against."""
     import sys
 
     import jax
     import jax.numpy as jnp
+
+    import mmlspark_tpu.dl.pallas_paged_attention as paged
+    import mmlspark_tpu.utils.platform as plat
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
@@ -357,29 +359,79 @@ def test_xglm_prefill_programs_hold_no_window_of_logits(one_chip,
     pools = jax.tree.map(
         lambda a: _sds((num_blocks,) + a.shape[1:], a.dtype, one_chip),
         engine.pools.target)
-    MB, P, w = engine.max_blocks, engine.prefiller.batch, \
-        engine.prefiller.max_window
-    assert (MB, P, w) == (14, 1, 192)
+    S, MB, P, w = engine.decoder.slots, engine.max_blocks, \
+        engine.prefiller.batch, engine.prefiller.max_window
+    assert (S, MB, P, w) == (32, 14, 1, 192)
 
     def i32(*shape):
         return _sds(shape, jnp.int32, one_chip)
 
+    # the module-scoped twin of ``steer_tpu``: the chip's path is lowered
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(plat, "target_platform", lambda: "tpu")
+        m.setattr(paged, "target_platform", lambda: "tpu")
+        lowered = {"decode": engine.decoder._build().lower(
+            weights, None, pools, None, i32(S, MB), i32(S), i32(S),
+            i32(S), _sds((S,), jnp.bool_, one_chip))}
+        for head in (True, False):
+            lowered[f"prefill_head{head:d}"] = \
+                engine.prefiller._program(w, head).lower(
+                    weights, None, pools, None, i32(P, MB), i32(P, w),
+                    i32(P), i32(P))
+        compiled = {k: v.compile() for k, v in lowered.items()}
     vocab = int(cfg["vocab_size"])
-    head_bytes = int(cfg["d_model"]) * vocab * dtype.itemsize
-    mem = {}
-    for head in (True, False):
-        compiled = engine.prefiller._program(w, head).lower(
-            weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
-            i32(P)).compile()
-        # with no head nothing reads the last block's attention: the
-        # compiler drops that kernel with the block's feed-forward
-        assert compiled.as_text().count(KERNEL) == 23 + head, head
-        mem[head] = compiled.memory_analysis()
-    window_of_logits = w * vocab * 4
-    assert mem[True].temp_size_in_bytes < window_of_logits // 2
+    leaves = jax.tree.leaves(pools)
+    return {
+        "programs": {k: (c.as_text(), c.memory_analysis())
+                     for k, c in compiled.items()},
+        "window_of_logits": w * vocab * 4,
+        "head_bytes": int(cfg["d_model"]) * vocab * dtype.itemsize,
+        "pool_shape": leaves[0].shape,
+        "pool_bytes": int(np.prod(leaves[0].shape)) * dtype.itemsize,
+        "at_rest": sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in leaves)}
+
+
+def test_xglm_prefill_programs_hold_no_window_of_logits(xglm_programs):
+    """The widest prefill programs: the one that emits computes the head
+    for ONE row, so its temporaries stay far under the 197 MB that
+    ``[192, 256008]`` float32 logits take (with the head over every row
+    they were 226 MB), and the one with no head does not even take the
+    head's matrix."""
+    mem = {head: xglm_programs["programs"][f"prefill_head{head:d}"][1]
+           for head in (True, False)}
+    assert mem[True].temp_size_in_bytes \
+        < xglm_programs["window_of_logits"] // 2
     assert mem[False].temp_size_in_bytes <= mem[True].temp_size_in_bytes
     assert mem[False].argument_size_in_bytes \
-        <= mem[True].argument_size_in_bytes - head_bytes
+        <= mem[True].argument_size_in_bytes - xglm_programs["head_bytes"]
+
+
+@pytest.mark.parametrize("name,kernels", [
+    ("decode", 24), ("prefill_head1", 24), ("prefill_head0", 23)])
+def test_xglm_programs_keep_the_lane_dense_pools_in_place(
+        xglm_programs, name, kernels):
+    """The cache rests ``[320, 128, 2048]`` bfloat16, a token's 16 heads
+    of 128 side by side, which is the layout the paged kernel reads a
+    block in: every program takes the 48 pools and hands them back in the
+    buffers they came in, holds no temporary of a pool's size (a pool
+    re-laid out around the scatter or the kernel would be one), and runs
+    one Mosaic kernel a layer (with no head nothing reads the last
+    block's attention: the compiler drops that kernel with the block's
+    feed-forward)."""
+    text, mem = xglm_programs["programs"][name]
+    assert xglm_programs["pool_shape"] == (320, 128, 2048)
+    assert xglm_programs["at_rest"] == 48 * xglm_programs["pool_bytes"]
+    assert text.count(KERNEL) == kernels
+    assert mem.alias_size_in_bytes >= xglm_programs["at_rest"]
+    assert mem.temp_size_in_bytes < xglm_programs["pool_bytes"]
+    # no copy and no transpose makes an array of a pool's shape (as a
+    # pool rests, or flat as the scatter sees it)
+    for line in text.splitlines():
+        if " copy(" in line or " transpose(" in line:
+            made = line.split("=")[1].split("(")[0]
+            assert "bf16[320,128,2048]" not in made \
+                and "bf16[40960,2048]" not in made, line
 
 
 def test_gbdt_boosting_step(one_chip, steer_tpu, monkeypatch):

@@ -372,15 +372,16 @@ class TestPoolSizing:
                 vocab=8, width=heads * hd, depth=depth, heads=heads,
                 mlp_dim=8, dtype=dtype)).cache_spec(), block_len)
         # 2*depth arrays at rest + 4 in flight around the kernel, each
-        # [block_len, heads, 128 lanes]
+        # [block_len, heads * head_dim] with the heads side by side
         assert price(8, 128, jnp.bfloat16) == 20 * 16 * 8 * 128 * 2
-        # 64 lanes pad to 128; 2 heads of 16 in f32 cost 8x logical
-        assert price(8, 64, jnp.bfloat16) == price(8, 128, jnp.bfloat16)
+        # stated lane-dense, 8 heads of 64 fill whole tiles: no padding
+        assert price(8, 64, jnp.bfloat16) == 20 * 16 * 8 * 64 * 2
+        # 2 heads of 16 in f32 are 32 of 128 lanes: 4x logical
         assert price(2, 16, jnp.float32, depth=1, block_len=4) == \
-            6 * 8 * (4 * 2 * 16 * 4)
+            6 * 4 * (4 * 2 * 16 * 4)
         # the row dim rounds to the dtype's sublane tile once past it
-        assert price(20, 128, jnp.bfloat16) == \
-            price(32, 128, jnp.bfloat16)
+        assert price(8, 128, jnp.bfloat16, block_len=20) == \
+            price(8, 128, jnp.bfloat16, block_len=32)
 
     def test_target_and_draft_share_one_fraction(self, lm, monkeypatch):
         import mmlspark_tpu.obs.memory as memory

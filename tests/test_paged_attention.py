@@ -1,11 +1,15 @@
 """Paged-attention kernel (``dl/pallas_paged_attention.py``): the
-block-table-indexed decode kernel behind the serving executors.
+block-table-indexed decode kernel behind the serving executors, over
+pools that rest lane-dense ``[num_blocks, block_len, heads*head_dim]``.
 
 Two layers of contract. Kernel-level: the pure-lax reference is
 bit-compatible with the dense ``decode_window`` formulation over
 caches gathered whole in the test, and the Pallas kernel (interpret
 mode on CPU) matches the reference across windows, ragged chains, and
-every ``block_kv x slots_tile`` tiling. Engine-level: greedy /
+every ``block_kv x slots_tile`` tiling, in both forms of its cell (every
+head in one product while ``heads * w`` rows fit a tile group, a loop
+over the heads above it), and its index map fetches nothing past a
+chain's end. Engine-level: greedy /
 speculative serving over contexts spanning >= 8 pool blocks — with
 mid-generation eviction pressure and ragged per-slot lengths — stays
 byte-identical to ``dl.generate``.
@@ -21,6 +25,8 @@ from mmlspark_tpu.dl import (MaskedLMModel, TextEncoder, generate,
                              make_attention_fn, paged_attention,
                              paged_window_attention)
 from mmlspark_tpu.dl.paged_kv import TRASH_BLOCK
+from mmlspark_tpu.dl.pallas_paged_attention import (_BATCHED_ROWS,
+                                                    chain_block)
 from mmlspark_tpu.obs.metrics import MetricsRegistry
 from mmlspark_tpu.perf import autotune
 from mmlspark_tpu.serving.llm import LLMEngine
@@ -28,14 +34,15 @@ from mmlspark_tpu.serving.llm import LLMEngine
 # ---------------------------------------------------------- kernel level
 
 S, H, HD, BL, MB = 3, 2, 8, 4, 5   # ragged 3-slot micro case
+H6 = 6                             # heads whose window of 3 takes the loop
 NB = 13                            # pool rows (incl. trash row 0)
 
 
 def _pools(seed=0, nb=NB, bl=BL, heads=H, hd=HD):
     rng = np.random.default_rng(seed)
-    k = jnp.asarray(rng.standard_normal((nb, bl, heads, hd)),
+    k = jnp.asarray(rng.standard_normal((nb, bl, heads * hd)),
                     jnp.float32)
-    v = jnp.asarray(rng.standard_normal((nb, bl, heads, hd)),
+    v = jnp.asarray(rng.standard_normal((nb, bl, heads * hd)),
                     jnp.float32)
     return k, v
 
@@ -66,9 +73,9 @@ def _dense_ref(q, k_pool, v_pool, rows, pos):
     s_, h_, w_, hd_ = q.shape
 
     def dense(pool):
-        blocks = jnp.take(pool, rows, axis=0)       # [S, MB, BL, H, hd]
+        blocks = jnp.take(pool, rows, axis=0)       # [S, MB, BL, H*hd]
         return jnp.transpose(
-            blocks.reshape(s_, -1, *pool.shape[2:]), (0, 2, 1, 3))
+            blocks.reshape(s_, -1, h_, hd_), (0, 2, 1, 3))
 
     k, v = dense(k_pool), dense(v_pool)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -112,17 +119,37 @@ class TestKernelReference:
                                       np.asarray(win[:, :, 0, :]))
 
 
+def _cell_case(w):
+    """The benchmark cell's own head geometry (16 heads of 128,
+    ``block_len`` 128) over a table of 4 entries: chains that end in the
+    first, a middle and the last entry, one whose tail block is a single
+    position full, and a slot whose row is all padding."""
+    heads, hd, bl, mb = 16, 128, 128, 4
+    rows = np.full((5, mb), TRASH_BLOCK, np.int32)
+    rows[0, :1] = [3]
+    rows[1, :3] = [9, 1, 6]
+    rows[2, :4] = [2, 8, 5, 4]
+    rows[3, :2] = [7, 10]
+    # the window's last row sits at the chain's newest position
+    newest = np.asarray([bl // 2, 2 * bl + 77, 4 * bl - 1, bl, 0])
+    pos = (newest - (w - 1)).astype(np.int32)
+    kp, vp = _pools(60 + w, nb=11, bl=bl, heads=heads, hd=hd)
+    return (_q(70 + w, 5, heads, w, hd), kp, vp, jnp.asarray(rows),
+            jnp.asarray(pos))
+
+
 class TestKernelInterpret:
     """Pallas-in-interpret-mode smoke vs the lax reference (tier-1:
     tiny shapes; the full-size sweep is under ``slow``)."""
 
-    @pytest.mark.parametrize("w", [1, 3])
+    @pytest.mark.parametrize("heads", [H, H6])
+    @pytest.mark.parametrize("w", [1, 3, 4])
     @pytest.mark.parametrize("block_kv,slots_tile",
                              [(BL, 1), (1, 2), (3, 8)])
-    def test_matches_lax(self, w, block_kv, slots_tile):
-        kp, vp = _pools(w)
+    def test_matches_lax(self, heads, w, block_kv, slots_tile):
+        kp, vp = _pools(w, heads=heads)
         rows, pos = _ragged_case(w)
-        q = _q(10 + w, S, H, w, HD)
+        q = _q(10 + w, S, heads, w, HD)
         ref = paged_window_attention(q, kp, vp, rows, pos, impl="lax")
         got = paged_window_attention(q, kp, vp, rows, pos,
                                      impl="pallas", interpret=True,
@@ -131,11 +158,36 @@ class TestKernelInterpret:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_inactive_all_trash_slot_emits_zero(self):
-        kp, vp = _pools(9)
-        rows, pos = _ragged_case(1)
+    def test_both_forms_of_the_cell_are_driven(self):
+        """The cases fall on both sides of the row count up to which
+        every head shares one product."""
+        assert H * 4 <= _BATCHED_ROWS and H6 * 1 <= _BATCHED_ROWS < H6 * 3
+        assert 16 * 1 <= _BATCHED_ROWS < 16 * 3
+
+    @pytest.mark.parametrize("w,block_kv", [(1, 128), (1, 32), (3, 128)],
+                             ids=["decode", "decode-chunked", "window3"])
+    def test_cell_geometry_matches_lax(self, w, block_kv):
+        """16 heads of 128 over blocks of 128: the decode row takes the
+        batched form (16 rows), a window of 3 the loop over the heads;
+        chains end in the first, a middle and the last table entry, one
+        tail block holds a single position, one slot is all padding."""
+        q, kp, vp, rows, pos = _cell_case(w)
+        ref = paged_window_attention(q, kp, vp, rows, pos, impl="lax")
+        got = paged_window_attention(q, kp, vp, rows, pos,
+                                     impl="pallas", interpret=True,
+                                     block_kv=block_kv, slots_tile=1)
+        assert not np.asarray(got[4]).any()
+        np.testing.assert_allclose(np.asarray(got[:4]),
+                                   np.asarray(ref[:4]),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("heads,w", [(H, 1), (H, 3), (H6, 3)])
+    def test_inactive_all_trash_slot_emits_zero(self, heads, w):
+        kp, vp = _pools(9, heads=heads)
+        rows, pos = _ragged_case(w)
         rows = rows.at[2].set(TRASH_BLOCK)     # slot 2 fully inactive
-        q = _q(11, S, H, 1, HD)
+        pos = pos.at[2].set(0)
+        q = _q(11, S, heads, w, HD)
         got = paged_window_attention(q, kp, vp, rows, pos,
                                      impl="pallas", interpret=True)
         assert not np.asarray(got[2]).any()
@@ -143,6 +195,49 @@ class TestKernelInterpret:
         np.testing.assert_allclose(np.asarray(got[:2]),
                                    np.asarray(ref[:2]),
                                    rtol=1e-5, atol=1e-5)
+
+    def test_blocks_past_the_window_are_skipped_not_read(self):
+        """A chain may hold blocks the window has not reached (a prompt's
+        chain is whole while its chunks prefill): they are neither
+        fetched nor computed, and what they hold changes nothing."""
+        kp, vp = _pools(4)
+        rows, _ = _ragged_case(1)
+        pos = jnp.asarray([BL - 1, 2 * BL + 1, 0], jnp.int32)
+        q = _q(12, S, H, 1, HD)
+        got = paged_window_attention(q, kp, vp, rows, pos,
+                                     impl="pallas", interpret=True)
+        beyond = np.asarray([2, 9])            # slot 0's and 1's unreached
+        bad = [p.at[beyond].set(jnp.nan) for p in (kp, vp)]
+        again = paged_window_attention(q, *bad, rows, pos,
+                                       impl="pallas", interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+        ref = paged_window_attention(q, kp, vp, rows, pos, impl="lax")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("w", [1, 3])
+    def test_index_map_repeats_the_last_live_block(self, w):
+        """The index map alone, over a table with padded rows: it never
+        names an entry past ``(pos + w - 1) // block_len``, and repeats
+        that one for every later cell (an unchanged index is a copy the
+        pipeline does not issue)."""
+        rows, pos = _ragged_case(w)
+        rows_np, pos_np = np.asarray(rows), np.asarray(pos)
+        fetches = 0
+        for s in range(S):
+            last = (pos_np[s] + w - 1) // BL
+            named = [int(chain_block(rows, pos[:, None], s, j, w=w,
+                                     block_len=BL)) for j in range(MB)]
+            assert named[:last + 1] == list(rows_np[s, :last + 1])
+            assert set(named[last:]) == {int(rows_np[s, last])}
+            assert TRASH_BLOCK not in named
+            fetches += len(set(named))
+        assert fetches == int((rows_np != TRASH_BLOCK).sum())
+        # a slot whose row is all padding maps to the trash block once
+        empty = jnp.full((1, MB), TRASH_BLOCK, jnp.int32)
+        assert {int(chain_block(empty, jnp.zeros((1, 1), jnp.int32), 0, j,
+                                w=w, block_len=BL))
+                for j in range(MB)} == {TRASH_BLOCK}
 
     @pytest.mark.slow
     @pytest.mark.parametrize("w", [1, 4])
