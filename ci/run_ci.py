@@ -76,7 +76,8 @@ PACKAGES: dict[str, list[str]] = {
     # disaggregated prefill/decode + in-batch speculation + the
     # paged-attention kernel equivalence suite
     "llm": ["test_paged_kv.py", "test_llm_serving.py",
-            "test_paged_attention.py", "test_latent_moe_decoder.py"],
+            "test_paged_attention.py", "test_latent_moe_decoder.py",
+            "test_paged_kv_state.py", "test_sparse_linear_decoder.py"],
     # zero-downtime model lifecycle: versioned registry + blue/green
     # router + canary burn-rate rollback, and the rollout acceptance
     "deploy": ["test_deploy.py"],

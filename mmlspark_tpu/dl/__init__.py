@@ -48,6 +48,7 @@ _EXPORTS = {
     "paged_window_attention": ".pallas_paged_attention",
     "paged_latent_attention": ".pallas_paged_attention",
     "LatentMoEDecoder": ".latent_moe_decoder",
+    "SparseLinearDecoder": ".sparse_linear_decoder",
 }
 
 __all__ = sorted(_EXPORTS)

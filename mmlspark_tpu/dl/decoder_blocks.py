@@ -1,0 +1,78 @@
+"""What the config-driven decoders of ``serving.llm.LLMEngine`` share
+(``dl.latent_moe_decoder``, ``dl.sparse_linear_decoder``): RMSNorm in
+float32, the matrix product on operands of the serving type with float32
+accumulation, the gated SiLU MLP, the two rotary pairings, and the calling
+convention the engine uses for a decoder that is a plain class over a
+params dict.
+
+A configuration dict keeps the published ``config.json`` keys; what else it
+holds (``source``, ``reduced``, ``assumed``, ``published``, ``deployment``)
+is the benchmark's to state and a decoder's to ignore.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["rms_norm", "mm", "gated_silu", "rotate_interleaved",
+           "rotate_half", "rope_angles", "DictDecoder"]
+
+
+def rms_norm(x, scale, eps: float):
+    """RMSNorm over the last axis in float32, times ``scale``."""
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def mm(a, w, dtype):
+    """``a @ w`` on operands of ``dtype`` with float32 accumulation."""
+    return jnp.matmul(a.astype(dtype), w, preferred_element_type=jnp.float32)
+
+
+def gated_silu(u, gate, up, down, dtype):
+    return mm(jax.nn.silu(mm(u, gate, dtype)) * mm(u, up, dtype), down, dtype)
+
+
+def rope_angles(positions, inv_freq):
+    """``[..., dim / 2]`` angles of whole ``positions`` [...]."""
+    return positions.astype(jnp.float32)[..., None] * inv_freq
+
+
+def rotate_interleaved(x, cos, sin):
+    """Turn the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis
+    by their angles; first members land in the first half, second in the
+    second (only dot products of two rotated vectors are taken)."""
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def rotate_half(x, cos, sin):
+    """Turn the pairs ``(x[i], x[i + dim/2])`` of the last axis by their
+    angles (the ``rotate_half`` pairing of the Llama-style rotary code);
+    each member stays where it was."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+class DictDecoder:
+    """A decoder that is a plain class over a params dict: the engine
+    calls every decoder as ``module.apply({"params": ...}, *args,
+    method=...)``."""
+
+    dtype = jnp.dtype(jnp.bfloat16)
+    eps = 1e-6
+
+    def apply(self, variables, *args, method="walk"):
+        return getattr(self, method)(variables["params"], *args)
+
+    def _rms(self, x, scale):
+        return rms_norm(x, scale, self.eps)
+
+    def _mm(self, a, w):
+        return mm(a, w, self.dtype)
+
+    def _gated(self, u, gate, up, down):
+        return gated_silu(u, gate, up, down, self.dtype)

@@ -54,6 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.moe import MOE_STATS, dropless_moe, route_top_k
+from .decoder_blocks import DictDecoder
+from .decoder_blocks import rotate_interleaved as _rotate
 from .paged_kv import scatter_positions
 from .pallas_paged_attention import (latent_max_window,
                                      paged_latent_attention)
@@ -87,15 +89,7 @@ def yarn_inv_freq(dim: int, base: float, scaling: dict) -> np.ndarray:
     return (scaled * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
-def _rotate(x, cos, sin):
-    """Turn the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis
-    by their angles; first members land in the first half, second in the
-    second (only dot products of two rotated vectors are taken)."""
-    a, b = x[..., 0::2], x[..., 1::2]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
-class LatentMoEDecoder:
+class LatentMoEDecoder(DictDecoder):
     """See the module docstring. ``config`` keeps whatever else it holds
     (``source``, ``reduced``, ``assumed``); ``dtype`` is the type of the
     weights, the matrix products' operands and the cache."""
@@ -159,24 +153,7 @@ class LatentMoEDecoder:
                 "rope_scaling": dict(c["rope_scaling"]),
                 **{k: c[k] for k in keys}}
 
-    def apply(self, variables, *args, method="walk"):
-        """The calling convention the engine uses for every decoder."""
-        return getattr(self, method)(variables["params"], *args)
-
-    # -- pieces ---------------------------------------------------------------
-    def _rms(self, x, scale):
-        x = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.eps) * scale.astype(jnp.float32)
-
-    def _mm(self, a, w):
-        return jnp.matmul(a.astype(self.dtype), w,
-                          preferred_element_type=jnp.float32)
-
-    def _gated(self, u, gate, up, down):
-        return self._mm(jax.nn.silu(self._mm(u, gate)) * self._mm(u, up),
-                        down)
-
+    # -- pieces (RMSNorm, the product, the gated MLP: DictDecoder) -----------
     def _expert_layer(self, u, lw, valid):
         """``FFN(u)`` of an expert layer over ``u`` [T, D] float32: this
         holder's routed terms plus the shared experts'; and the counts."""

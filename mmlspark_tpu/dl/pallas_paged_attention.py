@@ -627,3 +627,279 @@ def paged_latent_attention(q, pool, rows, pos, *, scale: float,
     return _paged_latent_pallas(q, pool, rows, pos, scale=float(scale),
                                 value_dim=int(value_dim), tile_w=tile_w,
                                 interpret=bool(interpret))
+
+
+# ============================================ block-sparse grouped-query
+# Paged attention in which (1) several query heads share a key head (a
+# GROUP: the key head's block is fetched once for all of them and they are
+# the rows of one product) and (2) a query row attends a LIST of chosen
+# blocks of ``block_size`` tokens, not its slot's whole chain: the grid
+# walks the list, which rides scalar prefetch as the table does above, and
+# a cell past the list's end fetches nothing. Below a model's dense length
+# the caller lists every block up to the row, and the same kernel walks
+# the whole chain. The unit is ONE query token of one group: each token
+# chooses for itself, so a window of ``w`` tokens is ``w`` lists.
+#
+# The pool rests ``[num_blocks, block_len, kv_heads * 2 * hd]``: a token's
+# key heads side by side on the lanes, each head's KEY AND VALUE side by
+# side (``[k_g | v_g]``), so that a chosen block of one head is ONE copy of
+# ``[block_size, 2 * hd]`` (first chip run, PR 33: a cell's time went with
+# the count of copies and of products, not with their bytes). A chosen
+# block is rows ``[m * block_size, (m + 1) * block_size)`` of the sequence,
+# so with ``block_len`` a multiple of ``block_size`` it is one slab of the
+# pool viewed ``[num_blocks * block_len / block_size, block_size, ...]``.
+# A grid cell is handed ``_SPARSE_FETCH`` slabs and scores them in ONE
+# product (the slabs laid end to end), one softmax update, one weighted sum.
+#
+# The choice is made by the caller from the scores of :func:`select_scores`:
+# every query row against the slot's COMPRESSED keys, which rest a few rows
+# a block in a pool of their own ``[num_blocks, rows_per_block, kv_heads *
+# hd]`` and are read through the same table.
+
+#: the kernels' own names in a device trace
+SPARSE_KERNEL_NAME = "paged_sparse_attn"
+SELECT_KERNEL_NAME = "paged_sparse_select"
+#: chosen blocks one grid cell of the sparse kernel is handed (that many
+#: fetches and one pair of products share a cell's fixed cost), and table
+#: entries one cell of the scoring pass is handed
+_SPARSE_FETCH = 16
+_SELECT_FETCH = 16
+_SELECT_ROW_TILE = 512
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "scale"))
+def _sparse_reference(q, kv_pool, phys, logical, qpos, *,
+                      block_size: int, scale: float):
+    """Pure-lax twin of the sparse kernel: gather every listed slab,
+    scores in float32, ``-inf`` outside ``t <= qpos`` and at entries with
+    ``logical < 0``, softmax, NaN→0 for rows with nothing to see, the
+    weights in the pool's type against the values."""
+    T, G, R, hd = q.shape
+    bs = block_size
+    slabs = kv_pool.reshape(-1, bs, G, 2 * hd)
+    g = jnp.arange(G)[None, :, None]
+    kv = slabs[phys, :, g]                               # [T, G, K, bs, 2hd]
+    k, v = kv[..., :hd], kv[..., hd:]
+    s = jnp.einsum("tgrd,tgkbd->tgrkb", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    tpos = logical[..., None] * bs + jnp.arange(bs)      # [T, G, K, bs]
+    allowed = (logical[..., None] >= 0) & (tpos <= qpos[:, None, None, None])
+    s = jnp.where(allowed[:, :, None], s, -jnp.inf)
+    K = phys.shape[-1]
+    p = jax.nn.softmax(s.reshape(T, G, R, K * bs), axis=-1)
+    p = jnp.where(jnp.isnan(p), 0.0, p).reshape(T, G, R, K, bs)
+    return jnp.einsum("tgrkb,tgkbd->tgrd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+def _sparse_kernel(phys_ref, logical_ref, qpos_ref, q_ref, *refs,
+                   scale: float, block_size: int, fetch: int, groups: int,
+                   listed: int):
+    kv_refs = refs[:fetch]
+    o_ref, m_scr, l_scr, acc_scr = refs[fetch:]
+    t = pl.program_id(0)
+    g = pl.program_id(1)
+    c = pl.program_id(2)
+    R, hd = q_ref.shape[2], q_ref.shape[3]
+    bs = block_size
+
+    @pl.when(c == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    base = (t * groups + g) * listed + c * fetch
+
+    # the list has its real entries first: a cell whose first
+    # entry is past the list's end has nothing to see (and fetched nothing)
+    @pl.when(logical_ref[base] >= 0)
+    def _compute():
+        kv = kv_refs[0][0] if fetch == 1 else jnp.concatenate(
+            [r[0] for r in kv_refs], axis=0)             # [fetch*bs, 2hd]
+        s = jax.lax.dot_general(                         # [R, fetch*bs] f32
+            q_ref[0, 0], kv[:, :hd], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # each column's position in the sequence: its slab's block index
+        # times block_size plus its row; past the list's end, out of sight
+        first = jnp.full(s.shape, -1, jnp.int32)
+        for i in range(fetch):
+            first = jnp.where(lane >= i * bs, logical_ref[base + i], first)
+        tpos = first * bs + lane % bs
+        allowed = (first >= 0) & (tpos <= qpos_ref[t])
+        p, corr = _online_softmax(s, allowed, m_scr, l_scr, R)
+        acc_scr[:R, :] = acc_scr[:R, :] * corr + jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, hd:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _emit():
+        l = jnp.maximum(l_scr[:R, :1], 1e-35)
+        o_ref[0, 0] = (acc_scr[:R] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "scale",
+                                             "interpret"))
+def _paged_sparse_attn_pallas(q, kv_pool, phys, logical, qpos, *,
+                              block_size: int, scale: float,
+                              interpret: bool):
+    T, G, R, hd = q.shape
+    bs = block_size
+    K = phys.shape[-1]
+    nf = min(_SPARSE_FETCH, K)
+    Kp = -(-K // nf) * nf
+    # past the list's end the last slab again (no fetch), marked unseen
+    phys = jnp.pad(phys, ((0, 0), (0, 0), (0, Kp - K)), mode="edge")
+    logical = jnp.pad(logical, ((0, 0), (0, 0), (0, Kp - K)),
+                      constant_values=-1)
+    slabs = kv_pool.reshape(-1, bs, G * 2 * hd)
+    Rp = max(R, 8)
+    kern = functools.partial(_sparse_kernel, scale=scale, block_size=bs,
+                             fetch=nf, groups=G, listed=Kp)
+
+    def slab(i):
+        return pl.BlockSpec(
+            (1, bs, 2 * hd), lambda t, g, c, ph, lg, qp:
+            (ph[(t * G + g) * Kp + c * nf + i], 0, g))
+
+    own = pl.BlockSpec((1, 1, R, hd), lambda t, g, c, ph, lg, qp:
+                       (t, g, 0, 0))
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(T, G, Kp // nf),
+            in_specs=[own] + [slab(i) for i in range(nf)],
+            out_specs=own,
+            scratch_shapes=[pltpu.VMEM((Rp, 128), jnp.float32),
+                            pltpu.VMEM((Rp, 128), jnp.float32),
+                            pltpu.VMEM((Rp, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((T, G, R, hd), kv_pool.dtype),
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret, name=SPARSE_KERNEL_NAME,
+    )(phys.reshape(-1), logical.reshape(-1), qpos, q, *([slabs] * nf))
+
+
+def sparse_block_attention(q, kv_pool, phys, logical, qpos, *,
+                           block_size: int, scale: float,
+                           impl: str | None = None,
+                           interpret: bool | None = None):
+    """Grouped-query attention of single query tokens over LISTED blocks.
+    ``q`` [T, G, R, hd]: ``T`` query tokens, ``G`` key heads, the ``R``
+    query heads that share each; ``kv_pool`` ONE layer's pool
+    ``[num_blocks, block_len, G*2*hd]``, a head's key and value side by
+    side; ``phys``/``logical`` [T, G, K] int32 the list a (token, key
+    head) attends: ``logical`` the block's index in the sequence (tokens
+    ``logical * block_size ...``; the real entries first, in any order,
+    ``-1`` past the list's end) and ``phys`` the slab of the pool that
+    holds it (``table entry * (block_len / block_size) + offset``; past
+    the list's end the last real entry again, so that nothing is fetched
+    there); ``qpos`` [T] the tokens' global positions: a row sees tokens
+    ``<= qpos`` of its listed blocks. Returns [T, G, R, hd] in the pool's
+    type.
+
+    ``impl``: "pallas" | "lax" | None (the platform switch of
+    :func:`paged_window_attention`); ``interpret`` forces the Pallas
+    interpreter (tests)."""
+    plat = target_platform()
+    if impl is None:
+        impl = "pallas" if plat == "tpu" else "lax"
+    phys = jnp.asarray(phys, jnp.int32)
+    logical = jnp.asarray(logical, jnp.int32)
+    qpos = jnp.asarray(qpos, jnp.int32)
+    if impl == "lax":
+        return _sparse_reference(q, kv_pool, phys, logical, qpos,
+                                 block_size=int(block_size),
+                                 scale=float(scale))
+    if impl != "pallas":
+        raise ValueError(f"impl={impl!r} is not one of pallas|lax")
+    if interpret is None:
+        interpret = plat != "tpu"
+    return _paged_sparse_attn_pallas(
+        q, kv_pool, phys, logical, qpos, block_size=int(block_size),
+        scale=float(scale), interpret=bool(interpret))
+
+
+# ----------------------------------------------------- the scoring pass
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _select_reference(q, ck_pool, rows, *, scale: float):
+    S, G, M, hd = q.shape
+    NB, rpb, _ = ck_pool.shape
+    ck = ck_pool.reshape(NB, rpb, G, hd)[rows]         # [S, MB, rpb, G, hd]
+    ck = ck.reshape(S, -1, G, hd)
+    return jnp.einsum("sgmd,scgd->sgmc", q, ck,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _select_kernel(rows_ref, q_ref, *refs, scale: float, fetch: int):
+    ck_refs, o_ref = refs[:fetch], refs[fetch]
+    G, hd = q_ref.shape[1], q_ref.shape[3]
+    ck = jnp.concatenate([r[0] for r in ck_refs], axis=0) if fetch > 1 \
+        else ck_refs[0][0]                               # [fetch*rpb, G*hd]
+    for g in range(G):
+        o_ref[0, g] = jax.lax.dot_general(
+            q_ref[0, g], ck[:, g * hd:(g + 1) * hd],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_sparse_select_pallas(q, ck_pool, rows, *, scale: float,
+                                interpret: bool):
+    S, G, M, hd = q.shape
+    rpb = ck_pool.shape[1]
+    MB = rows.shape[1]
+    nf = max(_SELECT_FETCH, -(-128 // rpb))            # whole lanes out
+    MBp = -(-MB // nf) * nf
+    rows = jnp.pad(rows, ((0, 0), (0, MBp - MB)),
+                   constant_values=TRASH_BLOCK)
+    tm = min(_SELECT_ROW_TILE, M)
+    Mp = -(-M // tm) * tm
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, Mp - M), (0, 0)))
+    kern = functools.partial(_select_kernel, scale=scale, fetch=nf)
+
+    def entry(i):
+        return pl.BlockSpec((1, rpb, G * hd), lambda s, r, c, rt:
+                            (rt[s, c * nf + i], 0, 0))
+
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, Mp // tm, MBp // nf),
+            in_specs=[pl.BlockSpec((1, G, tm, hd), lambda s, r, c, rt:
+                                   (s, 0, r, 0))]
+            + [entry(i) for i in range(nf)],
+            out_specs=pl.BlockSpec((1, G, tm, nf * rpb),
+                                   lambda s, r, c, rt: (s, 0, r, c))),
+        out_shape=jax.ShapeDtypeStruct((S, G, Mp, MBp * rpb), jnp.float32),
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret, name=SELECT_KERNEL_NAME,
+    )(rows, q, *([ck_pool] * nf))
+    return out[:, :, :M, :MB * rpb]
+
+
+def select_scores(q, ck_pool, rows, *, scale: float,
+                  impl: str | None = None, interpret: bool | None = None):
+    """The scoring pass of block selection: every query row against every
+    compressed key of its slot's chain. ``q`` [S, G, M, hd] (``M`` rows of
+    key head ``g``: a window's tokens times the query heads that share
+    it); ``ck_pool`` ONE layer's compressed-key pool ``[num_blocks,
+    rows_per_block, G*hd]``; ``rows`` [S, max_blocks] the block table.
+    Returns ``[S, G, M, max_blocks * rows_per_block]`` float32 ``q . ck *
+    scale``, column ``b * rows_per_block + r`` row ``r`` of the chain's
+    ``b``-th block: EVERY column, also where no key has been written (the
+    caller knows from the positions which columns hold one)."""
+    plat = target_platform()
+    if impl is None:
+        impl = "pallas" if plat == "tpu" else "lax"
+    rows = jnp.asarray(rows, jnp.int32)
+    if impl == "lax":
+        return _select_reference(q, ck_pool, rows, scale=float(scale))
+    if impl != "pallas":
+        raise ValueError(f"impl={impl!r} is not one of pallas|lax")
+    if interpret is None:
+        interpret = plat != "tpu"
+    return _paged_sparse_select_pallas(q, ck_pool, rows, scale=float(scale),
+                                       interpret=bool(interpret))

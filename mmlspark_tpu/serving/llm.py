@@ -51,9 +51,11 @@ allocates nothing per step (donation is skipped off-TPU, where XLA
 ignores it with a warning).
 
 The engine knows nothing of a model's layers. It asks a DECODER MODULE
-for what one token takes in each layer's cache (``cache_spec()``: the
-arrays, as trailing shape and dtype — ``paged_kv.init_pools`` /
-``pool_block_bytes`` / ``scatter_positions`` take that spec), for a
+for what each layer caches (``cache_spec()``: the arrays, as trailing
+shape, dtype and KIND — one entry a token, one every ``n`` tokens, or one
+a sequence whatever its length; ``paged_kv.init_pools`` /
+``pool_block_bytes`` / ``state_row_bytes`` / ``scatter_positions`` take
+that spec), for a
 walk over a window of tokens through the paged pools
 (``module.apply({"params": ...}, toks, pools, rows, pos, valid,
 method="walk") -> (hidden, pools, counts)``: the window's ``[S, w,
@@ -67,22 +69,38 @@ already holds: a prefill program asks for ONE row a prompt (the last
 prompt row of the chunk in which the prompt ends) and for none in a
 chunk where no prompt ends; the decode step for its one row a slot; the
 speculative verify for all ``k + 1`` rows of its window. The model's
-type picks the path — ``dl.MaskedLMModel`` (per-head k and v pools) or
-``dl.LatentMoEDecoder`` (one latent array a layer, dropless experts) —
-and no flag does.
+type picks the path — ``dl.MaskedLMModel`` (per-head k and v pools),
+``dl.LatentMoEDecoder`` (one latent array a layer, dropless experts) or
+``dl.SparseLinearDecoder`` (grouped-query k and v pools with compressed
+keys beside them, blocks chosen inside paged attention, and a recurrent
+state a sequence in the lightning layers) — and no flag does.
+
+A decoder that caches arrays a SEQUENCE gets rows for them from the same
+block manager (``PagedKVManager(state_slots=...)``; the pools of all
+three kinds are ONE pytree, donated to every program alike), its walk is
+handed the slots' rows after the arguments every walk takes, and prefix
+reuse goes by STATE SNAPSHOT: admission after a prefix hit copies the
+snapshot's row into the sequence's row on the device (an
+``llm.state_restore`` span under ``llm.prefill``), a chunked prefill
+carries the row from chunk to chunk, and a prompt that brings new whole
+chunks is cut at its last one, where its state is copied into a snapshot
+row that ``publish`` indexes with the blocks. Speculation is refused
+beside such a decoder: a state cannot be rewound.
 
 Obs: every boundary is an ``llm.step`` span on the tracer's ring with
 ``llm.prefill`` and ``llm.decode`` children; a decoder's walk counts
 land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
-``moe_expert_load_max``); ``gen_ttft_seconds{reuse=cold|warm}``,
+``moe_expert_load_max``; ``sparse_blocks_chosen_total``,
+``sparse_blocks_in_chain_total``, ``sparse_dense_rows_total``);
+``gen_ttft_seconds{reuse=cold|warm}``,
 ``gen_tokens_total``, ``gen_prefill_calls_total{head=row|none}``
 (prefill program calls by what they emit),
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
-``gen_decode_attn_seconds{phase}`` here, the ``kv_*`` families in
-``dl.paged_kv`` — all federated fleet-wide and recorded by the
-telemetry history plane. Completions land FeatureLog rows with
+``gen_decode_attn_seconds{phase}`` here, the ``kv_*`` families
+(``kv_state_*`` among them) in ``dl.paged_kv`` — all federated
+fleet-wide and recorded by the telemetry history plane. Completions land FeatureLog rows with
 ``decode_steps``/``prefill_tokens``/``context_blocks`` so the cost
 model prices the two phases separately and decode by resident context
 (``perf.costmodel``, schema v5).
@@ -97,9 +115,10 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..core import aot
-from ..dl.paged_kv import (OutOfBlocks, PagedKVManager,
-                           blocks_for_hbm_budget, init_pools,
-                           pool_block_bytes)
+from ..dl.paged_kv import (TRASH_ROW, OutOfBlocks, PagedKVManager,
+                           blocks_for_hbm_budget, copy_state_rows,
+                           init_pools, pool_block_bytes,
+                           state_row_bytes)
 from ..obs import registry as _default_registry
 from ..obs.attribution import cost_attribution
 from ..obs.profile import compile_tracker, feature_log
@@ -303,6 +322,33 @@ class PrefillExecutor:
         self._walk_stats = _WalkStats(module, reg, service)
         self._programs: dict[tuple[int, bool], object] = {}
         self._fps: dict[str, tuple[str, str]] = {}
+        # a decoder with per-sequence cache arrays: its walk takes the
+        # rows' state rows, and snapshots are copied row to row
+        self.stateful = bool(kv.state_slots)
+        self._copy = None
+
+    def _copy_program(self):
+        """Rows of the per-sequence arrays copied onto other rows, ``batch``
+        pairs a call (a snapshot restored, a snapshot taken), the pools
+        donated like every program's."""
+        if self._copy is None:
+            spec = self.module.cache_spec()
+            donate = _donate_pools_kwargs()
+            self._copy = compile_tracker.jit(
+                lambda pools, src, dst: copy_state_rows(spec, pools, src,
+                                                        dst),
+                name=f"llm_state_copy_{self.service}_b{self.batch}",
+                **({"donate_argnums": (0,)} if donate else {}))
+        return self._copy
+
+    def _copy_rows(self, pairs: list) -> None:
+        import jax.numpy as jnp
+        src = np.full(self.batch, TRASH_ROW, np.int32)
+        dst = np.full(self.batch, TRASH_ROW, np.int32)
+        for i, (a, b) in enumerate(pairs):
+            src[i], dst[i] = a, b
+        self.pools.target = self._copy_program()(
+            self.pools.target, jnp.asarray(src), jnp.asarray(dst))
 
     # -- compiled programs per window bucket -------------------------------
     def _program(self, w: int, head: bool):
@@ -315,12 +361,13 @@ class PrefillExecutor:
         module, draft = self.module, self.draft_module
         pad_id, P = self.pad_id, self.batch
 
-        def run(params, dparams, pools_t, pools_d, rows, toks, pos, lens):
+        def run(params, dparams, pools_t, pools_d, rows, toks, pos, lens,
+                *srows):
             valid = (jnp.arange(w)[None] < lens[:, None]) & \
                 (lens[:, None] > 0)
             hidden, pools_t, counts = module.apply(
                 {"params": params}, toks, pools_t, rows, pos, valid,
-                method="walk")                          # [P, w, W]
+                *srows, method="walk")                  # [P, w, W]
             if draft is not None:
                 _, pools_d, _ = draft.apply(
                     {"params": dparams}, toks, pools_d, rows, pos, valid,
@@ -372,65 +419,113 @@ class PrefillExecutor:
         attending what the chunks before it wrote, and a chunk in which
         no prompt of the batch ends through the program with no head —
         commits lengths (``kv.advance`` + ``kv.publish``), returns
-        ``seq_id -> (first_token, suffix_len)``."""
+        ``seq_id -> (first_token, suffix_len)``.
+
+        With per-sequence cache arrays a row that reuses a prefix first
+        has the prefix's snapshot copied into its state row (an
+        ``llm.state_restore`` span; on the device), and a prompt that
+        brings new whole chunks is fed in two stretches, cut at its last
+        whole chunk (``snapshot_at``), where its state is copied into a
+        snapshot row before the rest is fed."""
         import jax
         import jax.numpy as jnp
         out: dict = {}
         P = self.batch
         for start in range(0, len(jobs), P):
-            metas = []
+            metas, restore = [], []
             for seq_id, prompt in jobs[start:start + P]:
                 h = self.kv.handle(seq_id)
                 # a fully reused prompt still re-feeds its last token:
                 # the window must emit logits for the first generated
                 # position (the rewrite stores bit-identical kv)
                 s0 = min(h.reused_tokens, h.prompt_len - 1)
-                metas.append((seq_id, list(prompt), s0,
+                metas.append((seq_id, np.asarray(prompt), s0,
                               h.prompt_len - s0))
+                if h.restore_row is not None:
+                    restore.append((h.restore_row, h.state_row))
+            if restore:
+                with _tracer.span("llm.state_restore", rows=len(restore)):
+                    self._copy_rows(restore)
+                for seq_id, *_ in metas:
+                    self.kv.restored(seq_id)
             ids: list = [m[0] for m in metas]
-            rows = jnp.asarray(self.kv.block_rows(
-                ids + [None] * (P - len(ids)), self.max_blocks))
+            padded = ids + [None] * (P - len(ids))
+            rows = jnp.asarray(self.kv.block_rows(padded, self.max_blocks))
+            state_rows = self.kv.state_rows(padded) if self.stateful \
+                else None
             firsts: dict = {}
             counts: list = []           # each call's walk counts
-            done = 0                    # suffix tokens fed so far
-            for w in self.windows_for(max(m[3] for m in metas)):
-                # a row whose suffix ended in an earlier chunk rides
-                # along like a padding row: position 0, length 0
-                toks = np.zeros((P, w), np.int32)
-                pos = np.zeros(P, np.int32)
-                lens = np.zeros(P, np.int32)
-                for i, (_, prompt, s0, n) in enumerate(metas):
-                    k = min(n - done, w)
-                    if k > 0:
-                        toks[i, :k] = prompt[s0 + done:s0 + done + k]
-                        pos[i] = s0 + done
+
+            def feed(spans):
+                """One stretch of every row: ``spans[i]`` is row ``i``'s
+                ``(first position, tokens)``."""
+                done = 0                # tokens of the stretch fed so far
+                for w in self.windows_for(max(n for _, n in spans)):
+                    # a row whose stretch ended in an earlier chunk rides
+                    # along like a padding row: position 0, length 0
+                    toks = np.zeros((P, w), np.int32)
+                    pos = np.zeros(P, np.int32)
+                    lens = np.zeros(P, np.int32)
+                    srows = np.full(P, TRASH_ROW, np.int32)
+                    ends = []
+                    for i, (p0, n) in enumerate(spans):
+                        k = min(n - done, w)
+                        if k <= 0:
+                            continue
+                        prompt = metas[i][1]
+                        toks[i, :k] = prompt[p0 + done:p0 + done + k]
+                        pos[i] = p0 + done
                         lens[i] = k
-                # known on the host before the call: whose last token
-                # is in this chunk
-                ends = [i for i, m in enumerate(metas)
-                        if done < m[3] <= done + w]
-                prog = self._program(w, bool(ends))
-                self._c_calls.inc(1, service=self.service,
-                                  head="row" if ends else "none")
-                t0 = time.perf_counter()
-                pools_t, pools_d, first, count = prog(
-                    self.variables["params"],
-                    None if self.draft_module is None
-                    else self.draft_variables["params"],
-                    self.pools.target, self.pools.draft,
-                    rows, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lens))
-                if self._walk_stats:
-                    counts.append(count)
-                self._h_attn.observe(time.perf_counter() - t0,
-                                     service=self.service,
-                                     phase="prefill")
-                self.pools.target = pools_t
-                if self.draft_module is not None:
-                    self.pools.draft = pools_d
-                for i in ends:
-                    firsts[i] = first
-                done += w
+                        if self.stateful:
+                            srows[i] = state_rows[i]
+                        # known on the host before the call: whose last
+                        # token is in this chunk
+                        if p0 + done + k == len(prompt):
+                            ends.append(i)
+                    prog = self._program(w, bool(ends))
+                    self._c_calls.inc(1, service=self.service,
+                                      head="row" if ends else "none")
+                    t0 = time.perf_counter()
+                    pools_t, pools_d, first, count = prog(
+                        self.variables["params"],
+                        None if self.draft_module is None
+                        else self.draft_variables["params"],
+                        self.pools.target, self.pools.draft,
+                        rows, jnp.asarray(toks),
+                        jnp.asarray(pos), jnp.asarray(lens),
+                        *((jnp.asarray(srows),) if self.stateful else ()))
+                    if self._walk_stats:
+                        counts.append(count)
+                    self._h_attn.observe(time.perf_counter() - t0,
+                                         service=self.service,
+                                         phase="prefill")
+                    self.pools.target = pools_t
+                    if self.draft_module is not None:
+                        self.pools.draft = pools_d
+                    for i in ends:
+                        firsts[i] = first
+                    done += w
+
+            # where a row's feed is cut: the boundary its snapshot is
+            # taken at, when the prompt goes on past it
+            cuts = []
+            for seq_id, prompt, s0, _ in metas:
+                at = self.kv.handle(seq_id).snapshot_at
+                cuts.append(at if at is not None and at < len(prompt)
+                            else len(prompt))
+            feed([(s0, cut - s0) for (_, _, s0, _), cut in zip(metas, cuts)])
+            if self.stateful:
+                taken = []
+                for seq_id, *_ in metas:
+                    h = self.kv.handle(seq_id)
+                    if h.snapshot_at is not None:
+                        row = self.kv.take_snapshot(seq_id)
+                        if row is not None:
+                            taken.append((h.state_row, row))
+                if taken:
+                    self._copy_rows(taken)
+            if any(cut < len(m[1]) for m, cut in zip(metas, cuts)):
+                feed([(cut, len(m[1]) - cut) for m, cut in zip(metas, cuts)])
             # ONE fetch: the first tokens and the calls' counts together
             firsts, counts = jax.device_get((firsts, counts))
             for count in counts:
@@ -457,6 +552,8 @@ class PrefillExecutor:
             chunks = self.windows_for(n)
             kinds.update((w, True) for w in chunks)
             kinds.update((w, False) for w in chunks[:-1])
+        if self.stateful:               # trash row onto trash row
+            self._copy_rows([])
         for w, head in sorted(kinds):
             rows = jnp.zeros((P, self.max_blocks), jnp.int32)
             prog = self._program(w, head)
@@ -466,7 +563,8 @@ class PrefillExecutor:
                 else self.draft_variables["params"],
                 self.pools.target, self.pools.draft, rows,
                 jnp.zeros((P, w), jnp.int32), jnp.zeros(P, jnp.int32),
-                jnp.zeros(P, jnp.int32))
+                jnp.zeros(P, jnp.int32),
+                *((jnp.zeros(P, jnp.int32),) if self.stateful else ()))
             # attribution must lower BEFORE the call: donation
             # invalidates the pool buffers the args reference
             _attribute_warm(prog, self.service, *args)
@@ -567,16 +665,22 @@ class DecodeExecutor:
 
         if k == 0:
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
-                    end, active):
+                    end, active, *srows):
                 hidden, pools_t, counts = module.apply(
                     {"params": params}, last[:, None], pools_t, rows,
-                    ptr - 1, active[:, None], method="walk")  # [S, 1, W]
+                    ptr - 1, active[:, None], *srows,
+                    method="walk")                      # [S, 1, W]
                 logits = module.apply({"params": params}, hidden,
                                       method="logits")  # every row: w = 1
                 committed = _greedy(logits[:, 0], pad_id)[:, None]  # [S, 1]
                 n_new = jnp.where(active, 1, 0)
                 return pools_t, pools_d, committed, n_new, n_new, counts
         else:
+            if self.kv.state_slots:
+                raise ValueError(
+                    "speculative decoding rewinds a slot by the tokens it "
+                    "rejects, and a per-sequence state cannot be rewound")
+
             def run(params, dparams, pools_t, pools_d, rows, last, ptr,
                     end, active):
                 pos = ptr - 1
@@ -665,9 +769,9 @@ class DecodeExecutor:
             if runnable[s]:
                 self.kv.ensure_capacity(self.seq_ids[s],
                                         int(self.ptr[s]) + self.spec_k)
-        rows = self.kv.block_rows(
-            [sid if runnable[i] else None
-             for i, sid in enumerate(self.seq_ids)], self.max_blocks)
+        running = [sid if runnable[i] else None
+                   for i, sid in enumerate(self.seq_ids)]
+        rows = self.kv.block_rows(running, self.max_blocks)
         prog = self._build()
         t0 = time.perf_counter()
         pools_t, pools_d, committed, n_new, n_acc, counts = prog(
@@ -676,7 +780,9 @@ class DecodeExecutor:
             else self.draft_variables["params"],
             self.pools.target, self.pools.draft, jnp.asarray(rows),
             jnp.asarray(self.last), jnp.asarray(self.ptr),
-            jnp.asarray(self.end), jnp.asarray(runnable))
+            jnp.asarray(self.end), jnp.asarray(runnable),
+            *((jnp.asarray(self.kv.state_rows(running)),)
+              if self.kv.state_slots else ()))
         self._h_attn.observe(time.perf_counter() - t0,
                              service=self.service, phase="decode")
         self.pools.target = pools_t
@@ -713,7 +819,8 @@ class DecodeExecutor:
             self.pools.target, self.pools.draft,
             jnp.zeros((S, self.max_blocks), jnp.int32),
             jnp.zeros(S, jnp.int32), jnp.ones(S, jnp.int32),
-            jnp.full(S, 2, jnp.int32), jnp.zeros(S, bool))
+            jnp.full(S, 2, jnp.int32), jnp.zeros(S, bool),
+            *((jnp.zeros(S, jnp.int32),) if self.kv.state_slots else ()))
         # attribution must lower BEFORE the call: donation invalidates
         # the pool buffers the args reference
         _attribute_warm(prog, self.service, *args)
@@ -727,7 +834,7 @@ class DecodeExecutor:
 
 @dataclass
 class _SeqMeta:
-    prompt: list
+    prompt: np.ndarray
     max_new_tokens: int
     t_submit: float
     slot: int | None = None
@@ -761,7 +868,8 @@ class LLMEngine:
                  num_blocks: int | None = None, spec_k: int = 0,
                  pad_id: int = 0, prefill_batch: int = 2,
                  hbm_fraction: float = 0.5, service: str = "llm",
-                 registry=None, clock=time.monotonic):
+                 registry=None, clock=time.monotonic,
+                 state_slots: int | None = None):
         reg = registry if registry is not None else _default_registry
         self.module = module
         self.variables = variables
@@ -786,14 +894,28 @@ class LLMEngine:
             num_blocks = blocks_for_hbm_budget(
                 block_bytes, fraction=hbm_fraction,
                 default=1 + 2 * slots * self.max_blocks)
+        # a decoder that keeps arrays a SEQUENCE (a recurrent state)
+        # gets rows for them from the same manager: one a slot, and as
+        # many again for snapshots unless the caller says how many
+        row_bytes = state_row_bytes(spec)
+        if not row_bytes:
+            state_slots = 0
+        elif state_slots is None:
+            state_slots = 2 * int(slots)
+        if draft_module is not None and (
+                row_bytes or state_row_bytes(draft_module.cache_spec())):
+            raise ValueError("a draft model beside per-sequence cache "
+                             "arrays is not supported")
+        row_blocks = -(-row_bytes // block_bytes)
         self.kv = PagedKVManager(
             num_blocks, self.block_len,
             block_budget=blocks_for_hbm_budget(
                 block_bytes, fraction=hbm_fraction,
-                default=num_blocks - 1),
-            service=service, registry=reg)
+                default=num_blocks - 1 + state_slots * row_blocks),
+            service=service, registry=reg, state_slots=state_slots,
+            state_row_bytes=row_bytes, state_row_blocks=row_blocks)
         self.pools = _PoolState(
-            init_pools(spec, num_blocks, self.block_len),
+            init_pools(spec, num_blocks, self.block_len, state_slots),
             None if draft_module is None else init_pools(
                 draft_module.cache_spec(), num_blocks, self.block_len))
         self.sched = SlotScheduler(slots, service=service,
@@ -844,7 +966,9 @@ class LLMEngine:
     # -- intake ------------------------------------------------------------
     def submit(self, seq_id, prompt, max_new_tokens: int,
                deadline: float | None = None) -> None:
-        prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
+        # kept as one array from here to the block table's hashes and the
+        # prefill windows: a 65,536-token prompt is not a list of ints
+        prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) + int(max_new_tokens) > self.max_seq_len:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens "
@@ -973,10 +1097,9 @@ class LLMEngine:
         # prompt + [prefill's first token] + decode commits, trimmed to
         # the budget (a final speculative burst can overshoot by 0 —
         # the decode step clamps — but trim defensively anyway)
-        full = meta.prompt + [int(meta.first_token)] + \
-            [int(t) for t in meta.generated]
-        return np.asarray(full[:len(meta.prompt) + meta.max_new_tokens],
-                          np.int32)
+        full = np.concatenate([meta.prompt, [meta.first_token],
+                               meta.generated]).astype(np.int32)
+        return full[:len(meta.prompt) + meta.max_new_tokens]
 
     # -- warmup / acceptance -----------------------------------------------
     def warm(self, prefill_windows=(1,), mark_steady: bool = True

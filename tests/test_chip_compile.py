@@ -53,10 +53,12 @@ def steer_tpu(monkeypatch):
     """Whole programs ask ``target_platform()`` which path to build, and
     here it answers ``cpu``: steer it from the test (not through an
     option of the program) so that the chip's path is what is lowered."""
+    import mmlspark_tpu.dl.pallas_lightning as lightning
     import mmlspark_tpu.dl.pallas_paged_attention as paged
     import mmlspark_tpu.utils.platform as plat
     monkeypatch.setattr(plat, "target_platform", lambda: "tpu")
     monkeypatch.setattr(paged, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(lightning, "target_platform", lambda: "tpu")
 
 
 def _sds(shape, dtype, sharding):
@@ -318,6 +320,141 @@ def test_latent_moe_engine_programs(one_chip, steer_tpu):
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= at_rest, name
         assert mem.temp_size_in_bytes < 100e6, (name,
+                                                mem.temp_size_in_bytes)
+        assert mem.argument_size_in_bytes < 11.7e9, name
+
+
+@pytest.mark.parametrize("block_len", [128, 256, 512])
+@pytest.mark.parametrize("kernel", ["sparse-decode", "sparse-prefill256",
+                                    "select-decode", "select-prefill256"])
+def test_block_sparse_attention_kernels(one_chip, kernel, block_len):
+    """MiniCPM-SALA's widths (16 query heads a key head, 2 key heads of
+    128, blocks of 64 chosen out of chains of 66,560 tokens, a pool of
+    385k tokens): the sparse kernel over lists of 128 entries (its scalar
+    prefetch holds a list a (token, key head)) and the scoring pass over
+    the compressed-key pool, for the decode step's 128 tokens and a
+    prefill window's 256."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_paged_attention import (
+        select_scores, sparse_block_attention)
+    G, R, hd, bs, K = 2, 16, 128, 64, 128
+    NB, MB, rpb = 385024 // block_len, -(-66560 // block_len), \
+        block_len // 16
+    bf16 = jnp.bfloat16
+    if kernel.startswith("sparse"):
+        T = 128 if kernel.endswith("decode") else 256
+        _compile(lambda q, kv, ph, lg, qp: sparse_block_attention(
+            q, kv, ph, lg, qp, block_size=bs, scale=hd ** -0.5,
+            impl="pallas", interpret=False),
+            _sds((T, G, R, hd), bf16, one_chip),
+            _sds((NB, block_len, G * 2 * hd), bf16, one_chip),
+            _sds((T, G, K), jnp.int32, one_chip),
+            _sds((T, G, K), jnp.int32, one_chip),
+            _sds((T,), jnp.int32, one_chip))
+    else:
+        S, M = (128, R) if kernel.endswith("decode") else (1, 256 * R)
+        _compile(lambda q, ck, rows: select_scores(
+            q, ck, rows, scale=hd ** -0.5, impl="pallas", interpret=False),
+            _sds((S, G, M, hd), bf16, one_chip),
+            _sds((NB, rpb, G * hd), bf16, one_chip),
+            _sds((S, MB), jnp.int32, one_chip))
+
+
+@pytest.mark.parametrize("window", [1, 8, 32, 192, 256, 512],
+                         ids=lambda w: f"w{w}")
+def test_lightning_attention_kernels(one_chip, window):
+    """32 heads of 128 over a pool of 135 states: the decode step (128
+    slots, ``w`` = 1) and the chunked form of a prefill window; the pool
+    comes back in the buffer it arrived in."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_lightning import lightning_attention
+    S, H, hd, rows = (128 if window == 1 else 1), 32, 128, 135
+    qkv = [_sds((S, window, H, hd), jnp.bfloat16, one_chip)] * 3
+    compiled = jax.jit(
+        lambda q, k, v, st, sr, ps, ln, sl: lightning_attention(
+            q, k, v, st, sr, ps, ln, sl, impl="pallas", interpret=False),
+        donate_argnums=(3,)).lower(
+            *qkv, _sds((rows, H, hd, hd), jnp.float32, one_chip),
+            *[_sds((S,), jnp.int32, one_chip)] * 3,
+            _sds((H,), jnp.float32, one_chip)).compile()
+    assert KERNEL in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= rows * H * hd * hd * 4
+
+
+def test_sparse_linear_engine_programs(one_chip, steer_tpu):
+    """The engine's decode program and its widest prefill programs for
+    the block-sparse / lightning decoder at the benchmark cell's own sizes
+    (``benchmark/configs/minicpm-sala.json``: 12 layers, 128 slots, a pool
+    of 385k tokens and 135 state rows): every pool of the three kinds
+    donated and not copied, the four kernels there, weights and pools
+    inside the chip's memory with the temporaries of a prefill window
+    (its scores against every compressed key) under half a gigabyte."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run
+    from benchmark.references import minicpm_sala as ref
+    from mmlspark_tpu.obs.metrics import MetricsRegistry
+
+    _, wl, cfg, params = run.load_cell("minicpm-sala.long-doc-qa",
+                                       run.load_bench())
+    driver = run._load_module("drivers", wl["driver"])
+
+    def leaf(entry):
+        return _sds(entry[1], jnp.bfloat16, one_chip)
+
+    weights = {k: leaf(v) for k, v in ref.top_shapes(cfg).items()}
+    weights["layers"] = [
+        {k: leaf(v) for k, v in ref.layer_shapes(cfg, i).items()}
+        for i in range(int(cfg["num_hidden_layers"]))]
+    eng = params["engine"]
+    num_blocks, rows = int(eng["num_blocks"]), int(eng["state_slots"]) + 1
+    small = {**params, "engine": {**eng, "num_blocks": 4, "state_slots": 2}}
+    engine = driver.build_engine(cfg, small, weights, MetricsRegistry())
+    pools = jax.tree.map(
+        lambda a: _sds(((num_blocks if a.ndim == 3 else rows),)
+                       + a.shape[1:], a.dtype, one_chip),
+        engine.pools.target)
+    S, MB, P = engine.decoder.slots, engine.max_blocks, \
+        engine.prefiller.batch
+    w = engine.prefiller.max_window
+    assert (S, P, w) == (128, 1, int(params["prefill_chunk"]))
+    assert MB * int(eng["block_len"]) == 66560
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    programs = {
+        "decode": engine.decoder._build().lower(
+            weights, None, pools, None, i32(S, MB), i32(S), i32(S),
+            i32(S), _sds((S,), jnp.bool_, one_chip), i32(S))}
+    for head in (True, False):
+        programs[f"prefill_w{w}_head{head:d}"] = \
+            engine.prefiller._program(w, head).lower(
+                weights, None, pools, None, i32(P, MB), i32(P, w), i32(P),
+                i32(P), i32(P))
+    at_rest = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree.leaves(pools))
+    assert at_rest == 3 * num_blocks * int(eng["block_len"]) * 256 * 2 \
+        * (2 + 1 / 16) + 9 * rows * 32 * 128 * 128 * 4
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        step = "lightning_step" if name == "decode" else "lightning_chunk"
+        for kernel in ("paged_sparse_attn", "paged_sparse_select", step):
+            assert kernel in text, (name, kernel)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= at_rest, name
+        assert mem.temp_size_in_bytes < 500e6, (name,
                                                 mem.temp_size_in_bytes)
         assert mem.argument_size_in_bytes < 11.7e9, name
 
