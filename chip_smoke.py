@@ -615,10 +615,9 @@ def phase_llm(rec: dict, *, vocab: int = 32768, width: int = 512,
 
     decode_args = (
         variables["params"], None, engine.pools.target, engine.pools.draft,
-        jnp.zeros((slots, engine.max_blocks), jnp.int32),
-        jnp.zeros(slots, jnp.int32), jnp.ones(slots, jnp.int32),
-        jnp.full(slots, 2, jnp.int32), jnp.zeros(slots, bool))
-    decode_text = engine.decoder._build().lower(*decode_args).as_text()
+        *engine.programs.blank(True, None))
+    decode_text = engine.programs.get(True, None).lower(
+        *decode_args).as_text()
     rec.update(prefill_windows=windows,
                decode_kernel_calls=decode_text.count(KERNEL),
                decode_steps=int(total("gen_decode_steps_total")),

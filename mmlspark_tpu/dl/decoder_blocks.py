@@ -3,7 +3,11 @@
 float32, the matrix product on operands of the serving type with float32
 accumulation, the gated SiLU MLP, the two rotary pairings, and the calling
 convention the engine uses for a decoder that is a plain class over a
-params dict.
+params dict; and what every decoder's ``walk`` shares (``dl.pretrain``'s
+too): the rows of several windows laid end to end, so that whatever acts a
+row at a time runs once over all of them and reads its weights once, and
+split again for what acts a window at a time (the scatter into the cache
+and attention).
 
 A configuration dict keeps the published ``config.json`` keys; what else it
 holds (``source``, ``reduced``, ``assumed``, ``published``, ``deployment``)
@@ -16,7 +20,33 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["rms_norm", "mm", "gated_silu", "rotate_interleaved",
-           "rotate_half", "rope_angles", "DictDecoder"]
+           "rotate_half", "rope_angles", "DictDecoder", "lay_rows",
+           "split_rows", "window_positions"]
+
+
+def lay_rows(parts):
+    """The rows of several windows end to end: arrays ``[S_i, w_i, ...]``
+    with one trailing shape → ``[sum S_i * w_i, ...]``."""
+    flat = [p.reshape((-1,) + p.shape[2:]) for p in parts]
+    return flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=0)
+
+
+def split_rows(rows, shapes):
+    """:func:`lay_rows` undone: ``rows`` [T, ...] → a tuple of ``[S_i,
+    w_i, ...]``, ``shapes`` the windows' ``(S_i, w_i)``."""
+    out, at = [], 0
+    for S, w in shapes:
+        out.append(rows[at:at + S * w].reshape((S, w) + rows.shape[1:]))
+        at += S * w
+    return tuple(out)
+
+
+def window_positions(windows):
+    """``[S_i, w_i]`` global positions of each window's rows: a window is
+    ``(toks [S, w], rows, pos [S], valid, ...)`` and its slot ``s`` holds
+    positions ``[pos[s], pos[s] + w)``."""
+    return tuple(win[2][:, None] + jnp.arange(win[0].shape[1])[None]
+                 for win in windows)
 
 
 def rms_norm(x, scale, eps: float):

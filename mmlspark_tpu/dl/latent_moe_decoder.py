@@ -47,6 +47,7 @@ applied as ``x @ w``.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -54,7 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.moe import MOE_STATS, dropless_moe, route_top_k
-from .decoder_blocks import DictDecoder
+from .decoder_blocks import (DictDecoder, lay_rows, split_rows,
+                             window_positions)
 from .decoder_blocks import rotate_interleaved as _rotate
 from .paged_kv import scatter_positions
 from .pallas_paged_attention import (latent_max_window,
@@ -173,66 +175,95 @@ class LatentMoEDecoder(DictDecoder):
                                     lw["shared_down"]), stats
 
     # -- the walk -------------------------------------------------------------
-    def walk(self, params, toks, pools, rows, pos, valid):
-        """[S, w] token ids at per-slot global positions ``[pos[s],
-        pos[s] + w)`` → ``([S, w, D] float32 hidden rows after the last
-        block, updated pools, int32 counts named by ``walk_stats``)``,
-        reading and writing the latent pools IN PLACE through the block
-        table: per layer the window's ``[c_kv, k_rope]`` is scattered
-        first (``valid`` False sends a row's write to the trash block),
-        then every row attends its slot's chain up to itself. Prefill
-        windows and the decode step (``w`` = 1) alike. No head: the
-        caller picks the rows a token is sampled from and asks
+    #: the walk takes any number of windows in one call
+    several_windows = True
+
+    def walk(self, params, windows, pools):
+        """A tuple of WINDOWS, each ``(toks [S, w], rows, pos [S], valid)``
+        — [S, w] token ids at per-slot global positions ``[pos[s], pos[s]
+        + w)`` → ``(a tuple of [S, w, D] float32 hidden rows after the
+        last block, one a window; updated pools; int32 counts named by
+        ``walk_stats``)``, reading and writing the latent pools IN PLACE
+        through each window's block table. The decode step (``w`` = 1), a
+        prefill window, or both in one call.
+
+        Everything that acts a row at a time (norms, the projections, the
+        absorbed query, the output projection, the router and the experts)
+        runs ONCE over all the windows' rows laid end to end, so each
+        weight is read once and a held expert sees the pairs of all the
+        rows; per layer and window the ``[c_kv, k_rope]`` entries are
+        scattered first (``valid`` False sends a row's write to the trash
+        block), then every row attends its slot's chain up to itself. No
+        head: the caller picks the rows a token is sampled from and asks
         :meth:`logits` for those alone."""
-        S, w = toks.shape
         H, C, R = self.heads, self.latent, self.rope
         pad = self.cache_width - (C + R)
-        x = params["embed"][toks].astype(jnp.float32)       # [S, w, D]
-        wrote = pos[:, None] + jnp.arange(w)[None]           # [S, w]
-        ang = wrote.astype(jnp.float32)[..., None] * self._inv_freq
-        cos = jnp.cos(ang) * self._rope_scale                # [S, w, R/2]
+        shapes = [win[0].shape for win in windows]
+        wrote = window_positions(windows)                    # [S, w] each
+        x = params["embed"][lay_rows([win[0] for win in windows])] \
+            .astype(jnp.float32)                             # [T, D]
+        T = x.shape[0]
+        ang = lay_rows(wrote).astype(jnp.float32)[:, None] * self._inv_freq
+        cos = jnp.cos(ang) * self._rope_scale                # [T, R/2]
         sin = jnp.sin(ang) * self._rope_scale
-        token_valid = jnp.broadcast_to(valid, (S, w)).reshape(-1)
-        stats = jnp.zeros((len(MOE_STATS),), jnp.int32)
-        new_pools = []
-        for i, (lw, (pool,)) in enumerate(zip(params["layers"], pools)):
+        token_valid = lay_rows([jnp.broadcast_to(win[3], win[0].shape)
+                                for win in windows])
+        tables = tuple((rows, pos, valid, at) for (_, rows, pos, valid), at
+                       in zip(windows, wrote))
+
+        # ONE jitted function a kind of layer (the layers of a kind have
+        # the same shapes, each its own weights): a program traces and
+        # lowers one expert layer, not every one
+        @functools.partial(jax.jit, static_argnames="dense")
+        def layer(lw, x, pool, tables, rotary, token_valid, dense: bool):
+            cos, sin = rotary
             u = self._rms(x, lw["attn_norm"])
             c_q = self._rms(self._mm(u, lw["q_a"]), lw["q_a_norm"])
-            q = self._mm(c_q, lw["q_b"]).reshape(S, w, H, self.nope + R)
-            kv = self._mm(u, lw["kv_a"])                     # [S, w, C+R]
+            q = self._mm(c_q, lw["q_b"]).reshape(T, H, self.nope + R)
+            kv = self._mm(u, lw["kv_a"])                     # [T, C+R]
             c_kv = self._rms(kv[..., :C], lw["kv_a_norm"])
             k_rope = _rotate(kv[..., C:], cos, sin)
             entry = jnp.concatenate(
-                [c_kv, k_rope, jnp.zeros((S, w, pad), jnp.float32)],
+                [c_kv, k_rope, jnp.zeros((T, pad), jnp.float32)],
                 axis=-1).astype(pool.dtype)
-            ((pool,),) = scatter_positions(((pool,),), rows, wrote,
-                                           ((entry,),), valid=valid)
-            new_pools.append((pool,))
             kv_b = lw["kv_b"].reshape(C, H, self.nope + self.v_dim)
             q_lat = jnp.einsum(
-                "swhn,chn->swhc", q[..., :self.nope].astype(self.dtype),
+                "thn,chn->thc", q[..., :self.nope].astype(self.dtype),
                 kv_b[..., :self.nope], preferred_element_type=jnp.float32)
-            q_rope = _rotate(q[..., self.nope:], cos[:, :, None],
-                             sin[:, :, None])
+            q_rope = _rotate(q[..., self.nope:], cos[:, None], sin[:, None])
             q_abs = jnp.concatenate(
-                [q_lat, q_rope, jnp.zeros((S, w, H, pad), jnp.float32)],
+                [q_lat, q_rope, jnp.zeros((T, H, pad), jnp.float32)],
                 axis=-1).astype(pool.dtype)
-            o_lat = paged_latent_attention(
-                q_abs, pool, rows, pos, scale=self.softmax_scale,
-                value_dim=C)                                 # [S, w, H, C]
-            out = jnp.einsum("swhc,chv->swhv", o_lat,
+            o_lat = []
+            for (rows, pos, valid, at), e, qa in zip(
+                    tables, split_rows(entry, shapes),
+                    split_rows(q_abs, shapes)):
+                ((pool,),) = scatter_positions(((pool,),), rows, at,
+                                               ((e,),), valid=valid)
+                o_lat.append(paged_latent_attention(
+                    qa, pool, rows, pos, scale=self.softmax_scale,
+                    value_dim=C))                            # [S, w, H, C]
+            out = jnp.einsum("thc,chv->thv", lay_rows(o_lat),
                              kv_b[..., self.nope:],
                              preferred_element_type=jnp.float32)
-            h = x + self._mm(out.reshape(S, w, H * self.v_dim), lw["o"])
+            h = x + self._mm(out.reshape(T, H * self.v_dim), lw["o"])
             u = self._rms(h, lw["ffn_norm"])
-            if i < self.dense_layers:
-                x = h + self._gated(u, lw["gate"], lw["up"], lw["down"])
-                continue
-            ffn, counts = self._expert_layer(
-                u.reshape(S * w, self.width), lw, token_valid)
-            x = h + ffn.reshape(S, w, self.width)
-            stats = stats.at[:3].add(counts[:3]).at[3].max(counts[3])
-        return x, tuple(new_pools), stats
+            if dense:
+                return h + self._gated(u, lw["gate"], lw["up"],
+                                       lw["down"]), pool, None
+            ffn, counts = self._expert_layer(u, lw, token_valid)
+            return h + ffn, pool, counts
+
+        stats = jnp.zeros((len(MOE_STATS),), jnp.int32)
+        new_pools = []
+        for i, (lw, (pool,)) in enumerate(zip(params["layers"], pools)):
+            x, pool, counts = layer(lw, x, pool, tables, (cos, sin),
+                                    token_valid,
+                                    dense=i < self.dense_layers)
+            new_pools.append((pool,))
+            if counts is not None:
+                stats = stats.at[:3].add(counts[:3]).at[3].max(counts[3])
+        return split_rows(x, shapes), tuple(new_pools), stats
 
     def logits(self, params, hidden):
         """The head over the rows the caller picked out of a walk's

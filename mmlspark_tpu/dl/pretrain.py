@@ -105,56 +105,88 @@ class MaskedLMModel(nn.Module):
                 "depth": enc.depth, "heads": enc.heads,
                 "mlp_dim": enc.mlp_dim, "dtype": np.dtype(enc.dtype).name}
 
-    def walk(self, toks, pools, rows, pos, valid):
-        """The paged decode forward WITHOUT the head: [S, w] token ids at
-        per-slot global positions ``[pos[s], pos[s]+w)`` → ([S, w, width]
-        hidden rows after the last block, updated pools, None),
-        reading/writing the pools IN PLACE through the block table. The
-        caller picks the rows a token is sampled from and asks
-        :meth:`logits` for those alone (the engine: one row a prompt in
-        prefill, none in a chunk where no prompt ends, every row of a
-        decode or verify window).
+    #: the walk takes any number of windows in one call
+    several_windows = True
 
-        Per block: project qkv, scatter the window's kv through the table
-        (write-then-attend, the order ``decode_step``/``decode_window``
-        keep; ``valid`` False redirects a row's writes to the trash
-        block), then ``dl.paged_window_attention`` over each slot's own
-        chain — no dense gather anywhere. The embed/projection/attention/
-        ffn math, with :meth:`logits` after it, is element-for-element
-        the ``embed_window → decode_window_blocks → lm_head`` composition
+    def walk(self, windows, pools):
+        """The paged decode forward WITHOUT the head over a tuple of
+        WINDOWS, each ``(toks [S, w], rows [S, max_blocks], pos [S], valid
+        [S, w] or [S, 1])``: [S, w] token ids at per-slot global positions
+        ``[pos[s], pos[s]+w)`` → (a tuple of [S, w, width] hidden rows
+        after the last block, one a window; updated pools; None),
+        reading/writing the pools IN PLACE through each window's block
+        table. The engine hands over the decoding rows (``w`` = 1), a
+        prefill window, or both at a boundary where a prompt's window
+        rides with the decoding rows. The caller picks the rows a token
+        is sampled from and asks :meth:`logits` for those alone.
+
+        What acts a row at a time (embedding, norms, the qkv and output
+        projections, the feed-forward) runs ONCE over all the windows'
+        rows laid end to end (``decoder_blocks.lay_rows``), so each weight
+        is read once a call. Per block and window: scatter the window's
+        kv through its table (write-then-attend, the order
+        ``decode_step``/``decode_window`` keep; ``valid`` False redirects
+        a row's writes to the trash block), then
+        ``dl.paged_window_attention`` over each slot's own chain — no
+        dense gather anywhere. The embed/projection/attention/ffn math,
+        with :meth:`logits` after it, is element-for-element the
+        ``embed_window → decode_window_blocks → lm_head`` composition
         (the lax attention path shares ``decode_window``'s exact
         formulation), so greedy tokens stay byte-identical to
         ``dl.generate`` on CPU tier-1.
 
         Runs under ``module.apply(..., method="walk")``."""
+        from .decoder_blocks import lay_rows, split_rows, window_positions
         from .paged_kv import scatter_positions
         from .pallas_paged_attention import paged_window_attention
 
         enc = self.encoder
-        w = toks.shape[1]
+        shapes = [win[0].shape for win in windows]
+        wrote = window_positions(windows)                   # [S, w] each
         # batched embed_window: same constants/ops per element, positions
-        # per slot instead of one traced scalar
-        x = enc.embed_layer(toks)                           # [S, w, W]
-        dim = jnp.arange(enc.width // 2)[None, None, :]
-        p = (pos[:, None] + jnp.arange(w)[None, :]
-             ).astype(jnp.float32)[:, :, None]
+        # per row instead of one traced scalar
+        x = enc.embed_layer(lay_rows([win[0] for win in windows]))  # [T, W]
+        dim = jnp.arange(enc.width // 2)[None, :]
+        p = lay_rows(wrote).astype(jnp.float32)[:, None]
         ang = p / (10000.0 ** (2 * dim / enc.width))
         pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-        x = x + pe.astype(enc.dtype)
-        wrote = pos[:, None] + jnp.arange(w)[None]          # [S, w]
+        x = (x + pe.astype(enc.dtype))[None]                # [1, T, W]
+        # ONE jitted function serves every block (same shapes, each its
+        # own parameters): a program of 24 blocks traces and lowers one
+        # block, not 24, which is most of what ``engine.warm`` takes a
+        # program once the compile cache holds it
+        block = type(enc.blocks[0])(
+            enc.heads, enc.mlp_dim, enc.width,
+            attention_fn=enc.attention_fn, dtype=enc.dtype, parent=None)
+        tables = tuple((rows, pos, valid, at) for (_, rows, pos, valid), at
+                       in zip(windows, wrote))
+
+        @jax.jit
+        def layer(params, x, kp, vp, tables):
+            def call(method, *args):
+                return block.apply({"params": params}, *args, method=method)
+
+            q, k, v = (jnp.moveaxis(a[0], 0, 1)             # [T, H, hd]
+                       for a in call("_project_qkv", x))
+            # a token's heads side by side, as the pools hold them
+            ks, vs = (split_rows(a.reshape(-1, enc.width).astype(kp.dtype),
+                                 shapes) for a in (k, v))   # [S, w, W]
+            outs = []
+            for (rows, pos, valid, at), qw, kw, vw in zip(
+                    tables, split_rows(q, shapes), ks, vs):
+                (kp, vp), = scatter_positions(
+                    ((kp, vp),), rows, at, ((kw, vw),), valid=valid)
+                o = paged_window_attention(
+                    qw.transpose(0, 2, 1, 3), kp, vp, rows, pos)
+                outs.append(o.transpose(0, 2, 1, 3))        # [S, w, H, hd]
+            o = jnp.moveaxis(lay_rows(outs), 0, 1)[None]    # [1, H, T, hd]
+            return call("ffn", x + call("_merge_out", o)), kp, vp
+
         new_pools = []
         for blk, (kp, vp) in zip(enc.blocks, pools):
-            q, k, v = blk._project_qkv(x)                   # [S, H, w, hd]
-            (kp, vp), = scatter_positions(
-                ((kp, vp),), rows, wrote,
-                (tuple(a.transpose(0, 2, 1, 3).reshape(
-                    a.shape[0], w, enc.width).astype(kp.dtype)
-                    for a in (k, v)),),
-                valid=valid)
-            o = paged_window_attention(q, kp, vp, rows, pos)
-            x = blk.ffn(x + blk._merge_out(o))
+            x, kp, vp = layer(blk.variables["params"], x, kp, vp, tables)
             new_pools.append((kp, vp))
-        return x, tuple(new_pools), None
+        return split_rows(x[0], shapes), tuple(new_pools), None
 
     def logits(self, hidden):
         """The head over the rows the caller picked out of a walk's

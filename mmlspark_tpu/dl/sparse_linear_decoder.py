@@ -55,13 +55,15 @@ Every matrix is applied as ``x @ w``.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .decoder_blocks import DictDecoder, rope_angles, rotate_half
+from .decoder_blocks import (DictDecoder, lay_rows, rope_angles,
+                             rotate_half, split_rows, window_positions)
 from .paged_kv import scatter_positions, scatter_rows
 from .pallas_lightning import lightning_attention
 from .pallas_paged_attention import select_scores, sparse_block_attention
@@ -261,108 +263,139 @@ class SparseLinearDecoder(DictDecoder):
                             done)
 
     # -- the mixers ----------------------------------------------------------
-    def _sparse_layer(self, lw, u, pools, rows, pos, valid, t, stats):
-        S, w, _ = u.shape
+    # each takes ``u`` [T, D], the rows of all the windows end to end: its
+    # projections and its gate run once over them; the cache writes, the
+    # choosing and attention run a window at a time
+    def _sparse_layer(self, lw, u, pools, windows, shapes, wrote, stats):
+        T = u.shape[0]
         H, G, hd = self.heads, self.kv_heads, self.hd
         R = H // G
         kv_pool, ck_pool = pools
         BL = kv_pool.shape[1]
-        q = self._rms(self._mm(u, lw["q"]).reshape(S, w, G, R, hd),
+        q = self._rms(self._mm(u, lw["q"]).reshape(T, G, R, hd),
                       lw["q_norm"]).astype(self.dtype)
-        k = self._rms(self._mm(u, lw["k"]).reshape(S, w, G, hd),
+        k = self._rms(self._mm(u, lw["k"]).reshape(T, G, hd),
                       lw["k_norm"])
-        v = self._mm(u, lw["v"]).reshape(S, w, G, hd)
-        entry = jnp.concatenate([k, v], axis=-1).reshape(S, w, G * 2 * hd)
-        ((kv_pool,),) = scatter_positions(
-            ((kv_pool,),), rows, t, ((entry.astype(kv_pool.dtype),),),
-            valid=valid)
-        lens = jnp.sum(valid, axis=1).astype(jnp.int32)
-        ck_pool = self._compressed(kv_pool, ck_pool, rows, pos, lens, w)
+        v = self._mm(u, lw["v"]).reshape(T, G, hd)
+        entry = jnp.concatenate([k, v], axis=-1).reshape(T, G * 2 * hd)
         scale = hd ** -0.5
-        scores = select_scores(
-            jnp.transpose(q, (0, 2, 1, 3, 4)).reshape(S, G, w * R, hd),
-            ck_pool, rows, scale=scale)
-        phys, logical, dense = self._choose(
-            scores.reshape(S, G, w, R, -1), t, rows, BL)
-        K = phys.shape[-1]
+        outs = []
+        for (_, rows, pos, valid, _), (S, w), q, entry, t in zip(
+                windows, shapes, split_rows(q, shapes),
+                split_rows(entry.astype(kv_pool.dtype), shapes), wrote):
+            valid = jnp.broadcast_to(valid, (S, w))
+            ((kv_pool,),) = scatter_positions(
+                ((kv_pool,),), rows, t, ((entry,),), valid=valid)
+            lens = jnp.sum(valid, axis=1).astype(jnp.int32)
+            ck_pool = self._compressed(kv_pool, ck_pool, rows, pos, lens, w)
+            scores = select_scores(
+                jnp.transpose(q, (0, 2, 1, 3, 4)).reshape(S, G, w * R, hd),
+                ck_pool, rows, scale=scale)
+            phys, logical, dense = self._choose(
+                scores.reshape(S, G, w, R, -1), t, rows, BL)
+            K = phys.shape[-1]
 
-        def attend(listed: int):
-            return sparse_block_attention(
-                q.reshape(S * w, G, R, hd), kv_pool,
-                phys.reshape(S * w, G, K)[..., :listed],
-                logical.reshape(S * w, G, K)[..., :listed],
-                t.reshape(S * w), block_size=self.block, scale=scale)
+            def attend(listed: int, q=q, phys=phys, logical=logical, t=t,
+                       kv_pool=kv_pool):
+                n = t.size
+                return sparse_block_attention(
+                    q.reshape(n, G, R, hd), kv_pool,
+                    phys.reshape(n, G, K)[..., :listed],
+                    logical.reshape(n, G, K)[..., :listed],
+                    t.reshape(n), block_size=self.block, scale=scale)
 
-        # lists are as long as a dense row needs (dense_len / block_size);
-        # where no real row of the call is dense, ``topk`` entries hold every
-        # list (the real entries come first) and the grid is shorter
-        narrow = min(self.topk, K)
-        out = attend(K) if narrow == K else jax.lax.cond(
-            jnp.any(dense & valid), lambda: attend(K),
-            lambda: attend(narrow))
-        real = valid.astype(jnp.int32)
-        stats = stats + jnp.stack([
-            jnp.sum((logical >= 0).sum(axis=(2, 3)) * real),
-            jnp.sum((t // self.block + 1) * G * real),
-            jnp.sum(dense * real)]).astype(jnp.int32)
-        out = out.reshape(S, w, H * hd).astype(jnp.float32) \
+            # lists are as long as a dense row needs (dense_len /
+            # block_size); where no real row of the window is dense,
+            # ``topk`` entries hold every list (the real entries come
+            # first) and the grid is shorter
+            narrow = min(self.topk, K)
+            out = attend(K) if narrow == K else jax.lax.cond(
+                jnp.any(dense & valid), lambda: attend(K),
+                lambda: attend(narrow))
+            outs.append(out.reshape(S, w, H * hd))
+            real = valid.astype(jnp.int32)
+            stats = stats + jnp.stack([
+                jnp.sum((logical >= 0).sum(axis=(2, 3)) * real),
+                jnp.sum((t // self.block + 1) * G * real),
+                jnp.sum(dense * real)]).astype(jnp.int32)
+        out = lay_rows(outs).astype(jnp.float32) \
             * jax.nn.sigmoid(self._mm(u, lw["g"]))
         return self._mm(out, lw["o"]), (kv_pool, ck_pool), stats
 
-    def _lightning_layer(self, lw, u, state, srows, pos, valid, cos, sin,
+    def _lightning_layer(self, lw, u, state, windows, shapes, cos, sin,
                          slopes):
-        S, w, _ = u.shape
+        T = u.shape[0]
         H, hd = self.l_heads, self.l_hd
-        q = self._rms(self._mm(u, lw["q"]).reshape(S, w, H, hd),
-                      lw["q_norm"])
-        k = self._rms(self._mm(u, lw["k"]).reshape(S, w, H, hd),
-                      lw["k_norm"])
-        v = self._mm(u, lw["v"]).reshape(S, w, H, hd)
+        q = self._rms(self._mm(u, lw["q"]).reshape(T, H, hd), lw["q_norm"])
+        k = self._rms(self._mm(u, lw["k"]).reshape(T, H, hd), lw["k_norm"])
+        v = self._mm(u, lw["v"]).reshape(T, H, hd)
         q = rotate_half(q, cos, sin) * hd ** -0.5
         k = rotate_half(k, cos, sin)
-        o, state = lightning_attention(
-            q.astype(self.dtype), k.astype(self.dtype),
-            v.astype(self.dtype), state, srows, pos,
-            jnp.sum(valid, axis=1).astype(jnp.int32), slopes)
-        o = self._rms(o, lw["o_norm"]).reshape(S, w, H * hd) \
+        outs = []
+        for (_, _, pos, valid, srows), (S, w), q, k, v in zip(
+                windows, shapes, *(split_rows(a.astype(self.dtype), shapes)
+                                   for a in (q, k, v))):
+            lens = jnp.sum(jnp.broadcast_to(valid, (S, w)),
+                           axis=1).astype(jnp.int32)
+            o, state = lightning_attention(q, k, v, state, srows, pos, lens,
+                                           slopes)
+            outs.append(o)
+        o = self._rms(lay_rows(outs), lw["o_norm"]).reshape(T, H * hd) \
             * jax.nn.sigmoid(self._mm(u, lw["g"]))
         return self._mm(o, lw["o"]), state
 
     # -- the walk -------------------------------------------------------------
-    def walk(self, params, toks, pools, rows, pos, valid, srows):
-        """[S, w] token ids at per-slot global positions ``[pos[s],
-        pos[s] + w)`` → ``([S, w, D] float32 hidden rows after the last
-        block, updated pools, int32 counts named by ``walk_stats``)``.
-        ``valid`` [S, w] (or [S, 1]) marks the real rows, a prefix of each
-        slot's window; ``srows`` [S] are the slots' rows in the state
-        pools (the trash row for a slot that is not there). A slot at
-        position 0 starts from no state. Prefill windows and the decode
-        step (``w`` = 1) alike; no head."""
-        S, w = toks.shape
-        valid = jnp.broadcast_to(valid, (S, w))
-        x = params["embed"][toks].astype(jnp.float32) \
-            * float(self.config["scale_emb"])
-        t = pos[:, None] + jnp.arange(w)[None]               # [S, w]
-        ang = rope_angles(t, self._inv_freq)[:, :, None]     # [S, w, 1, hd/2]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
+    #: the walk takes any number of windows in one call
+    several_windows = True
+
+    def walk(self, params, windows, pools):
+        """A tuple of WINDOWS, each ``(toks [S, w], rows, pos [S], valid,
+        srows [S])`` — [S, w] token ids at per-slot global positions
+        ``[pos[s], pos[s] + w)`` → ``(a tuple of [S, w, D] float32 hidden
+        rows after the last block, one a window; updated pools; int32
+        counts named by ``walk_stats``)``. ``valid`` [S, w] (or [S, 1])
+        marks the real rows, a prefix of each slot's window; ``srows`` are
+        the slots' rows in the state pools (the trash row for a slot that
+        is not there). A slot at position 0 starts from no state. The
+        decode step (``w`` = 1), a prefill window, or both in one call:
+        the matrices run ONCE over all the windows' rows laid end to end,
+        the cache writes, the choosing, attention and the state's update
+        a window at a time (a window's ``w`` picks the lightning kernel).
+        No head."""
+        shapes = [win[0].shape for win in windows]
+        wrote = window_positions(windows)                    # [S, w] each
+        x = params["embed"][lay_rows([win[0] for win in windows])] \
+            .astype(jnp.float32) * float(self.config["scale_emb"])
+        ang = rope_angles(lay_rows(wrote), self._inv_freq)[:, None]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)                # [T, 1, hd/2]
+        # ONE jitted function a kind of layer (the layers of a kind have
+        # the same shapes, each its own weights and slopes): a program
+        # traces and lowers one sparse and one lightning layer, not twelve
+        @functools.partial(jax.jit, static_argnames="sparse")
+        def layer(lw, x, layer_pools, windows, wrote, rotary, stats, slopes,
+                  sparse: bool):
+            cos, sin = rotary
+            u = self._rms(x, lw["attn_norm"])
+            if sparse:
+                mixed, layer_pools, stats = self._sparse_layer(
+                    lw, u, layer_pools, windows, shapes, wrote, stats)
+            else:
+                mixed, state = self._lightning_layer(
+                    lw, u, layer_pools[0], windows, shapes, cos, sin, slopes)
+                layer_pools = (state,)
+            h = x + self.res_scale * mixed
+            return h + self.res_scale * self._gated(
+                self._rms(h, lw["ffn_norm"]), lw["gate"], lw["up"],
+                lw["down"]), tuple(layer_pools), stats
+
         stats = jnp.zeros((len(self.walk_stats),), jnp.int32)
         new_pools = []
         for i, (lw, layer_pools) in enumerate(zip(params["layers"], pools)):
-            u = self._rms(x, lw["attn_norm"])
-            if self.mixers[i] == SPARSE:
-                mixed, layer_pools, stats = self._sparse_layer(
-                    lw, u, layer_pools, rows, pos, valid, t, stats)
-            else:
-                mixed, state = self._lightning_layer(
-                    lw, u, layer_pools[0], srows, pos, valid, cos, sin,
-                    self._slopes[i])
-                layer_pools = (state,)
-            new_pools.append(tuple(layer_pools))
-            h = x + self.res_scale * mixed
-            x = h + self.res_scale * self._gated(
-                self._rms(h, lw["ffn_norm"]), lw["gate"], lw["up"],
-                lw["down"])
-        return x, tuple(new_pools), stats
+            x, layer_pools, stats = layer(
+                lw, x, layer_pools, windows, wrote, (cos, sin), stats,
+                self._slopes[i], sparse=self.mixers[i] == SPARSE)
+            new_pools.append(layer_pools)
+        return split_rows(x, shapes), tuple(new_pools), stats
 
     def logits(self, params, hidden):
         """The head over the rows the caller picked out of a walk's

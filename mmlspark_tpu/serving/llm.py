@@ -9,14 +9,21 @@ serving-shaped rebuild the ROADMAP names (and the TPU serving
 comparison in arXiv:2605.25645 measures): the two phases have opposite
 execution profiles — prefill is a large, MXU-saturating causal forward;
 decode is a tiny launch-latency-bound step — so they get SEPARATE
-executors with separate padding buckets and separate AOT-fingerprinted
-programs, stitched together by a handoff of (sequence, block chain)
-over the paged KV pool (``dl.paged_kv``):
+executors with separate padding buckets, stitched together by a handoff
+of (sequence, block chain) over the paged KV pool (``dl.paged_kv``);
+what they share is the model's weights, so where both have work at a
+boundary their rows go through ONE AOT-fingerprinted program
+(:class:`_Programs` builds every program: a walk over the decoding rows,
+a prefill window, or both):
 
-- :class:`PrefillExecutor` fills KV blocks in padding-bucketed batches
-  (one compiled program per window bucket), starting AFTER any
-  prefix-reused blocks — a warm prompt skips exactly the prefill the
-  cache already holds, which is the TTFT win the bench measures.
+- :class:`PrefillExecutor` fills KV blocks in padding-bucketed windows,
+  starting AFTER any prefix-reused blocks — a warm prompt skips exactly
+  the prefill the cache already holds, which is the TTFT win the bench
+  measures. While enough slots decode, a prompt's next window RIDES in
+  the decode step's program, one window a boundary, so the weights are
+  read once a boundary and no decoding slot waits for a prefill program;
+  with nothing to ride with (set-up, a cold start) its windows run in
+  programs of their own, back to back.
 - :class:`DecodeExecutor` runs the fixed-shape continuous-batching step
   over block tables. Attention reads the pools IN PLACE through the
   block table (``dl.pallas_paged_attention`` — the Pallas kernel on
@@ -57,17 +64,20 @@ a sequence whatever its length; ``paged_kv.init_pools`` /
 ``pool_block_bytes`` / ``state_row_bytes`` / ``scatter_positions`` take
 that spec), for a
 walk over a window of tokens through the paged pools
-(``module.apply({"params": ...}, toks, pools, rows, pos, valid,
-method="walk") -> (hidden, pools, counts)``: the window's ``[S, w,
-width]`` hidden rows after the last block and NO head; prefill windows
-and the decode step alike) and for the head over the rows it names
+(``module.apply({"params": ...}, windows, pools, method="walk") ->
+(hidden, pools, counts)``, ``windows`` a tuple of ``(toks [S, w], rows,
+pos, valid[, state rows])`` — the decode step's ``[S, 1]``, a prefill
+window's ``[P, w]``, or both — and ``hidden`` each window's ``[S, w,
+width]`` rows after the last block, NO head; ``several_windows`` says
+whether the walk takes more than one) and for the head over the rows it names
 (``module.apply({"params": ...}, hidden_rows, method="logits") ->
 [..., V]``); with them ``max_window()``, ``program_key()`` and
 ``walk_stats``, the names of the counts. Logits exist only for rows a
 token is sampled from, and each caller says which from the shapes it
 already holds: a prefill program asks for ONE row a prompt (the last
 prompt row of the chunk in which the prompt ends) and for none in a
-chunk where no prompt ends; the decode step for its one row a slot; the
+chunk where no prompt ends; the decode step for its one row a slot, and
+in the same call for one row a prompt of a window that rides with it; the
 speculative verify for all ``k + 1`` rows of its window. The model's
 type picks the path — ``dl.MaskedLMModel`` (per-head k and v pools),
 ``dl.LatentMoEDecoder`` (one latent array a layer, dropless experts) or
@@ -77,18 +87,22 @@ state a sequence in the lightning layers) — and no flag does.
 
 A decoder that caches arrays a SEQUENCE gets rows for them from the same
 block manager (``PagedKVManager(state_slots=...)``; the pools of all
-three kinds are ONE pytree, donated to every program alike), its walk is
-handed the slots' rows after the arguments every walk takes, and prefix
+three kinds are ONE pytree, donated to every program alike), each window
+of its walk ends in the slots' rows, and prefix
 reuse goes by STATE SNAPSHOT: admission after a prefix hit copies the
 snapshot's row into the sequence's row on the device (an
-``llm.state_restore`` span under ``llm.prefill``), a chunked prefill
-carries the row from chunk to chunk, and a prompt that brings new whole
-chunks is cut at its last one, where its state is copied into a snapshot
-row that ``publish`` indexes with the blocks. Speculation is refused
+``llm.state_restore`` span under ``llm.prefill``, before the boundary's
+program), a chunked prefill carries the row from chunk to chunk (from
+boundary to boundary where the chunks ride), and a prompt that brings new
+whole chunks is cut at its last one, where its state is copied into a
+snapshot row that ``publish`` indexes with the blocks. Speculation is refused
 beside such a decoder: a state cannot be rewound.
 
 Obs: every boundary is an ``llm.step`` span on the tracer's ring with
-``llm.prefill`` and ``llm.decode`` children; a decoder's walk counts
+``llm.prefill`` (the prefill programs of a boundary with nothing to ride
+with; the host's part of a riding window, whose real rows the root says
+as ``ride_rows``) and ``llm.decode`` (the block tables, the step's one
+program and its fetch) children; a decoder's walk counts
 land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
@@ -96,7 +110,9 @@ counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``sparse_blocks_in_chain_total``, ``sparse_dense_rows_total``);
 ``gen_ttft_seconds{reuse=cold|warm}``,
 ``gen_tokens_total``, ``gen_prefill_calls_total{head=row|none}``
-(prefill program calls by what they emit),
+(program calls that held a prefill window, by whether a prompt's first
+token came of it), ``gen_prefill_rows_total{ride=decode|alone}`` (prompt
+rows by how their window ran),
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
 ``gen_decode_attn_seconds{phase}`` here, the ``kv_*`` families
 (``kv_state_*`` among them) in ``dl.paged_kv`` — all federated
@@ -110,7 +126,9 @@ from __future__ import annotations
 
 import json
 import time
+from collections import deque
 from dataclasses import dataclass, field
+from itertools import islice
 
 import numpy as np
 
@@ -268,337 +286,42 @@ class _PoolState:
         self.draft = draft
 
 
-# --------------------------------------------------------------- executors
+# ---------------------------------------------------------------- programs
 
-class PrefillExecutor:
-    """Fills KV blocks for admitted prompts in padding-bucketed batches.
+class _Programs:
+    """The one builder of the engine's device programs. Every program is
+    ONE walk of the decoder over a tuple of windows — the decoding rows
+    (``[S, 1]``), a prefill window (``[P, w]``), or both — then the head,
+    once, over the rows a token is sampled from (each decoding row, and
+    each prompt's last row of the window) and one greedy pick.
 
-    Two compiled programs per window bucket ``w``, keyed by (window,
-    emits a token). Both run the paged window walk (the decoder's
-    ``walk``, which returns hidden rows and no logits) over the prompt
-    SUFFIX (everything past the prefix-reused blocks) at per-row start
-    positions — SCATTER-ONLY: each block's kv writes through the table
-    as it is computed and attention reads the pools in place. The one
-    that emits picks each prompt's last row of the window out of the
-    hidden rows, asks the decoder's ``logits`` for those ``[P, width]``
-    rows alone and returns each row's first generated token (TTFT is
-    measured here): no ``[P, w, V]`` value exists. The other runs no head at
-    all and returns the pools and the walk's counts; :meth:`prefill`
-    calls it for a chunk in which no prompt of the batch ends, which it
-    knows on the host before the call
-    (``gen_prefill_calls_total{head="row"|"none"}`` counts both kinds).
-    With a draft model the same window also fills the DRAFT pools, so
-    prefix-reused blocks hold both models' kv consistently."""
-
-    def __init__(self, module, variables, kv: PagedKVManager,
-                 pools: _PoolState, *, draft_module=None,
-                 draft_variables=None, max_blocks: int, batch: int = 4,
-                 pad_id: int = 0, service: str = "llm", registry=None):
-        self.module = module
-        self.variables = variables
-        self.draft_module = draft_module
-        self.draft_variables = draft_variables
-        self.kv = kv
-        self.pools = pools
-        self.max_blocks = int(max_blocks)
-        self.batch = max(int(batch), 1)
-        self.pad_id = int(pad_id)
-        self.service = service
-        # the widest window every model's walk takes: a longer suffix
-        # is fed in chunks of this width
-        self.max_window = min(
-            m.max_window() for m in (module, draft_module)
-            if m is not None)
-        reg = registry if registry is not None else _default_registry
-        self._h_attn = reg.histogram(
-            "gen_decode_attn_seconds",
-            "attention-program wall time, by service and phase",
-            buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1,
-                     .25, .5, 1., 2.5))
-        self._c_calls = reg.counter(
-            "gen_prefill_calls_total",
-            "prefill program calls, by service and what the program "
-            "emits: head=row one token a prompt, head=none no head")
-        self._walk_stats = _WalkStats(module, reg, service)
-        self._programs: dict[tuple[int, bool], object] = {}
-        self._fps: dict[str, tuple[str, str]] = {}
-        # a decoder with per-sequence cache arrays: its walk takes the
-        # rows' state rows, and snapshots are copied row to row
-        self.stateful = bool(kv.state_slots)
-        self._copy = None
-
-    def _copy_program(self):
-        """Rows of the per-sequence arrays copied onto other rows, ``batch``
-        pairs a call (a snapshot restored, a snapshot taken), the pools
-        donated like every program's."""
-        if self._copy is None:
-            spec = self.module.cache_spec()
-            donate = _donate_pools_kwargs()
-            self._copy = compile_tracker.jit(
-                lambda pools, src, dst: copy_state_rows(spec, pools, src,
-                                                        dst),
-                name=f"llm_state_copy_{self.service}_b{self.batch}",
-                **({"donate_argnums": (0,)} if donate else {}))
-        return self._copy
-
-    def _copy_rows(self, pairs: list) -> None:
-        import jax.numpy as jnp
-        src = np.full(self.batch, TRASH_ROW, np.int32)
-        dst = np.full(self.batch, TRASH_ROW, np.int32)
-        for i, (a, b) in enumerate(pairs):
-            src[i], dst[i] = a, b
-        self.pools.target = self._copy_program()(
-            self.pools.target, jnp.asarray(src), jnp.asarray(dst))
-
-    # -- compiled programs per window bucket -------------------------------
-    def _program(self, w: int, head: bool):
-        """The program of window ``w`` that emits each prompt's first
-        token (``head``), or the one that runs no head."""
-        prog = self._programs.get((w, head))
-        if prog is not None:
-            return prog
-        import jax.numpy as jnp
-        module, draft = self.module, self.draft_module
-        pad_id, P = self.pad_id, self.batch
-
-        def run(params, dparams, pools_t, pools_d, rows, toks, pos, lens,
-                *srows):
-            valid = (jnp.arange(w)[None] < lens[:, None]) & \
-                (lens[:, None] > 0)
-            hidden, pools_t, counts = module.apply(
-                {"params": params}, toks, pools_t, rows, pos, valid,
-                *srows, method="walk")                  # [P, w, W]
-            if draft is not None:
-                _, pools_d, _ = draft.apply(
-                    {"params": dparams}, toks, pools_d, rows, pos, valid,
-                    method="walk")
-            if not head:
-                return pools_t, pools_d, None, counts
-            # the head AFTER the pick: one row a prompt
-            last = jnp.clip(lens - 1, 0, w - 1)
-            row = jnp.take_along_axis(
-                hidden, last[:, None, None], axis=1)[:, 0]      # [P, W]
-            logits = module.apply({"params": params}, row,
-                                  method="logits")      # [P, V]
-            return pools_t, pools_d, _greedy(logits, pad_id), counts
-
-        name = f"llm_prefill_{self.service}_w{w}_b{P}" \
-            + ("" if head else "_nohead")
-        prog = compile_tracker.jit(run, name=name,
-                                   **_donate_pools_kwargs())
-        self._programs[(w, head)] = prog
-        key = {"phase": "prefill", "service": self.service,
-               "window": w, "batch": P, "head": head,
-               "attn": "paged",
-               "max_blocks": self.max_blocks,
-               "block_len": self.kv.block_len,
-               "encoder": self.module.program_key(),
-               "draft": None if draft is None else draft.program_key(),
-               "versions": aot.runtime_versions()}
-        self._fps[name] = aot.fingerprints(key, [], [])
-        return prog
-
-    def aot_fingerprints(self) -> dict:
-        """program name -> (static_fp, full_fp) for every program built
-        so far — the identity a warmed worker advertises."""
-        return dict(self._fps)
-
-    # -- host driver --------------------------------------------------------
-    def windows_for(self, n: int) -> list:
-        """The window of each program call a suffix of ``n`` tokens is
-        fed through: whole ``max_window`` chunks, then the remainder on
-        its bucket. One entry for every suffix the kernel holds whole."""
-        full, rem = divmod(max(int(n), 1), self.max_window)
-        return [self.max_window] * full + (
-            [_bucket_window(rem)] if rem else [])
-
-    def prefill(self, jobs: list) -> dict:
-        """``jobs``: list of ``(seq_id, prompt_tokens)`` whose chains
-        are already allocated in ``kv``. Runs bucketed batches — a
-        suffix wider than ``max_window`` in consecutive chunks, each
-        attending what the chunks before it wrote, and a chunk in which
-        no prompt of the batch ends through the program with no head —
-        commits lengths (``kv.advance`` + ``kv.publish``), returns
-        ``seq_id -> (first_token, suffix_len)``.
-
-        With per-sequence cache arrays a row that reuses a prefix first
-        has the prefix's snapshot copied into its state row (an
-        ``llm.state_restore`` span; on the device), and a prompt that
-        brings new whole chunks is fed in two stretches, cut at its last
-        whole chunk (``snapshot_at``), where its state is copied into a
-        snapshot row before the rest is fed."""
-        import jax
-        import jax.numpy as jnp
-        out: dict = {}
-        P = self.batch
-        for start in range(0, len(jobs), P):
-            metas, restore = [], []
-            for seq_id, prompt in jobs[start:start + P]:
-                h = self.kv.handle(seq_id)
-                # a fully reused prompt still re-feeds its last token:
-                # the window must emit logits for the first generated
-                # position (the rewrite stores bit-identical kv)
-                s0 = min(h.reused_tokens, h.prompt_len - 1)
-                metas.append((seq_id, np.asarray(prompt), s0,
-                              h.prompt_len - s0))
-                if h.restore_row is not None:
-                    restore.append((h.restore_row, h.state_row))
-            if restore:
-                with _tracer.span("llm.state_restore", rows=len(restore)):
-                    self._copy_rows(restore)
-                for seq_id, *_ in metas:
-                    self.kv.restored(seq_id)
-            ids: list = [m[0] for m in metas]
-            padded = ids + [None] * (P - len(ids))
-            rows = jnp.asarray(self.kv.block_rows(padded, self.max_blocks))
-            state_rows = self.kv.state_rows(padded) if self.stateful \
-                else None
-            firsts: dict = {}
-            counts: list = []           # each call's walk counts
-
-            def feed(spans):
-                """One stretch of every row: ``spans[i]`` is row ``i``'s
-                ``(first position, tokens)``."""
-                done = 0                # tokens of the stretch fed so far
-                for w in self.windows_for(max(n for _, n in spans)):
-                    # a row whose stretch ended in an earlier chunk rides
-                    # along like a padding row: position 0, length 0
-                    toks = np.zeros((P, w), np.int32)
-                    pos = np.zeros(P, np.int32)
-                    lens = np.zeros(P, np.int32)
-                    srows = np.full(P, TRASH_ROW, np.int32)
-                    ends = []
-                    for i, (p0, n) in enumerate(spans):
-                        k = min(n - done, w)
-                        if k <= 0:
-                            continue
-                        prompt = metas[i][1]
-                        toks[i, :k] = prompt[p0 + done:p0 + done + k]
-                        pos[i] = p0 + done
-                        lens[i] = k
-                        if self.stateful:
-                            srows[i] = state_rows[i]
-                        # known on the host before the call: whose last
-                        # token is in this chunk
-                        if p0 + done + k == len(prompt):
-                            ends.append(i)
-                    prog = self._program(w, bool(ends))
-                    self._c_calls.inc(1, service=self.service,
-                                      head="row" if ends else "none")
-                    t0 = time.perf_counter()
-                    pools_t, pools_d, first, count = prog(
-                        self.variables["params"],
-                        None if self.draft_module is None
-                        else self.draft_variables["params"],
-                        self.pools.target, self.pools.draft,
-                        rows, jnp.asarray(toks),
-                        jnp.asarray(pos), jnp.asarray(lens),
-                        *((jnp.asarray(srows),) if self.stateful else ()))
-                    if self._walk_stats:
-                        counts.append(count)
-                    self._h_attn.observe(time.perf_counter() - t0,
-                                         service=self.service,
-                                         phase="prefill")
-                    self.pools.target = pools_t
-                    if self.draft_module is not None:
-                        self.pools.draft = pools_d
-                    for i in ends:
-                        firsts[i] = first
-                    done += w
-
-            # where a row's feed is cut: the boundary its snapshot is
-            # taken at, when the prompt goes on past it
-            cuts = []
-            for seq_id, prompt, s0, _ in metas:
-                at = self.kv.handle(seq_id).snapshot_at
-                cuts.append(at if at is not None and at < len(prompt)
-                            else len(prompt))
-            feed([(s0, cut - s0) for (_, _, s0, _), cut in zip(metas, cuts)])
-            if self.stateful:
-                taken = []
-                for seq_id, *_ in metas:
-                    h = self.kv.handle(seq_id)
-                    if h.snapshot_at is not None:
-                        row = self.kv.take_snapshot(seq_id)
-                        if row is not None:
-                            taken.append((h.state_row, row))
-                if taken:
-                    self._copy_rows(taken)
-            if any(cut < len(m[1]) for m, cut in zip(metas, cuts)):
-                feed([(cut, len(m[1]) - cut) for m, cut in zip(metas, cuts)])
-            # ONE fetch: the first tokens and the calls' counts together
-            firsts, counts = jax.device_get((firsts, counts))
-            for count in counts:
-                self._walk_stats.record(count)
-            for i, (seq_id, _, _, n) in enumerate(metas):
-                h = self.kv.handle(seq_id)
-                self.kv.advance(seq_id, h.prompt_len - h.length)
-                self.kv.publish(seq_id)
-                out[seq_id] = (int(firsts[i][i]), int(n))
-        return out
-
-    def warm(self, windows=(1,)) -> None:
-        """Compile (and run, against the trash block only) the programs
-        a suffix of each given length is fed through — the warmup sweep
-        before ``compile_tracker.mark_steady()``. Every window gets the
-        program that emits (a prompt of the batch can end in any chunk);
-        a chunk before the last is ``max_window`` wide and may hold no
-        prompt's end, so a suffix of several chunks adds that window's
-        program with no head."""
-        import jax.numpy as jnp
-        P = self.batch
-        kinds = set()
-        for n in windows:
-            chunks = self.windows_for(n)
-            kinds.update((w, True) for w in chunks)
-            kinds.update((w, False) for w in chunks[:-1])
-        if self.stateful:               # trash row onto trash row
-            self._copy_rows([])
-        for w, head in sorted(kinds):
-            rows = jnp.zeros((P, self.max_blocks), jnp.int32)
-            prog = self._program(w, head)
-            args = (
-                self.variables["params"],
-                None if self.draft_module is None
-                else self.draft_variables["params"],
-                self.pools.target, self.pools.draft, rows,
-                jnp.zeros((P, w), jnp.int32), jnp.zeros(P, jnp.int32),
-                jnp.zeros(P, jnp.int32),
-                *((jnp.zeros(P, jnp.int32),) if self.stateful else ()))
-            # attribution must lower BEFORE the call: donation
-            # invalidates the pool buffers the args reference
-            _attribute_warm(prog, self.service, *args)
-            pools_t, pools_d, *_ = prog(*args)
-            self.pools.target = pools_t
-            if self.draft_module is not None:
-                self.pools.draft = pools_d
-
-
-class DecodeExecutor:
-    """The fixed-shape continuous-batching decode step over block
-    tables. All shapes are pinned at construction — ``[slots]`` state
-    vectors, ``[slots, max_blocks]`` block tables — so ONE program per
-    mode serves every step (the zero-runtime-compile contract).
-
-    Plain mode: ONE paged window walk of width 1 — embed the slots'
-    last tokens, scatter kv through the table, paged attention over
-    each chain in place — then the decoder's ``logits`` over its one
-    row a slot and a greedy ``argmax`` with pad masked — the
-    numerics of ``dl.generate``'s cached path with zero dense
-    gathers. Spec mode (draft present): ``dl.speculative``'s
-    draft/verify runs as k width-1 draft walks plus one width-(k+1)
-    target walk (the kernel's windowed variant) whose ``k + 1`` rows
-    all get logits; each slot accepts its
-    own longest agreeing prefix — no batch sync-on-min, block chains
-    advance independently."""
+    A program is keyed by ``(decode, window, head)``: ``(True, None,
+    True)`` the decode step; ``(False, w, head)`` a prefill window alone
+    (``head`` False: the program of a chunk in which no prompt ends, which
+    runs no head at all); ``(True, w, True)`` the decode step with a
+    prefill window RIDING in it, so that the weights are read once a
+    boundary (the head comes free: decode reads it anyway). Every program
+    takes ``(params, draft params, pools, draft pools, dec, win)``:
+    ``dec`` the decode rows' ``(rows, last, ptr, end, active[, state
+    rows])`` or ``()``, ``win`` the window's ``(rows, toks, pos, lens[,
+    state rows])`` or ``()``; it returns the pools and a dict of what it
+    made (``committed``/``n_new``/``n_acc`` of the decode rows, ``first``
+    of the window's prompts, the walk's ``counts``). With a draft model a
+    prefill window also fills the DRAFT pools, and the decode step
+    (``spec_k`` > 0) is ``dl.speculative``'s draft/verify per slot, which
+    no window rides in."""
 
     def __init__(self, module, variables, kv: PagedKVManager,
                  pools: _PoolState, *, draft_module=None,
-                 draft_variables=None, slots: int, max_blocks: int,
-                 spec_k: int = 0, pad_id: int = 0,
+                 draft_variables=None, slots: int, batch: int,
+                 max_blocks: int, spec_k: int = 0, pad_id: int = 0,
                  service: str = "llm", registry=None):
         if spec_k and draft_module is None:
             raise ValueError("spec_k > 0 needs a draft model")
+        if spec_k and kv.state_slots:
+            raise ValueError(
+                "speculative decoding rewinds a slot by the tokens it "
+                "rejects, and a per-sequence state cannot be rewound")
         self.module = module
         self.variables = variables
         self.draft_module = draft_module
@@ -606,6 +329,7 @@ class DecodeExecutor:
         self.kv = kv
         self.pools = pools
         self.slots = int(slots)
+        self.batch = max(int(batch), 1)
         self.max_blocks = int(max_blocks)
         self.spec_k = int(spec_k)
         self.pad_id = int(pad_id)
@@ -616,15 +340,597 @@ class DecodeExecutor:
             "attention-program wall time, by service and phase",
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1,
                      .25, .5, 1., 2.5))
-        self._walk_stats = _WalkStats(module, reg, service)
+        self.walk_stats = _WalkStats(module, reg, service)
+        self.built: dict[tuple, object] = {}
+        self._fps: dict[str, tuple[str, str]] = {}
+        self._copy = None
+
+    def name(self, decode: bool, w: int | None, head: bool = True) -> str:
+        S, P, svc = self.slots, self.batch, self.service
+        if w is None:
+            return f"llm_decode_paged_{svc}_S{S}_k{self.spec_k}"
+        if decode:
+            return f"llm_step_{svc}_S{S}_w{w}_b{P}"
+        return f"llm_prefill_{svc}_w{w}_b{P}" + ("" if head else "_nohead")
+
+    def get(self, decode: bool, w: int | None, head: bool = True):
+        key = (bool(decode), w, bool(head))
+        prog = self.built.get(key)
+        if prog is not None:
+            return prog
+        run = self._speculative() if decode and self.spec_k \
+            else self._walk_program(*key)
+        name = self.name(*key)
+        prog = compile_tracker.jit(run, name=name, **_donate_pools_kwargs())
+        self.built[key] = prog
+        draft = self.draft_module
+        fp = {"service": self.service, "attn": "paged",
+              "max_blocks": self.max_blocks,
+              "block_len": self.kv.block_len,
+              "encoder": self.module.program_key(),
+              "draft": None if draft is None else draft.program_key(),
+              "versions": aot.runtime_versions()}
+        if decode:
+            fp.update(slots=self.slots, spec_k=self.spec_k)
+        if w is not None:
+            fp.update(window=w, batch=self.batch, head=bool(head))
+        fp["phase"] = "decode" if w is None else \
+            "step" if decode else "prefill"
+        self._fps[name] = aot.fingerprints(fp, [], [])
+        return prog
+
+    def _walk_program(self, decode: bool, w: int | None, head: bool):
+        import jax.numpy as jnp
+        module, draft = self.module, self.draft_module
+        pad_id, S = self.pad_id, self.slots
+
+        def run(params, dparams, pools_t, pools_d, dec, win):
+            windows = []
+            if decode:
+                rows, last, ptr, _, active, *srows = dec
+                windows.append((last[:, None], rows, ptr - 1,
+                                active[:, None], *srows))
+            if w is not None:
+                rows, toks, pos, lens, *srows = win
+                windows.append((toks, rows, pos,
+                                jnp.arange(w)[None] < lens[:, None], *srows))
+            hidden, pools_t, counts = module.apply(
+                {"params": params}, tuple(windows), pools_t, method="walk")
+            if draft is not None and w is not None:
+                _, pools_d, _ = draft.apply(
+                    {"params": dparams}, (windows[-1],), pools_d,
+                    method="walk")
+            out = {"counts": counts}
+            picked = [hidden[0][:, 0]] if decode else []    # [S, W]
+            if w is not None and head:
+                # the head AFTER the pick: one row a prompt
+                at = jnp.clip(lens - 1, 0, w - 1)
+                picked.append(jnp.take_along_axis(
+                    hidden[-1], at[:, None, None], axis=1)[:, 0])  # [P, W]
+            if not picked:
+                return pools_t, pools_d, out
+            # the head ONCE, over every row a token is sampled from
+            tok = _greedy(module.apply(
+                {"params": params}, jnp.concatenate(picked),
+                method="logits"), pad_id)
+            if decode:
+                n_new = jnp.where(active, 1, 0)
+                out.update(committed=tok[:S, None], n_new=n_new,
+                           n_acc=n_new)
+            if w is not None:
+                out["first"] = tok[S:] if decode else tok
+            return pools_t, pools_d, out
+
+        return run
+
+    def _speculative(self):
+        import jax.numpy as jnp
+        module, draft = self.module, self.draft_module
+        pad_id, k, S = self.pad_id, self.spec_k, self.slots
+
+        def run(params, dparams, pools_t, pools_d, dec, win):
+            rows, last, ptr, end, active = dec
+            pos = ptr - 1
+            av = active[:, None]
+            tok = last[:, None]                     # [S, 1]
+            drafts = []
+            for j in range(k):
+                (hd,), pools_d, _ = draft.apply(
+                    {"params": dparams}, ((tok, rows, pos + j, av),),
+                    pools_d, method="walk")
+                ld = draft.apply({"params": dparams}, hd, method="logits")
+                tok = _greedy(ld[:, 0], pad_id)[:, None]
+                drafts.append(tok[:, 0])
+            # extra cache-fill step: d_k's kv, or the next round's
+            # draft attends a zero hole after a full accept (same
+            # fix as dl.speculative)
+            _, pools_d, _ = draft.apply(
+                {"params": dparams}, ((tok, rows, pos + k, av),), pools_d,
+                method="walk")
+            d = jnp.stack(drafts, 1)                # [S, k]
+            window = jnp.concatenate([last[:, None], d], 1)
+            (ht,), pools_t, counts = module.apply(
+                {"params": params},
+                ((window, rows, pos, av & jnp.ones((S, k + 1), bool)),),
+                pools_t, method="walk")             # [S, k+1, W]
+            # the verify samples from every row of its window
+            lt = module.apply({"params": params}, ht,
+                              method="logits")      # [S, k+1, V]
+            t = _greedy(lt, pad_id)
+            agree = jnp.cumprod(
+                (d == t[:, :k]).astype(jnp.int32), axis=1)
+            n_acc = agree.sum(axis=1)               # PER-SLOT
+            bonus = jnp.take_along_axis(
+                t, n_acc[:, None], axis=1)[:, 0]
+            ar = jnp.arange(k + 1)[None]            # [1, k+1]
+            d_ext = jnp.concatenate(
+                [d, jnp.zeros((S, 1), jnp.int32)], 1)
+            committed = jnp.where(
+                ar < n_acc[:, None], d_ext,
+                jnp.where(ar == n_acc[:, None], bonus[:, None],
+                          pad_id))                  # [S, k+1]
+            # never commit past the slot's budget (end - ptr
+            # tokens remain; runnable slots have at least 1)
+            n_new = jnp.clip(n_acc + 1, 1,
+                             jnp.maximum(end - ptr, 1))
+            n_new = jnp.where(active, n_new, 0)
+            return pools_t, pools_d, {
+                "committed": committed, "n_new": n_new,
+                "n_acc": jnp.where(active, n_acc, 0), "counts": counts}
+
+        return run
+
+    def aot_fingerprints(self) -> dict:
+        """program name -> (static_fp, full_fp) for every program built
+        so far — the identity a warmed worker advertises."""
+        return dict(self._fps)
+
+    def _args(self, dec, win) -> tuple:
+        return (self.variables["params"],
+                None if self.draft_module is None
+                else self.draft_variables["params"],
+                self.pools.target, self.pools.draft, dec, win)
+
+    def call(self, dec: tuple = (), win: tuple = (), head: bool = True
+             ) -> dict:
+        """Dispatch the program of these windows over the shared pools;
+        returns what it made, still on the device."""
+        w = win[1].shape[1] if win else None
+        prog = self.get(bool(dec), w, head)
+        t0 = time.perf_counter()
+        pools_t, pools_d, out = prog(*self._args(dec, win))
+        self._h_attn.observe(time.perf_counter() - t0, service=self.service,
+                             phase="decode" if dec else "prefill")
+        self.pools.target = pools_t
+        if self.draft_module is not None:
+            self.pools.draft = pools_d
+        return out
+
+    def blank(self, decode: bool, w: int | None) -> tuple:
+        """``(dec, win)`` of a call that touches the trash block alone:
+        every decode row inactive, every window row of length 0."""
+        import jax.numpy as jnp
+        S, P, MB = self.slots, self.batch, self.max_blocks
+        state = bool(self.kv.state_slots)
+        dec = (jnp.zeros((S, MB), jnp.int32), jnp.zeros(S, jnp.int32),
+               jnp.ones(S, jnp.int32), jnp.full(S, 2, jnp.int32),
+               jnp.zeros(S, bool),
+               *((jnp.zeros(S, jnp.int32),) if state else ())) \
+            if decode else ()
+        win = (jnp.zeros((P, MB), jnp.int32), jnp.zeros((P, w), jnp.int32),
+               jnp.zeros(P, jnp.int32), jnp.zeros(P, jnp.int32),
+               *((jnp.zeros(P, jnp.int32),) if state else ())) \
+            if w is not None else ()
+        return dec, win
+
+    def warm(self, decode: bool, w: int | None, head: bool = True) -> None:
+        """Compile the program and run it once against the trash block —
+        the warmup before ``mark_steady``."""
+        dec, win = self.blank(decode, w)
+        # a step with a window riding in it is the decode step's work and
+        # that window's prefill program's, which are both attributed: the
+        # service's cost is the sum over its programs
+        if w is None or not decode:
+            # attribution must lower BEFORE the call: donation
+            # invalidates the pool buffers the args reference
+            _attribute_warm(self.get(decode, w, head), self.service,
+                            *self._args(dec, win))
+        self.call(dec, win, head)
+
+    # -- the per-sequence arrays' rows ----------------------------------------
+    def copy_rows(self, pairs: list) -> None:
+        """Rows of the per-sequence arrays copied onto other rows, at most
+        ``batch`` ``(from, to)`` pairs a call (a snapshot restored, a
+        snapshot taken), the pools donated like every program's."""
+        import jax.numpy as jnp
+        if self._copy is None:
+            spec = self.module.cache_spec()
+            self._copy = compile_tracker.jit(
+                lambda pools, src, dst: copy_state_rows(spec, pools, src,
+                                                        dst),
+                name=f"llm_state_copy_{self.service}_b{self.batch}",
+                **({"donate_argnums": (0,)} if _donate_pools_kwargs()
+                   else {}))
+        src = np.full(self.batch, TRASH_ROW, np.int32)
+        dst = np.full(self.batch, TRASH_ROW, np.int32)
+        for i, (a, b) in enumerate(pairs):
+            src[i], dst[i] = a, b
+        self.pools.target = self._copy(
+            self.pools.target, jnp.asarray(src), jnp.asarray(dst))
+
+
+# --------------------------------------------------------------- executors
+
+@dataclass(eq=False)
+class _Feed:
+    """A prompt on its way into the cache: ``at`` is the next position to
+    feed, kept on the host between the windows (and, for a prompt that
+    rides in with the decoding rows, between the boundaries). With
+    per-sequence cache arrays the feed is cut at ``cut``, the boundary the
+    prompt's snapshot is taken at (``snap``: still to take)."""
+    seq_id: object
+    prompt: np.ndarray
+    at: int
+    fed_from: int
+    cut: int
+    snap: bool
+    restore: tuple | None = None
+
+    @property
+    def stop(self) -> int:
+        """Where the stretch being fed ends."""
+        return self.cut if self.at < self.cut else len(self.prompt)
+
+
+class PrefillExecutor:
+    """Fills KV blocks for admitted prompts, a window of at most ``batch``
+    prompts and ``max_window`` rows a program call, over the prompt SUFFIX
+    (everything past the prefix-reused blocks) at per-row start positions
+    — SCATTER-ONLY: each block's kv writes through the table as it is
+    computed and attention reads the pools in place. The program picks
+    each prompt's last row of the window out of the hidden rows, asks the
+    decoder's ``logits`` for those rows alone and returns each row's first
+    generated token (TTFT is measured here): no ``[P, w, V]`` value
+    exists.
+
+    :meth:`prefill` takes a window one of two ways, by what it observes.
+    While slots DECODE (``rider``, the engine's :class:`DecodeExecutor`,
+    has ``ride_from`` runnable slots or more) one window a boundary RIDES
+    with the decoding rows in ONE program (``_Programs``' ``(True, w,
+    True)``): the weights are read once, and the prompts' progress is
+    kept here between boundaries; the decode executor's one fetch brings
+    the first tokens back (:meth:`landed`). With too few rows to ride
+    with (set-up, a cold start), beside a speculative decode step, or
+    with a decoder that takes one window a walk (``rider`` None), the
+    prompts run to their ends ALONE, window after
+    window, as batches of ``batch`` in step with each other: a chunk in
+    which no prompt of the batch ends goes through the program with no
+    head, which is known on the host before the call
+    (``gen_prefill_calls_total{head="row"|"none"}`` counts the window
+    calls of both ways; ``gen_prefill_rows_total{ride="decode"|"alone"}``
+    the prompt rows by how they ran). With a draft model the same window
+    also fills the DRAFT pools, so prefix-reused blocks hold both models'
+    kv consistently."""
+
+    def __init__(self, programs: _Programs, *, registry=None):
+        self.programs = programs
+        self.kv = programs.kv
+        self.pools = programs.pools
+        self.max_blocks = programs.max_blocks
+        self.batch = programs.batch
+        self.service = programs.service
+        # the widest window every model's walk takes: a longer suffix
+        # is fed in chunks of this width
+        self.max_window = min(
+            m.max_window() for m in (programs.module,
+                                     programs.draft_module)
+            if m is not None)
+        reg = registry if registry is not None else _default_registry
+        self._c_calls = reg.counter(
+            "gen_prefill_calls_total",
+            "program calls that held a prefill window, by service and "
+            "whether a prompt's first token was taken from the call: "
+            "head=row | head=none")
+        self._c_rows = reg.counter(
+            "gen_prefill_rows_total",
+            "prompt rows fed through prefill windows, by service and how "
+            "the window ran: ride=decode with the decoding rows in one "
+            "program, ride=alone in a program of its own")
+        # a decoder with per-sequence cache arrays: its walk takes the
+        # rows' state rows, and snapshots are copied row to row
+        self.stateful = bool(self.kv.state_slots)
+        #: the decode executor whose rows a window rides with; None where
+        #: every window runs alone
+        self.rider: DecodeExecutor | None = None
+        #: a window rides where at least this many rows decode: the
+        #: riding prompt holds its slot idle a boundary a window and one
+        #: more, which the weights read once pay for from 6 to 8 decoding
+        #: rows up at the benchmark's sizes (PERF.md section 6, PR 34)
+        self.ride_from = 8
+        self._queue: deque = deque()    # allocated, not wholly fed: FIFO
+        self._landed: dict = {}
+        self.rode = 0                   # rows of the last riding window
+
+    @property
+    def waiting(self) -> int:
+        """Prompts whose chains are allocated and not wholly fed."""
+        return len(self._queue)
+
+    # -- host driver --------------------------------------------------------
+    def _chunks(self, n: int) -> list:
+        """The rows of each program call a suffix of ``n`` tokens is fed
+        through: whole ``max_window`` chunks, then the remainder."""
+        full, rem = divmod(max(int(n), 1), self.max_window)
+        return [self.max_window] * full + [rem] * bool(rem)
+
+    def windows_for(self, n: int) -> list:
+        """The window of each program call a suffix of ``n`` tokens is
+        fed through alone: each chunk on its bucket. One entry for every
+        suffix the kernel holds whole."""
+        return [_bucket_window(k) if k < self.max_window else k
+                for k in self._chunks(n)]
+
+    def riding_window(self, n: int) -> int:
+        """The window a chunk of ``n`` rows rides in: the next multiple of
+        32. A window's padding rows go through the attention kernel like
+        real ones (a latent-attention window of 128 padded rows costs the
+        step 30 ms where 64 cost 9), so the ladder is finer than
+        :func:`_bucket_window`'s and one fixed width is not it; a program
+        of a width costs ``engine.warm`` a fraction of a second once the
+        compile cache holds it (PERF.md section 6, PR 34)."""
+        return min(-(-max(int(n), 1) // 32) * 32, self.max_window)
+
+    def _feed(self, seq_id, prompt) -> _Feed:
+        h = self.kv.handle(seq_id)
+        # a fully reused prompt still re-feeds its last token: the window
+        # must emit logits for the first generated position (the rewrite
+        # stores bit-identical kv)
+        s0 = min(h.reused_tokens, h.prompt_len - 1)
+        return _Feed(
+            seq_id, np.asarray(prompt), s0, s0,
+            cut=h.prompt_len if h.snapshot_at is None else h.snapshot_at,
+            snap=h.snapshot_at is not None,
+            restore=None if h.restore_row is None
+            else (h.restore_row, h.state_row))
+
+    def _restore(self, feeds: list) -> None:
+        """Copy the snapshot of its reused prefix into the state row of
+        every feed that waits for one (on the device, before the window
+        that feeds it)."""
+        pairs = [f.restore for f in feeds if f.restore is not None]
+        if not pairs:
+            return
+        with _tracer.span("llm.state_restore", rows=len(pairs)):
+            self.programs.copy_rows(pairs)
+        for f in feeds:
+            if f.restore is not None:
+                self.kv.restored(f.seq_id)
+                f.restore = None
+
+    def _snapshot(self, feeds: list) -> None:
+        """Copy the state of every feed that has just reached its cut into
+        a snapshot row (``publish`` indexes it with the blocks)."""
+        taken = []
+        for f in feeds:
+            if f.snap and f.at == f.cut:
+                f.snap = False
+                row = self.kv.take_snapshot(f.seq_id)
+                if row is not None:
+                    taken.append((self.kv.handle(f.seq_id).state_row, row))
+        if taken:
+            self.programs.copy_rows(taken)
+
+    def _window(self, feeds: list, width) -> tuple:
+        """The next window of ``feeds`` (``batch`` entries, None a row
+        that sits this window out): each feed's next chunk of its stretch,
+        on the bucket ``width`` gives the longest. Moves the feeds on.
+        Returns ``(win, ends, rows)``: the program's window argument,
+        the rows whose prompt ends in it, the real rows it holds."""
+        import jax.numpy as jnp
+        P = self.batch
+        spans = [0 if f is None else min(f.stop - f.at, self.max_window)
+                 for f in feeds]
+        w = width(max(spans))
+        ids = [None if f is None else f.seq_id for f in feeds]
+        # a row that sits out rides along like a padding row: position 0,
+        # length 0, the trash row
+        toks = np.zeros((P, w), np.int32)
+        pos = np.zeros(P, np.int32)
+        lens = np.zeros(P, np.int32)
+        srows = np.full(P, TRASH_ROW, np.int32)
+        state_rows = self.kv.state_rows(ids) if self.stateful else None
+        ends = []
+        for i, (f, k) in enumerate(zip(feeds, spans)):
+            if k <= 0:
+                continue
+            toks[i, :k] = f.prompt[f.at:f.at + k]
+            pos[i], lens[i] = f.at, k
+            if self.stateful:
+                srows[i] = state_rows[i]
+            f.at += k
+            # known on the host before the call: whose last token is in
+            # this window
+            if f.at == len(f.prompt):
+                ends.append(i)
+        win = (jnp.asarray(self.kv.block_rows(ids, self.max_blocks)),
+               jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(lens),
+               *((jnp.asarray(srows),) if self.stateful else ()))
+        return win, ends, int(sum(spans))
+
+    def _count(self, ends: list, rows: int, ride: str) -> None:
+        self._c_calls.inc(1, service=self.service,
+                          head="row" if ends else "none")
+        self._c_rows.inc(rows, service=self.service, ride=ride)
+
+    def _commit(self, feed: _Feed, first: int) -> tuple:
+        """A prompt is wholly in: commit its length, index its new blocks
+        (and its snapshot) for reuse."""
+        h = self.kv.handle(feed.seq_id)
+        self.kv.advance(feed.seq_id, h.prompt_len - h.length)
+        self.kv.publish(feed.seq_id)
+        return int(first), len(feed.prompt) - feed.fed_from
+
+    def prefill(self, jobs: list) -> dict:
+        """``jobs``: list of ``(seq_id, prompt_tokens)`` whose chains
+        have just been allocated in ``kv``; they join the prompts that
+        wait. Returns ``seq_id -> (first_token, suffix_len)`` of the
+        prompts that ran to their end ALONE in this call. While slots
+        decode nothing runs alone: the next window of the oldest ``batch``
+        prompts is left with the decode executor, whose step takes it
+        through its one program, and the prompts that end in it come back
+        from :meth:`landed` after that step's fetch. The prompts behind
+        them wait their turn, a window a boundary.
+
+        With per-sequence cache arrays a feed that reuses a prefix first
+        has the prefix's snapshot copied into its state row (an
+        ``llm.state_restore`` span; on the device), and a prompt that
+        brings new whole chunks is fed in two stretches, cut at its last
+        whole chunk (``snapshot_at``), where its state is copied into a
+        snapshot row before the rest is fed."""
+        self._queue.extend(self._feed(*job) for job in jobs)
+        self.rode = 0
+        rider = self.rider
+        if rider is not None and rider.runnable.sum() >= self.ride_from:
+            # the prompts beyond the window wait their turn, a window a
+            # boundary: a waiting prompt idles ONE slot, a prefill program
+            # of its own would hold every decoding slot (chip sweep,
+            # PERF.md section 6, PR 34)
+            if self._queue:
+                self._ride(rider)
+            return {}
+        out: dict = {}
+        while self._queue:
+            batch = [self._queue.popleft()
+                     for _ in range(min(self.batch, len(self._queue)))]
+            out.update(self._alone(batch))
+        return out
+
+    def _ride(self, rider: "DecodeExecutor") -> None:
+        """The next window of the oldest ``batch`` prompts, handed to the
+        decode executor to go through its step's program."""
+        feeds = list(islice(self._queue, self.batch))
+        self._restore(feeds)
+        win, ends, rows = self._window(
+            feeds + [None] * (self.batch - len(feeds)), self.riding_window)
+        self._count(ends, rows, "decode")
+        self.rode = rows
+        ended = [(i, feeds[i]) for i in ends]
+        for _, feed in ended:
+            self._queue.remove(feed)
+
+        def land(first) -> None:
+            """After the step's fetch: the first tokens are here, and a
+            feed's cut falls between this boundary's program and the
+            next one's."""
+            self._snapshot(feeds)
+            for i, feed in ended:
+                self._landed[feed.seq_id] = self._commit(feed, first[i])
+
+        rider.riding = (win, land)
+
+    def landed(self) -> dict:
+        """``seq_id -> (first_token, suffix_len)`` of the prompts whose
+        last row rode in a decode step since the last call."""
+        out, self._landed = self._landed, {}
+        return out
+
+    def _alone(self, feeds: list) -> dict:
+        """A batch of prompts to their ends, in step with each other: a
+        suffix wider than ``max_window`` in consecutive chunks, each
+        attending what the chunks before it wrote; ONE fetch brings the
+        first tokens and the calls' counts."""
+        import jax
+        self._restore(feeds)
+        padded = feeds + [None] * (self.batch - len(feeds))
+        firsts: dict = {}
+        counts: list = []               # each call's walk counts
+
+        def feed_while(live) -> None:
+            while True:
+                part = [f if f is not None and live(f) else None
+                        for f in padded]
+                if not any(f is not None for f in part):
+                    return
+                win, ends, rows = self._window(part, _bucket_window)
+                self._count(ends, rows, "alone")
+                made = self.programs.call((), win, head=bool(ends))
+                if self.programs.walk_stats:
+                    counts.append(made["counts"])
+                for i in ends:
+                    firsts[i] = made["first"]
+
+        feed_while(lambda f: f.at < f.cut)
+        self._snapshot(feeds)
+        feed_while(lambda f: f.at < len(f.prompt))
+        firsts, counts = jax.device_get((firsts, counts))
+        for count in counts:
+            self.programs.walk_stats.record(count)
+        return {f.seq_id: self._commit(f, firsts[i][i])
+                for i, f in enumerate(feeds)}
+
+    def warm(self, windows=(1,)) -> None:
+        """Compile (and run, against the trash block only) the programs
+        a suffix of each given length is fed through — the warmup sweep
+        before ``compile_tracker.mark_steady()``. Alone, every window
+        gets the program that emits (a prompt of the batch can end in any
+        chunk); a chunk before the last is ``max_window`` wide and may
+        hold no prompt's end, so a suffix of several chunks adds that
+        window's program with no head. With a rider, each chunk's riding
+        window besides."""
+        kinds = set()
+        for n in windows:
+            chunks = self.windows_for(n)
+            kinds.update((False, w, True) for w in chunks)
+            kinds.update((False, w, False) for w in chunks[:-1])
+            if self.rider is not None:
+                kinds.update((True, self.riding_window(k), True)
+                             for k in self._chunks(n))
+        if self.stateful:               # trash row onto trash row
+            self.programs.copy_rows([])
+        for key in sorted(kinds):
+            self.programs.warm(*key)
+
+
+class DecodeExecutor:
+    """The fixed-shape continuous-batching decode step over block
+    tables. All shapes are pinned at construction — ``[slots]`` state
+    vectors, ``[slots, max_blocks]`` block tables — so ONE program per
+    mode serves every step (the zero-runtime-compile contract), and one
+    more a riding window's width.
+
+    Plain mode: ONE paged window walk of width 1 — embed the slots'
+    last tokens, scatter kv through the table, paged attention over
+    each chain in place — then the decoder's ``logits`` over its one
+    row a slot and a greedy ``argmax`` with pad masked — the
+    numerics of ``dl.generate``'s cached path with zero dense
+    gathers. A prefill window the :class:`PrefillExecutor` leaves
+    (``riding``) goes through the same walk and the same head call, and
+    :meth:`step`'s one fetch brings its prompts' first tokens back with
+    the slots'. Spec mode (draft present): ``dl.speculative``'s
+    draft/verify runs as k width-1 draft walks plus one width-(k+1)
+    target walk (the kernel's windowed variant) whose ``k + 1`` rows
+    all get logits; each slot accepts its
+    own longest agreeing prefix — no batch sync-on-min, block chains
+    advance independently."""
+
+    def __init__(self, programs: _Programs):
+        self.programs = programs
+        self.kv = programs.kv
+        self.pools = programs.pools
+        self.slots = programs.slots
+        self.max_blocks = programs.max_blocks
+        self.spec_k = programs.spec_k
+        self.pad_id = programs.pad_id
         # host-side slot state (the engine owns seq metadata)
         self.seq_ids: list = [None] * self.slots
         self.ptr = np.ones(self.slots, np.int32)   # committed tokens
         self.end = np.ones(self.slots, np.int32)   # commit cap
         self.last = np.zeros(self.slots, np.int32)  # token @ ptr-1
         self.active = np.zeros(self.slots, bool)
-        self._program = None
-        self._fps: dict[str, tuple[str, str]] = {}
+        #: ``(window, land)`` the prefill executor left for the next step:
+        #: the window goes through the step's program with the decoding
+        #: rows, ``land`` takes its prompts' first tokens from the fetch
+        self.riding: tuple | None = None
 
     @property
     def free_slots(self) -> int:
@@ -655,98 +961,6 @@ class DecodeExecutor:
         self.end[slot] = 1
         self.last[slot] = self.pad_id
 
-    # -- the compiled step --------------------------------------------------
-    def _build(self):
-        if self._program is not None:
-            return self._program
-        import jax.numpy as jnp
-        module, draft = self.module, self.draft_module
-        pad_id, k, S = self.pad_id, self.spec_k, self.slots
-
-        if k == 0:
-            def run(params, dparams, pools_t, pools_d, rows, last, ptr,
-                    end, active, *srows):
-                hidden, pools_t, counts = module.apply(
-                    {"params": params}, last[:, None], pools_t, rows,
-                    ptr - 1, active[:, None], *srows,
-                    method="walk")                      # [S, 1, W]
-                logits = module.apply({"params": params}, hidden,
-                                      method="logits")  # every row: w = 1
-                committed = _greedy(logits[:, 0], pad_id)[:, None]  # [S, 1]
-                n_new = jnp.where(active, 1, 0)
-                return pools_t, pools_d, committed, n_new, n_new, counts
-        else:
-            if self.kv.state_slots:
-                raise ValueError(
-                    "speculative decoding rewinds a slot by the tokens it "
-                    "rejects, and a per-sequence state cannot be rewound")
-
-            def run(params, dparams, pools_t, pools_d, rows, last, ptr,
-                    end, active):
-                pos = ptr - 1
-                av = active[:, None]
-                tok = last[:, None]                     # [S, 1]
-                drafts = []
-                for j in range(k):
-                    hd, pools_d, _ = draft.apply(
-                        {"params": dparams}, tok, pools_d, rows,
-                        pos + j, av, method="walk")
-                    ld = draft.apply({"params": dparams}, hd,
-                                     method="logits")
-                    tok = _greedy(ld[:, 0], pad_id)[:, None]
-                    drafts.append(tok[:, 0])
-                # extra cache-fill step: d_k's kv, or the next round's
-                # draft attends a zero hole after a full accept (same
-                # fix as dl.speculative)
-                _, pools_d, _ = draft.apply(
-                    {"params": dparams}, tok, pools_d, rows, pos + k,
-                    av, method="walk")
-                d = jnp.stack(drafts, 1)                # [S, k]
-                window = jnp.concatenate([last[:, None], d], 1)
-                ht, pools_t, counts = module.apply(
-                    {"params": params}, window, pools_t, rows, pos,
-                    av & jnp.ones((S, k + 1), bool),
-                    method="walk")                      # [S, k+1, W]
-                # the verify samples from every row of its window
-                lt = module.apply({"params": params}, ht,
-                                  method="logits")      # [S, k+1, V]
-                t = _greedy(lt, pad_id)
-                agree = jnp.cumprod(
-                    (d == t[:, :k]).astype(jnp.int32), axis=1)
-                n_acc = agree.sum(axis=1)               # PER-SLOT
-                bonus = jnp.take_along_axis(
-                    t, n_acc[:, None], axis=1)[:, 0]
-                ar = jnp.arange(k + 1)[None]            # [1, k+1]
-                d_ext = jnp.concatenate(
-                    [d, jnp.zeros((S, 1), jnp.int32)], 1)
-                committed = jnp.where(
-                    ar < n_acc[:, None], d_ext,
-                    jnp.where(ar == n_acc[:, None], bonus[:, None],
-                              pad_id))                  # [S, k+1]
-                # never commit past the slot's budget (end - ptr
-                # tokens remain; runnable slots have at least 1)
-                n_new = jnp.clip(n_acc + 1, 1,
-                                 jnp.maximum(end - ptr, 1))
-                n_new = jnp.where(active, n_new, 0)
-                return pools_t, pools_d, committed, n_new, \
-                    jnp.where(active, n_acc, 0), counts
-
-        name = f"llm_decode_paged_{self.service}_S{S}_k{k}"
-        self._program = compile_tracker.jit(run, name=name,
-                                            **_donate_pools_kwargs())
-        key = {"phase": "decode", "service": self.service, "slots": S,
-               "spec_k": k, "attn": "paged",
-               "max_blocks": self.max_blocks,
-               "block_len": self.kv.block_len,
-               "encoder": self.module.program_key(),
-               "draft": None if draft is None else draft.program_key(),
-               "versions": aot.runtime_versions()}
-        self._fps[name] = aot.fingerprints(key, [], [])
-        return self._program
-
-    def aot_fingerprints(self) -> dict:
-        return dict(self._fps)
-
     @property
     def runnable(self) -> np.ndarray:
         """Slots that should actually decode this step: active AND
@@ -754,14 +968,18 @@ class DecodeExecutor:
         prefill-produced first token lands)."""
         return self.active & (self.ptr < self.end)
 
+    # -- the step -----------------------------------------------------------
     def step(self) -> dict:
-        """One decode step over every runnable slot. Returns
-        ``slot -> (tokens_committed list, n_accepted)``; the caller
-        commits tokens, advances the block table, and retires finished
+        """One decode step over every runnable slot, ONE program: the
+        decode rows and, riding with them, the prefill window the
+        :class:`PrefillExecutor` left (``riding``). Returns ``slot ->
+        (tokens_committed list, n_accepted)``; the caller commits
+        tokens, advances the block table, and retires finished
         sequences."""
         import jax
         import jax.numpy as jnp
         runnable = self.runnable
+        (win, land), self.riding = self.riding or ((), None), None
         if not runnable.any():
             return {}
         # capacity for this step's writes: positions up to ptr-1+k
@@ -771,63 +989,33 @@ class DecodeExecutor:
                                         int(self.ptr[s]) + self.spec_k)
         running = [sid if runnable[i] else None
                    for i, sid in enumerate(self.seq_ids)]
-        rows = self.kv.block_rows(running, self.max_blocks)
-        prog = self._build()
-        t0 = time.perf_counter()
-        pools_t, pools_d, committed, n_new, n_acc, counts = prog(
-            self.variables["params"],
-            None if self.draft_module is None
-            else self.draft_variables["params"],
-            self.pools.target, self.pools.draft, jnp.asarray(rows),
-            jnp.asarray(self.last), jnp.asarray(self.ptr),
-            jnp.asarray(self.end), jnp.asarray(runnable),
-            *((jnp.asarray(self.kv.state_rows(running)),)
-              if self.kv.state_slots else ()))
-        self._h_attn.observe(time.perf_counter() - t0,
-                             service=self.service, phase="decode")
-        self.pools.target = pools_t
-        if self.draft_module is not None:
-            self.pools.draft = pools_d
-        # ONE fetch a step: the tokens and the walk's counts together
-        committed, n_new, n_acc, counts = jax.device_get(
-            (committed, n_new, n_acc, counts))
-        if self._walk_stats:
-            self._walk_stats.record(counts)
+        dec = (jnp.asarray(self.kv.block_rows(running, self.max_blocks)),
+               jnp.asarray(self.last), jnp.asarray(self.ptr),
+               jnp.asarray(self.end), jnp.asarray(runnable),
+               *((jnp.asarray(self.kv.state_rows(running)),)
+                 if self.kv.state_slots else ()))
+        # ONE fetch a step: the tokens, a riding window's first tokens
+        # and the walk's counts together
+        made = jax.device_get(self.programs.call(dec, win))
+        if self.programs.walk_stats:
+            self.programs.walk_stats.record(made["counts"])
+        if land is not None:
+            land(made["first"])
         out = {}
-        for s in range(self.slots):
-            if not runnable[s]:
-                continue
-            n = int(n_new[s])
-            toks = [int(t) for t in committed[s, :n]]
+        for s in np.flatnonzero(runnable):
+            n = int(made["n_new"][s])
+            toks = [int(t) for t in made["committed"][s, :n]]
             self.kv.advance(self.seq_ids[s], n)
             self.ptr[s] += n
             self.last[s] = toks[-1]
-            out[s] = (toks, int(n_acc[s]))
+            out[int(s)] = (toks, int(made["n_acc"][s]))
         return out
 
     def warm(self) -> None:
         """Run the step program once against the trash block (all slots
         inactive — every write lands in block 0) — the warmup before
         ``mark_steady``."""
-        import jax.numpy as jnp
-        prog = self._build()
-        S = self.slots
-        args = (
-            self.variables["params"],
-            None if self.draft_module is None
-            else self.draft_variables["params"],
-            self.pools.target, self.pools.draft,
-            jnp.zeros((S, self.max_blocks), jnp.int32),
-            jnp.zeros(S, jnp.int32), jnp.ones(S, jnp.int32),
-            jnp.full(S, 2, jnp.int32), jnp.zeros(S, bool),
-            *((jnp.zeros(S, jnp.int32),) if self.kv.state_slots else ()))
-        # attribution must lower BEFORE the call: donation invalidates
-        # the pool buffers the args reference
-        _attribute_warm(prog, self.service, *args)
-        pools_t, pools_d, *_ = prog(*args)
-        self.pools.target = pools_t
-        if self.draft_module is not None:
-            self.pools.draft = pools_d
+        self.programs.warm(True, None)
 
 
 # ------------------------------------------------------------------ engine
@@ -920,16 +1108,20 @@ class LLMEngine:
                 draft_module.cache_spec(), num_blocks, self.block_len))
         self.sched = SlotScheduler(slots, service=service,
                                    registry=reg, clock=clock)
-        self.prefiller = PrefillExecutor(
+        self.programs = _Programs(
             module, variables, self.kv, self.pools,
             draft_module=draft_module, draft_variables=draft_variables,
-            max_blocks=self.max_blocks, batch=prefill_batch,
-            pad_id=pad_id, service=service, registry=reg)
-        self.decoder = DecodeExecutor(
-            module, variables, self.kv, self.pools,
-            draft_module=draft_module, draft_variables=draft_variables,
-            slots=slots, max_blocks=self.max_blocks, spec_k=spec_k,
-            pad_id=pad_id, service=service, registry=reg)
+            slots=slots, batch=prefill_batch, max_blocks=self.max_blocks,
+            spec_k=spec_k, pad_id=pad_id, service=service, registry=reg)
+        self.prefiller = PrefillExecutor(self.programs, registry=reg)
+        self.decoder = DecodeExecutor(self.programs)
+        # a prompt's window rides with the decoding rows where the
+        # decoder's walk takes several windows and another slot can be
+        # decoding meanwhile; the speculative step's draft walks share
+        # nothing with a prefill window
+        if not spec_k and int(slots) > 1 and \
+                getattr(module, "several_windows", False):
+            self.prefiller.rider = self.decoder
         self.handoff = HandoffQueue()
         self._meta: dict = {}
         self._to_prefill: list = []
@@ -982,7 +1174,14 @@ class LLMEngine:
 
     # -- one step boundary --------------------------------------------------
     def step(self) -> list:
-        """Admit → prefill → handoff → decode. Returns ``(seq_id,
+        """One boundary: admit, allocate the admitted prompts' chains,
+        ONE program — the decoding rows and, riding with them, the next
+        prefill window of the prompts that wait — one fetch, and the
+        handoff of the prompts whose last row was in the window to their
+        slots (they decode from the next boundary). Where no slot
+        decodes, beside a speculative step, or with a decoder that takes
+        one window a walk, the order is prefill (every window of every
+        ready prompt, alone) → handoff → decode. Returns ``(seq_id,
         tokens)`` pairs (full sequence: prompt then generated) finished
         at this boundary."""
         with _tracer.span("llm.step") as root:
@@ -994,20 +1193,18 @@ class LLMEngine:
         for seq_id in self.sched.drain_expired():
             self._meta.pop(seq_id, None)
             self.expired.append(seq_id)
-        if self._to_prefill:
+        jobs = self._allocate()
+        if jobs or self.prefiller.waiting:
+            # the host's part of a riding window; every window, every
+            # program and the fetch of a prefill alone
             with _tracer.span("llm.prefill", parent=root):
-                self._run_prefill()
-        for payload in self.handoff.pull(self.decoder.free_slots):
-            meta = self._meta[payload["seq"]["seq_id"]]
-            slot = self.decoder.activate(meta.slot, payload)
-            meta.slot = slot
-            meta.first_token = int(payload["first"])
-            # the prefill-produced first token spends 1 of the slot's
-            # budget; credit it at this boundary's scheduler step
-            self._first_credit[slot] = 1
+                self._hand_off(self.prefiller.prefill(jobs))
+            root.set_attr("ride_rows", self.prefiller.rode)
         finished = []
         with _tracer.span("llm.decode", parent=root):
             results = self.decoder.step()
+        # the prompts whose last row rode in the step's program
+        self._hand_off(self.prefiller.landed())
         if results:
             self._c_steps.inc(1, service=self.service)
         tokens_by_slot = dict(self._first_credit)
@@ -1043,9 +1240,11 @@ class LLMEngine:
                 finished.append((seq_id, self._finish(seq_id)))
         return finished
 
-    def _run_prefill(self) -> None:
-        ready = []
-        still_stalled = []
+    def _allocate(self) -> list:
+        """Chains for the admitted prompts the pool has blocks for:
+        ``(seq_id, prompt)`` of each; the others wait for a boundary at
+        which decode completions have released some."""
+        ready, still_stalled = [], []
         for a in self._to_prefill:
             try:
                 h = self.kv.allocate(a.seq_id, a.prompt)
@@ -1057,26 +1256,35 @@ class LLMEngine:
             meta = self._meta[a.seq_id]
             meta.slot = a.slot
             meta.reused_tokens = h.reused_tokens
-            ready.append(a)
+            ready.append((a.seq_id, a.prompt))
         self._to_prefill = still_stalled
-        if not ready:
-            return
-        firsts = self.prefiller.prefill(
-            [(a.seq_id, a.prompt) for a in ready])
+        return ready
+
+    def _hand_off(self, firsts: dict) -> None:
+        """Prompts that are wholly in, ``seq_id -> (first_token,
+        suffix_len)``: TTFT, then through the handoff queue to their
+        slots."""
         now = self.clock()
-        for a in ready:
-            first, suffix_len = firsts[a.seq_id]
-            meta = self._meta[a.seq_id]
+        for seq_id, (first, suffix_len) in firsts.items():
+            meta = self._meta[seq_id]
             meta.t_first = now
             meta.prefill_tokens = suffix_len
             self._h_ttft.observe(
                 now - meta.t_submit, service=self.service,
                 reuse="warm" if meta.reused_tokens else "cold")
             self.handoff.push({
-                "seq": self.kv.export_seq(a.seq_id),
+                "seq": self.kv.export_seq(seq_id),
                 "first": first,
-                "max_new_tokens": a.max_new_tokens,
+                "max_new_tokens": meta.max_new_tokens,
             })
+        for payload in self.handoff.pull(self.decoder.free_slots):
+            meta = self._meta[payload["seq"]["seq_id"]]
+            slot = self.decoder.activate(meta.slot, payload)
+            meta.slot = slot
+            meta.first_token = int(payload["first"])
+            # the prefill-produced first token spends 1 of the slot's
+            # budget; credit it at this boundary's scheduler step
+            self._first_credit[slot] = 1
 
     def _finish(self, seq_id) -> np.ndarray:
         meta = self._meta.pop(seq_id)
@@ -1113,8 +1321,7 @@ class LLMEngine:
         self.decoder.warm()
         if mark_steady:
             compile_tracker.mark_steady()
-        return {**self.prefiller.aot_fingerprints(),
-                **self.decoder.aot_fingerprints()}
+        return self.programs.aot_fingerprints()
 
     def run_until_drained(self) -> dict:
         """Step until every submitted sequence completes or expires;
@@ -1128,7 +1335,7 @@ class LLMEngine:
             # in-flight decode to release any is unrecoverable
             if len(self._done) == before and self._to_prefill and \
                     not self.decoder.active.any() and \
-                    not len(self.handoff):
+                    not len(self.handoff) and not self.prefiller.waiting:
                 stalled += 1
                 if stalled > 3:
                     raise OutOfBlocks(

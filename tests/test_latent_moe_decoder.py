@@ -76,9 +76,10 @@ def test_prefill_then_decode_through_the_pool_matches_the_reference(model):
     def head(hidden):
         return module.apply({"params": weights}, hidden, method="logits")
 
-    hidden, pools, counts = module.apply(
-        {"params": weights}, jnp.asarray(toks), pools, rows,
-        jnp.zeros(2, jnp.int32), valid)
+    (hidden,), pools, counts = module.apply(
+        {"params": weights},
+        ((jnp.asarray(toks), rows, jnp.zeros(2, jnp.int32), valid),),
+        pools)
     assert hidden.shape == (2, w, 64)        # hidden rows, no head
     logits = head(hidden)
     assert counts.shape == (4,) and int(counts[0]) > 0
@@ -86,9 +87,9 @@ def test_prefill_then_decode_through_the_pool_matches_the_reference(model):
     for step in range(5):
         pos = jnp.asarray([p + step for p in prefix], jnp.int32)
         tok = jnp.asarray([[s[p + step]] for s, p in zip(seqs, prefix)])
-        hidden, pools, _ = module.apply(
-            {"params": weights}, tok, pools, rows, pos,
-            jnp.ones((2, 1), bool))
+        (hidden,), pools, _ = module.apply(
+            {"params": weights},
+            ((tok, rows, pos, jnp.ones((2, 1), bool)),), pools)
         logits = head(hidden)
         for i in range(2):
             got[i].append(np.asarray(logits[i]))
@@ -298,3 +299,65 @@ def test_engine_boundaries_leave_step_prefill_and_decode_spans(engine_of):
     assert names.count("llm.decode") == len(roots)
     assert all(len([k for k in kids if k.parent_id == r.span_id]) <= 3
                for r in roots)
+
+
+#: case -> (widest window, length of the prompt that rides, its new tokens)
+RIDE_CASES = {"suffix_of_one_window": (192, 20, 5),
+              "several_chunks": (8, 21, 4),
+              "one_new_token": (8, 13, 1)}
+
+
+@pytest.mark.parametrize("case", sorted(RIDE_CASES))
+def test_a_riding_window_serves_the_tokens_and_counts_of_todays_order(
+        model, engine_of, case):
+    """A prompt that arrives while another decodes rides into the decode
+    step's program: the served tokens, and every expert pair counted, are
+    those of the engine that prefills alone and then decodes (``rider``
+    None: today's order). Each expert is touched once a call where the
+    two programs touched it once each."""
+    max_window, length, new = RIDE_CASES[case]
+    rng = np.random.default_rng(17)
+    first, second = rng.integers(1, 256, 11), rng.integers(1, 256, length)
+    served, counts, rode = {}, {}, {}
+    for order in ("ride", "alone"):
+        eng, reg = engine_of()
+        eng.prefiller.max_window = max_window
+        eng.prefiller.ride_from = 1
+        if order == "alone":
+            eng.prefiller.rider = None
+        eng.submit("a", first, 10)
+        out = dict(eng.step())
+        eng.submit("b", second, new)
+        out.update(eng.run_until_drained())
+        served[order] = out
+        counts[order] = {
+            name: _value(reg, f"moe_{name}_total").value(service="latent")
+            for name in ("pairs_held", "pairs_absent", "experts_touched")}
+        rode[order] = _value(reg, "gen_prefill_rows_total").value(
+            service="latent", ride="decode")
+    assert rode == {"ride": length, "alone": 0}
+    for seq_id in ("a", "b"):
+        np.testing.assert_array_equal(served["ride"][seq_id],
+                                      served["alone"][seq_id])
+    for name in ("pairs_held", "pairs_absent"):
+        assert counts["ride"][name] == counts["alone"][name] > 0
+    assert 0 < counts["ride"]["experts_touched"] \
+        <= counts["alone"]["experts_touched"]
+
+
+def test_a_riding_boundary_keeps_its_prefill_and_decode_spans(engine_of):
+    """``llm.prefill`` is the host's part of the riding window,
+    ``llm.decode`` the program and its fetch: siblings under ``llm.step``,
+    which says how many rows rode."""
+    eng, _ = engine_of()
+    eng.prefiller.ride_from = 1
+    eng.submit("a", np.arange(1, 12), 8)
+    eng.step()
+    before = len(tracer.recent("llm.step"))
+    eng.submit("b", np.arange(3, 20), 2)
+    eng.step()
+    root = tracer.recent("llm.step")[before]
+    assert root.attrs["ride_rows"] == 17
+    kids = [s.name for s in tracer.recent() if s.parent_id == root.span_id]
+    assert sorted(kids) == ["llm.decode", "llm.prefill"]
+    eng.run_until_drained()

@@ -181,7 +181,8 @@ class TestChunkedPrefill:
             np.testing.assert_array_equal(got[i],
                                           ref[i][:len(p) + MAXNEW])
         # no window wider than the cap was ever built
-        assert max(w for w, _ in eng.prefiller._programs) <= 8
+        assert max(w for _, w, _ in eng.programs.built
+                   if w is not None) <= 8
 
     def test_chunk_after_reused_prefix_and_steady_state(self, lm,
                                                         narrow):
@@ -257,11 +258,12 @@ def _last_row_argmax(decoder, prompt):
     module, variables, _ = decoder
     n = len(prompt)
     blocks = -(-n // 4)
-    hidden, _, _ = module.apply(
-        variables, jnp.asarray(prompt)[None],
-        init_pools(module.cache_spec(), blocks + 1, 4),
-        jnp.arange(1, blocks + 1, dtype=jnp.int32)[None],
-        jnp.zeros(1, jnp.int32), jnp.ones((1, n), bool), method="walk")
+    (hidden,), _, _ = module.apply(
+        variables,
+        ((jnp.asarray(prompt)[None],
+          jnp.arange(1, blocks + 1, dtype=jnp.int32)[None],
+          jnp.zeros(1, jnp.int32), jnp.ones((1, n), bool)),),
+        init_pools(module.cache_spec(), blocks + 1, 4), method="walk")
     logits = np.array(module.apply(variables, hidden, method="logits"))
     assert logits.shape[:2] == (1, n)
     logits[..., 0] = -np.inf
@@ -318,7 +320,7 @@ def test_first_token_after_a_fully_reused_prefix(decoder):
     eng.submit("warm", p, 2)
     assert int(eng.run_until_drained()["warm"][16]) == want
     assert _prefill_calls(reg) == (2, 1)
-    assert (1, True) in eng.prefiller._programs
+    assert (False, 1, True) in eng.programs.built
 
 
 def _made_shapes(jaxpr):
@@ -339,28 +341,207 @@ def test_no_prefill_program_holds_a_window_of_logits(decoder):
     eng = _head_engine(decoder, MetricsRegistry(), slots=1,
                        prefill_batch=2)
     w, P = 8, 2
-    args = (variables["params"], None, eng.pools.target, None,
-            jnp.zeros((P, eng.max_blocks), jnp.int32),
-            jnp.zeros((P, w), jnp.int32), jnp.zeros(P, jnp.int32),
-            jnp.ones(P, jnp.int32))
-    emits = eng.prefiller._program(w, True).trace(*args).jaxpr
+    dec, win = eng.programs.blank(False, w)
+    args = (variables["params"], None, eng.pools.target, None, dec, win)
+    emits = eng.programs.get(False, w, True).trace(*args).jaxpr
     made = set(_made_shapes(emits.jaxpr))
     assert (P, vocab) in made
     assert not [s for s in made if vocab in s and w in s]
     # one row of logits a prompt, no more
     assert all(np.prod(s) <= P * vocab for s in made if vocab in s)
-    quiet = eng.prefiller._program(w, False).trace(*args).jaxpr
+    quiet = eng.programs.get(False, w, False).trace(*args).jaxpr
     assert not [s for s in _made_shapes(quiet.jaxpr) if vocab in s]
     assert not [v for v in quiet.jaxpr.outvars
                 if vocab in getattr(v.aval, "shape", ())]
     # and the decode step keeps its one row a slot
     S = eng.decoder.slots
-    step = eng.decoder._build().trace(
+    step = eng.programs.get(True, None).trace(
         variables["params"], None, eng.pools.target, None,
-        jnp.zeros((S, eng.max_blocks), jnp.int32), jnp.zeros(S, jnp.int32),
-        jnp.ones(S, jnp.int32), jnp.full(S, 2, jnp.int32),
-        jnp.zeros(S, bool)).jaxpr
-    assert (S, 1, vocab) in set(_made_shapes(step.jaxpr))
+        *eng.programs.blank(True, None)).jaxpr
+    made = set(_made_shapes(step.jaxpr))
+    assert (S, vocab) in made
+    assert all(np.prod(s) <= S * vocab for s in made if vocab in s)
+
+
+# -- a prompt's prefill window rides with the decoding rows ------------------
+
+def _serve(eng, waves):
+    """Each wave of ``(seq_id, prompt, max_new)`` is submitted, then ONE
+    boundary runs; after the last wave the engine drains. Returns
+    ``seq_id -> tokens``."""
+    out = {}
+    for wave in waves:
+        for seq_id, prompt, max_new in wave:
+            eng.submit(seq_id, prompt, max_new)
+        out.update(dict(eng.step()))
+    out.update(eng.run_until_drained())
+    return out
+
+
+def _prefill_rows(reg, service):
+    """``(rows that rode with the decoding rows, rows fed alone)``."""
+    rows = next(m for m in reg.metrics("gen_prefill_rows_total")
+                if m.name == "gen_prefill_rows_total")
+    return (rows.value(service=service, ride="decode"),
+            rows.value(service=service, ride="alone"))
+
+
+def _one_window_lm(lm):
+    """The same decoder saying, through the interface, that its walk
+    takes one window a call."""
+    module, variables = lm
+
+    class OneWindow(MaskedLMModel):
+        several_windows = False
+
+    return OneWindow(module.encoder), variables
+
+
+#: case -> (engine keywords, waves of (prompt length, new tokens) submitted a
+#: boundary apart, prompt rows that ride with the decoding rows); windows
+#: are at most 8 wide, blocks hold 4 tokens
+RIDE_CASES = {
+    # 21 rows ride in over three boundaries while the first decodes on and
+    # the second finishes
+    "several_chunks_ride_across_boundaries": (
+        {}, [[(5, 12), (6, 3)], [(21, 4)]], 21),
+    # admitted together a boundary after the first: one rides, the other
+    # waits its turn behind it
+    "two_wait_for_one_window": (
+        {}, [[(5, 12)], [(13, 4), (9, 4)]], 22),
+    # two prompts a window
+    "two_prompts_a_window": (
+        {"prefill_batch": 2}, [[(5, 12)], [(13, 4), (21, 4)]], 34),
+    # finished by its prefill alone: no decode step of its own
+    "one_new_token": ({}, [[(5, 12)], [(11, 1)], [(3, 2)]], 14),
+    # the third cannot be allocated while the second is half in: it waits
+    # for the blocks the first releases
+    "pool_out_of_blocks_with_a_prompt_half_in": (
+        {"num_blocks": 13, "hbm_fraction": 1.0},
+        [[(5, 8)], [(21, 4), (9, 4)]], 30),
+    # what keeps today's order: nothing to ride with, fewer decoding rows
+    # than pay for the slot a riding prompt holds idle (the engine's own 8),
+    # a speculative step, a decoder whose walk takes one window
+    "no_runnable_slot": ({}, [[(5, 4), (21, 4), (9, 4)]], 0),
+    "too_few_rows_decode": ({"ride_from": None}, [[(5, 12)], [(21, 4)]], 0),
+    "speculative_step": ({"spec_k": 2}, [[(5, 12)], [(21, 4)]], 0),
+    "decoder_of_one_window": ({}, [[(5, 12)], [(21, 4)]], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RIDE_CASES))
+def test_riding_prefill_serves_generates_tokens(lm, draft_lm, case):
+    kw, waves, rode = RIDE_CASES[case]
+    kw = {"ride_from": 1, **kw}
+    ride_from = kw.pop("ride_from")
+    module, variables = _one_window_lm(lm) \
+        if case == "decoder_of_one_window" else lm
+    if kw.get("spec_k"):
+        kw = dict(kw, draft_module=draft_lm[0], draft_variables=draft_lm[1])
+    rng = np.random.default_rng(41)
+    sizes = [size for wave in waves for size in wave]
+    prompts = [rng.integers(2, VOCAB, size=n).astype(np.int32)
+               for n, _ in sizes]
+    reg = MetricsRegistry()
+    svc = f"ride-{case}"
+    eng = LLMEngine(module, variables, block_len=4, max_seq_len=32,
+                    service=svc, registry=reg,
+                    **{"slots": 3, "prefill_batch": 1, **kw})
+    eng.prefiller.max_window = 8         # VMEM-bound on the chip
+    if ride_from is not None:
+        eng.prefiller.ride_from = ride_from
+    ids = iter(range(len(sizes)))
+    got = _serve(eng, [[(i, prompts[i], sizes[i][1])
+                        for i in (next(ids) for _ in wave)]
+                       for wave in waves])
+    assert set(got) == set(range(len(sizes)))
+    for i, (p, (_, max_new)) in enumerate(zip(prompts, sizes)):
+        want = np.asarray(generate(
+            module, variables, p[None, :], max_new_tokens=max_new,
+            temperature=0.0)[0])
+        np.testing.assert_array_equal(got[i], want[:len(p) + max_new])
+    assert _prefill_rows(reg, svc) == (
+        rode, sum(n for n, _ in sizes) - rode)
+    assert eng.kv.stats()["sequences"] == 0
+
+
+def test_a_prefix_hit_rides_its_suffix_alone(lm):
+    """The second request's first 16 tokens are in the prefix index: its
+    6-row suffix rides with the first request's decode rows, and the
+    tokens are ``dl.generate``'s."""
+    module, variables = lm
+    rng = np.random.default_rng(43)
+    doc = rng.integers(2, VOCAB, size=16).astype(np.int32)
+    prompts = [np.concatenate([doc, rng.integers(2, VOCAB, size=n)
+                               .astype(np.int32)]) for n in (3, 6)]
+    reg = MetricsRegistry()
+    eng = LLMEngine(module, variables, slots=2, block_len=4,
+                    max_seq_len=40, prefill_batch=1, service="ridehit",
+                    registry=reg)
+    eng.prefiller.ride_from = 1
+    got = _serve(eng, [[(0, prompts[0], 10)], [(1, prompts[1], 5)]])
+    ref = _ref(lm, prompts, max_new=10)
+    np.testing.assert_array_equal(got[0], ref[0][:len(prompts[0]) + 10])
+    np.testing.assert_array_equal(got[1], ref[1][:len(prompts[1]) + 5])
+    assert reg.snapshot()[
+        'kv_prefix_tokens_reused_total{service="ridehit"}'] == 16.0
+    assert _prefill_rows(reg, "ridehit") == (6, 19)
+
+
+def _weight_products(jaxpr, shapes) -> int:
+    """Matrix products of a program whose second operand has the shape of
+    one of the decoder's matrices: how often the program reads them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and \
+                tuple(eqn.invars[1].aval.shape) in shapes:
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _weight_products(sub, shapes)
+    return n
+
+
+def test_a_boundary_is_one_program_that_reads_each_weight_once(lm):
+    """A boundary at which a prompt's window rides is ONE program call —
+    no prefill program, no decode program — and that program multiplies
+    by each of the decoder's matrices as often as the decode program
+    does: the rows of both windows go through them together."""
+    module, variables = lm
+    reg = MetricsRegistry()
+    svc = "rideonce"
+    eng = LLMEngine(module, variables, slots=2, block_len=4,
+                    max_seq_len=32, prefill_batch=1, service=svc,
+                    registry=reg)
+    eng.prefiller.max_window = 8         # VMEM-bound on the chip
+    eng.prefiller.ride_from = 1
+    matrices = {tuple(a.shape) for a in jax.tree.leaves(
+        variables["params"]) if a.ndim == 2}
+    args = (variables["params"], None, eng.pools.target, None)
+    products = {
+        key: _weight_products(
+            eng.programs.get(*key).trace(
+                *args, *eng.programs.blank(*key[:2])).jaxpr.jaxpr, matrices)
+        for key in ((True, None, True), (True, 8, True))}
+    assert products[True, 8, True] == products[True, None, True] > 0
+    rng = np.random.default_rng(47)
+    eng.submit("a", rng.integers(2, VOCAB, size=5).astype(np.int32), 12)
+    eng.step()                           # alone: nothing decodes yet
+    eng.submit("b", rng.integers(2, VOCAB, size=21).astype(np.int32), 2)
+    called = {name: compile_tracker.calls(name)
+              for name in eng.programs.aot_fingerprints()}
+    steps = next(m for m in reg.metrics("gen_decode_steps_total")
+                 if m.name == "gen_decode_steps_total")
+    before = steps.value(service=svc), sum(_prefill_calls(reg, svc))
+    for _ in range(3):                   # b rides in, 8 + 8 + 5 rows
+        eng.step()
+    now = {name: compile_tracker.calls(name) - n
+           for name, n in called.items()}
+    assert now == {**dict.fromkeys(called, 0),
+                   f"llm_step_{svc}_S2_w8_b1": 3}
+    assert steps.value(service=svc) - before[0] == 3
+    assert sum(_prefill_calls(reg, svc)) - before[1] == 3
+    assert _prefill_rows(reg, svc) == (21, 5)
+    eng.run_until_drained()
 
 
 class TestPoolSizing:
@@ -468,12 +649,14 @@ class TestSteadyState:
         finally:
             compile_tracker.unmark_steady()
         assert len(got) == 3
-        # one decode program + one prefill program per window bucket,
-        # each with an AOT fingerprint pair
+        # one decode program + one prefill program per window bucket +
+        # one riding window (the ladder starts at 32 rows), each with an
+        # AOT fingerprint pair
         assert set(fps) == {"llm_decode_paged_llmsteady_S2_k0",
                             "llm_prefill_llmsteady_w1_b2",
                             "llm_prefill_llmsteady_w4_b2",
-                            "llm_prefill_llmsteady_w8_b2"}
+                            "llm_prefill_llmsteady_w8_b2",
+                            "llm_step_llmsteady_S2_w32_b2"}
         for static_fp, full_fp in fps.values():
             assert static_fp and full_fp
 
@@ -493,7 +676,8 @@ class TestSteadyState:
         ref = _ref(lm, prompts)
         fps = eng.warm(prefill_windows=(21, 5, 16), mark_steady=True)
         try:
-            assert {(8, True), (8, False)} <= set(eng.prefiller._programs)
+            assert {(False, 8, True), (False, 8, False)} \
+                <= set(eng.programs.built)
             for i, p in enumerate(prompts):
                 eng.submit(i, p, MAXNEW)
             got = eng.run_until_drained()
@@ -539,11 +723,18 @@ def test_program_names_and_fingerprint_keys(lm, draft_lm, monkeypatch,
     fps = eng.warm(prefill_windows=(3, 21), mark_steady=False)
     assert eng.prefiller.windows_for(21) == [8, 8, 8]
     decode = f"llm_decode_paged_{svc}_S2_k{spec_k}"
+    # a window rides with the plain decode step alone: one program a
+    # riding width (here every chunk rides 8 wide), with the head
+    ride = set() if spec_k else {f"llm_step_{svc}_S2_w8_b1"}
     assert set(fps) == {decode, f"llm_prefill_{svc}_w4_b1",
                         f"llm_prefill_{svc}_w8_b1",
-                        f"llm_prefill_{svc}_w8_b1_nohead"}
+                        f"llm_prefill_{svc}_w8_b1_nohead"} | ride
     by_name = {name: key_of[fp] for name, fp in fps.items()}
     assert all(key["attn"] == "paged" for key in by_name.values())
+    for name in ride:
+        key = by_name[name]
+        assert (key["phase"], key["window"], key["batch"], key["slots"],
+                key["spec_k"]) == ("step", 8, 1, 2, 0)
     assert by_name[decode]["phase"] == "decode"
     assert by_name[decode]["spec_k"] == spec_k
     assert by_name[decode]["slots"] == 2

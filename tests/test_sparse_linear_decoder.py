@@ -182,10 +182,11 @@ def _walk_all(module, weights, tokens, windows, *, state_slots=2):
         k = min(w, n - pos)
         toks = np.zeros((1, w), np.int32)
         toks[0, :k] = tokens[pos:pos + k]
-        hidden, pools, c = module.apply(
-            {"params": weights}, jnp.asarray(toks), pools, rows,
-            jnp.asarray([pos], jnp.int32), jnp.arange(w)[None] < k,
-            jnp.asarray([1], jnp.int32), method="walk")
+        (hidden,), pools, c = module.apply(
+            {"params": weights},
+            ((jnp.asarray(toks), rows, jnp.asarray([pos], jnp.int32),
+              jnp.arange(w)[None] < k, jnp.asarray([1], jnp.int32)),),
+            pools, method="walk")
         out.append(np.asarray(module.apply(
             {"params": weights}, hidden, method="logits"))[0, :k])
         counts += np.asarray(c)
@@ -313,3 +314,60 @@ def test_speculation_beside_a_state_is_refused(model):
         LLMEngine(module, {"params": weights}, draft_module=module,
                   draft_variables={"params": weights}, spec_k=2,
                   block_len=BL, num_blocks=8, registry=MetricsRegistry())
+
+
+#: case -> (tokens the question adds to the 64-token document, new tokens)
+RIDE_CASES = {
+    # restored, then 7 rows in one window
+    "restore_then_one_window": (7, 6),
+    # restored; two new whole blocks make a snapshot at 96: [64, 96) rides
+    # in two windows, the cut falls between two boundaries, [96, 104) rides
+    "restore_then_a_cut_between_two_boundaries": (40, 6),
+    # finished by its prefill alone, a cut at 80 on its way
+    "one_new_token": (21, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RIDE_CASES))
+def test_a_riding_window_restores_cuts_and_serves_todays_tokens(model, case):
+    """A question on an indexed document arrives while another request
+    decodes: its snapshot is restored before the boundary's program, its
+    rows ride in the decode step's program (a cut at ``snapshot_at``
+    between two of them), and the tokens, the restores and the snapshots
+    are those of the engine that prefills alone (``rider`` None)."""
+    extra, new = RIDE_CASES[case]
+    rng = np.random.default_rng(21)
+    doc = rng.integers(1, 256, 64).astype(np.int32)    # four whole blocks
+    other = rng.integers(1, 256, 37).astype(np.int32)
+    ask = np.concatenate([doc, rng.integers(1, 256, extra).astype(np.int32)])
+    served, seen = {}, {}
+    for order in ("ride", "alone"):
+        reg = MetricsRegistry()
+        eng = _engine(model, reg, slots=3, state_slots=6, prefill_batch=1)
+        eng.prefiller.ride_from = 1
+        if order == "alone":
+            eng.prefiller.rider = None
+        eng.submit("doc", doc, 1)
+        eng.run_until_drained()
+        eng.submit("other", other, 12)
+        out = dict(eng.step())
+        eng.submit("q", ask, new)
+        out.update(eng.run_until_drained())
+        served[order] = out
+        seen[order] = {name: _counter(reg, name) for name in (
+            "kv_state_restores_total", "kv_state_snapshots",
+            "kv_prefix_tokens_reused_total", "sparse_blocks_chosen_total",
+            "sparse_dense_rows_total")}
+        rode = next(m for m in reg.metrics("gen_prefill_rows_total")
+                    if m.name == "gen_prefill_rows_total").value(
+                        service="sala", ride="decode")
+        assert rode == (extra if order == "ride" else 0)
+        assert _counter(reg, "kv_state_slots_used") == 0   # all given back
+    for seq_id in ("other", "q"):
+        np.testing.assert_array_equal(served["ride"][seq_id],
+                                      served["alone"][seq_id])
+    assert seen["ride"] == seen["alone"]
+    assert seen["ride"]["kv_state_restores_total"] == 1
+    # the document's, the other request's two whole blocks', and the
+    # question's where it brings a whole block of its own
+    assert seen["ride"]["kv_state_snapshots"] == 2 + (extra >= BL)
