@@ -77,7 +77,9 @@ PACKAGES: dict[str, list[str]] = {
     # paged-attention kernel equivalence suite
     "llm": ["test_paged_kv.py", "test_llm_serving.py",
             "test_paged_attention.py", "test_latent_moe_decoder.py",
-            "test_paged_kv_state.py", "test_sparse_linear_decoder.py"],
+            "test_paged_kv_state.py", "test_sparse_linear_decoder.py",
+            "test_gated_delta.py", "test_gated_delta_moe_decoder.py",
+            "test_qwen3_next_reference.py"],
     # zero-downtime model lifecycle: versioned registry + blue/green
     # router + canary burn-rate rollback, and the rollout acceptance
     "deploy": ["test_deploy.py"],

@@ -49,6 +49,8 @@ _EXPORTS = {
     "paged_latent_attention": ".pallas_paged_attention",
     "LatentMoEDecoder": ".latent_moe_decoder",
     "SparseLinearDecoder": ".sparse_linear_decoder",
+    "GatedDeltaMoEDecoder": ".gated_delta_moe_decoder",
+    "gated_delta_rule": ".pallas_gated_delta",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -1,6 +1,7 @@
 """What the config-driven decoders of ``serving.llm.LLMEngine`` share
-(``dl.latent_moe_decoder``, ``dl.sparse_linear_decoder``): RMSNorm in
-float32, the matrix product on operands of the serving type with float32
+(``dl.latent_moe_decoder``, ``dl.sparse_linear_decoder``,
+``dl.gated_delta_moe_decoder``): RMSNorm in float32 (plain and
+zero-centred), the matrix product on operands of the serving type with float32
 accumulation, the gated SiLU MLP, the two rotary pairings, and the calling
 convention the engine uses for a decoder that is a plain class over a
 params dict; and what every decoder's ``walk`` shares (``dl.pretrain``'s
@@ -20,8 +21,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["rms_norm", "mm", "gated_silu", "rotate_interleaved",
-           "rotate_half", "rope_angles", "DictDecoder", "lay_rows",
-           "split_rows", "window_positions"]
+           "rotate_half", "rotate_half_partial", "rope_angles",
+           "DictDecoder", "lay_rows", "split_rows", "window_positions"]
 
 
 def lay_rows(parts):
@@ -87,6 +88,17 @@ def rotate_half(x, cos, sin):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
+def rotate_half_partial(x, cos, sin):
+    """:func:`rotate_half` on the first ``2 * cos.shape[-1]`` numbers of
+    the last axis (a head's rotary part, ``partial_rotary_factor`` of
+    it); the rest pass as they are."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return rotate_half(x, cos, sin)
+    return jnp.concatenate([rotate_half(x[..., :rot], cos, sin),
+                            x[..., rot:]], axis=-1)
+
+
 class DictDecoder:
     """A decoder that is a plain class over a params dict: the engine
     calls every decoder as ``module.apply({"params": ...}, *args,
@@ -100,6 +112,11 @@ class DictDecoder:
 
     def _rms(self, x, scale):
         return rms_norm(x, scale, self.eps)
+
+    def _rms_centred(self, x, weight):
+        """The zero-centred RMSNorm ``x^ (1 + w)``: a weight of zeros is
+        the plain norm."""
+        return rms_norm(x, 1.0 + weight.astype(jnp.float32), self.eps)
 
     def _mm(self, a, w):
         return mm(a, w, self.dtype)

@@ -44,7 +44,12 @@ lists, a layer, entries ``(trailing shape, dtype[, kind])``:
 - ``"seq"``: one entry a SEQUENCE whatever its length, ``[1 + state_slots,
   *shape]``, addressed by a ROW the manager hands out with the chain
   (``SequenceHandle.state_row``; row 0 is the trash row) — the recurrent
-  state of a linear-attention layer.
+  state of a linear-attention layer. A layer may state SEVERAL, of
+  different shape and type (a float32 matrix state and the last inputs
+  of a causal convolution): each is a pool of its own and a sequence
+  holds the SAME row in every one, so a row's price
+  (:func:`state_row_bytes`), a snapshot and a restore
+  (:func:`copy_state_rows`) are of all of them together.
 
 With ``"seq"`` arrays a cached prefix is reusable only up to a boundary at
 which a SNAPSHOT of the state exists: a row of the same pools that holds
@@ -225,7 +230,8 @@ def pool_block_bytes(spec, block_len: int) -> int:
 
 def state_row_bytes(spec) -> int:
     """Bytes ONE row of the ``"seq"`` entries of a ``cache_spec()`` takes,
-    all layers (0: the decoder keeps nothing a sequence)."""
+    every such entry of every layer (0: the decoder keeps nothing a
+    sequence)."""
     return sum(int(np.prod(e[0])) * np.dtype(e[1]).itemsize
                for layer in spec for e in layer
                if entry_kind(e)[0] == "seq")

@@ -77,18 +77,22 @@ def _paged_reference(q, k_pool, v_pool, rows, pos):
     NaN→0 for fully-masked rows, ``p.astype(v.dtype)`` before the
     value einsum. Bit-identical to the dense-cache decode math — the
     byte-identity contract with ``dl.generate`` rides on it. The pools
-    rest ``[num_blocks, block_len, heads*hd]``; the gathered keys are
-    viewed ``[S, L, heads, hd]``."""
+    rest ``[num_blocks, block_len, kv_heads*hd]``; the gathered keys are
+    viewed ``[S, L, kv_heads, hd]``, and with fewer key heads than query
+    heads each key head is repeated for the query heads it serves."""
     S, H, w, hd = q.shape
     NB, BL = k_pool.shape[0], k_pool.shape[1]
+    G = k_pool.shape[2] // hd
     MB = rows.shape[1]
     L = MB * BL
     idx = (rows[:, :, None] * BL
            + jnp.arange(BL)[None, None, :]).reshape(S, L)
-    k = jnp.take(k_pool.reshape(NB * BL, H, hd), idx, axis=0)
-    v = jnp.take(v_pool.reshape(NB * BL, H, hd), idx, axis=0)
-    k = jnp.transpose(k, (0, 2, 1, 3))                  # [S, H, L, hd]
+    k = jnp.take(k_pool.reshape(NB * BL, G, hd), idx, axis=0)
+    v = jnp.take(v_pool.reshape(NB * BL, G, hd), idx, axis=0)
+    k = jnp.transpose(k, (0, 2, 1, 3))                  # [S, G, L, hd]
     v = jnp.transpose(v, (0, 2, 1, 3))
+    if G != H:
+        k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * (hd ** -0.5)
     allowed = (jnp.arange(L)[None, None, :]
@@ -142,16 +146,21 @@ def _online_softmax(s, allowed, m_scr, l_scr, R: int):
 def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *qbd_scr, scale: float,
                   heads: int, w: int, block_len: int, block_kv: int,
-                  slots_tile: int):
+                  slots_tile: int, group: int = 1):
     """One (slot-group, slot, chain-block) grid cell. The k/v refs
     already hold pool block ``chain_block(s, j)`` as ``[block_len,
-    heads*hd]``: positions on sublanes, head ``h`` in lanes ``h*hd …``,
-    so a head's keys are a lane-aligned slice of the ref, loaded as they
-    lie. ``qbd_scr`` is there in the batched form (``heads * w <=
+    kv_heads*hd]``: positions on sublanes, key head ``h`` in lanes ``h*hd
+    …``, so a head's keys are a lane-aligned slice of the ref, loaded as
+    they lie. ``group`` = ``heads / kv_heads`` query heads share a key
+    head (1: every query head has its own): the ``group * w`` query rows
+    of a key head, rows ``kh*group*w …`` of the slot's ``[heads*w, hd]``
+    queries, are the rows of ONE product against that head's lane slice.
+    ``qbd_scr`` is there in the batched form (``heads * w <=
     _BATCHED_ROWS``): the slot's query rows laid block-diagonally,
-    ``[heads*w, heads*hd]`` with row ``h*w + i`` holding ``q[h, i]`` in
-    head ``h``'s lanes and zeros elsewhere, so that ONE product scores
-    every head against the block and ONE weights the values."""
+    ``[heads*w, kv_heads*hd]`` with row ``h*w + i`` holding ``q[h, i]`` in
+    the lanes of head ``h``'s key head and zeros elsewhere, so that ONE
+    product scores every head against the block and ONE weights the
+    values."""
     g = pl.program_id(0)
     u = pl.program_id(1)
     j = pl.program_id(2)
@@ -159,6 +168,11 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     s_idx = g * slots_tile + u
     R = heads * w
     hd = q_ref.shape[2]
+    kv_heads = heads // group
+
+    def key_head(own):
+        """The key head of query head ``own``."""
+        return own if group == 1 else own // group
 
     def row_of(shape):
         """Row ``h*w + i`` of the batched form, as (head, window row)."""
@@ -172,8 +186,8 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
         if qbd_scr:
             q = q_ref[0]                                # [R, hd]
-            own, _ = row_of(q.shape)
-            for h in range(heads):
+            own = key_head(row_of(q.shape)[0])
+            for h in range(kv_heads):
                 qbd_scr[0][:, h * hd:(h + 1) * hd] = jnp.where(
                     own == h, q, jnp.zeros_like(q))
 
@@ -189,22 +203,23 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         pv = jax.lax.dot_general(          # [R, heads*hd] f32
             p.astype(v_ref.dtype), v_ref[0, lo:hi, :],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        # a row's own head is the diagonal block of the product
-        own, _ = row_of((R, hd))
+        # a row's own key head is the diagonal block of the product
+        own = key_head(row_of((R, hd))[0])
         acc_scr[:R, :] = acc_scr[:R, :] * corr + sum(
             jnp.where(own == h, pv[:, h * hd:(h + 1) * hd], 0.0)
-            for h in range(heads))
+            for h in range(kv_heads))
 
     def by_head(lo, hi, allowed):
-        for h in range(heads):
-            rows_h = slice(h * w, (h + 1) * w)
+        gw = group * w                     # a key head's query rows
+        for h in range(kv_heads):
+            rows_h = slice(h * gw, (h + 1) * gw)
             lanes_h = slice(h * hd, (h + 1) * hd)
-            s = jax.lax.dot_general(       # [w, cw] f32 on the MXU
+            s = jax.lax.dot_general(       # [gw, cw] f32 on the MXU
                 q_ref[0, rows_h, :], k_ref[0, lo:hi, lanes_h],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             p, corr = _online_softmax(
-                s, allowed, m_scr.at[rows_h], l_scr.at[rows_h], w)
+                s, allowed, m_scr.at[rows_h], l_scr.at[rows_h], gw)
             acc_scr[rows_h, :] = acc_scr[rows_h, :] * corr \
                 + jax.lax.dot_general(
                     p.astype(v_ref.dtype), v_ref[0, lo:hi, lanes_h],
@@ -219,8 +234,8 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         for c in range(-(-block_len // block_kv)):
             lo = c * block_kv
             hi = min(block_len, lo + block_kv)
-            # one score tile: every head's rows, or one head's
-            tile = (R if qbd_scr else w, hi - lo)
+            # one score tile: every head's rows, or one key head's
+            tile = (R if qbd_scr else group * w, hi - lo)
             # chain-logical key positions of this chunk vs each query
             # row's global position: covers causality AND length (the
             # tail block's unwritten positions are > pos + i)
@@ -228,6 +243,8 @@ def _paged_kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                 jnp.int32, tile, 1)
             i = row_of(tile)[1] if qbd_scr else \
                 jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+            if group > 1 and not qbd_scr:
+                i = i % w                  # row r*w + i of the key head
             (batched if qbd_scr else by_head)(lo, hi, tpos <= pos + i)
 
     @pl.when(j == nj - 1)
@@ -252,9 +269,11 @@ def _paged_pallas(q, k_pool, v_pool, rows, pos, *, block_kv: int,
     rows_p = jnp.pad(rows.astype(jnp.int32), ((0, Sp - S), (0, 0)),
                      constant_values=TRASH_BLOCK)
     pos_p = jnp.pad(pos.astype(jnp.int32), (0, Sp - S))[:, None]
-    kern = functools.partial(_paged_kernel, scale=hd ** -0.5, heads=H,
-                             w=w, block_len=BL, block_kv=bkv,
-                             slots_tile=st)
+    group = H * hd // width               # query heads a key head
+    kern = functools.partial(
+        _paged_kernel, scale=hd ** -0.5, heads=H, w=w, block_len=BL,
+        block_kv=bkv, slots_tile=st, **({"group": group} if group > 1
+                                        else {}))
 
     def block_of(g, u, j, rt, pt):
         # the zero-copy read: the table entry IS the block index
@@ -286,11 +305,21 @@ def _paged_pallas(q, k_pool, v_pool, rows, pos, *, block_kv: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Sp, R, hd), v_pool.dtype),
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            # a key head's ``group * w`` rows of scores and weights are
+            # live at once: more than the default scope at 8 x 128 rows
+            **({"vmem_limit_bytes": _GROUPED_VMEM_LIMIT} if group > 1
+               else {})),
         interpret=interpret,
+        **({"name": GROUPED_KERNEL_NAME} if group > 1 else {}),
     )(rows_p, pos_p, qf, k_pool, v_pool)
     return out[:S].reshape(S, H, w, hd)
 
+
+#: the kernel's name in a device trace where key heads are shared (the
+#: per-head case keeps the name it always had: ``_paged_pallas``)
+GROUPED_KERNEL_NAME = "paged_gqa_attn"
+_GROUPED_VMEM_LIMIT = 64 << 20
 
 #: fast memory one window may claim: half of the 16 MiB a v5e kernel
 #: scopes; the k/v blocks, the score tile and Mosaic's own temporaries
@@ -299,14 +328,18 @@ _WINDOW_VMEM_BYTES = 8 << 20
 
 
 def max_window(heads: int, hd: int, dtype) -> int:
-    """The widest query window (rows per slot) the kernel takes. It
+    """The widest query window (rows per slot) ONE call of the kernel
+    takes. It
     holds a slot's whole window in fast memory — q and the output block
     double-buffered, the f32 accumulator, running max and denominator,
     ``heads * w`` rows of each at 128-lane width — so ``w`` is bounded
     by VMEM, not by the context (first chip compile, PR 23: a 4096-row
     window of 2 heads asked for 23.5 MB against 16). Floored to the
     prefill window ladder (a multiple of 64, below that a power of two);
-    a longer prompt suffix is prefilled in chunks of this width."""
+    a longer prompt suffix is prefilled in chunks of this width.
+    ``heads`` are the QUERY heads (the rows held do not depend on how
+    many key heads they share); :func:`paged_window_attention` attends a
+    wider window in sub-windows of at most this many rows."""
     lanes = -(-int(hd) // 128) * 128
     per_row = lanes * (4 * jnp.dtype(dtype).itemsize + 4) + 2 * 128 * 4
     w = max(_WINDOW_VMEM_BYTES // (int(heads) * per_row), 1)
@@ -361,13 +394,18 @@ def paged_window_attention(q, k_pool, v_pool, rows, pos, *,
     """Windowed paged attention: ``q`` [S, H, w, hd] holds w query rows
     per slot at global positions ``pos[s] + i`` (speculative verify
     passes the k+1 draft window); ``k_pool``/``v_pool`` are ONE layer's
-    pools ``[num_blocks, block_len, H*hd]`` (a token's heads side by
-    side on the lanes); ``rows`` [S, max_blocks]
+    pools ``[num_blocks, block_len, kv_heads*hd]`` (a token's key heads
+    side by side on the lanes; ``kv_heads`` divides ``H``, and query heads
+    ``g*H/kv_heads …`` read key head ``g``: grouped-query attention);
+    ``rows`` [S, max_blocks]
     is the ``PagedKVManager.block_rows`` table (TRASH_BLOCK padding);
     ``pos`` [S] int32. Query row i attends pool positions
     ``t <= pos + i`` through the slot's chain — the window's own k/v
     must already be scattered (write-then-attend, like
-    ``decode_window``'s cache update). Returns [S, H, w, hd].
+    ``decode_window``'s cache update). Returns [S, H, w, hd]. A window
+    wider than :func:`max_window` is attended in equal sub-windows, each a
+    slot of its own over the same chain (the window's keys are in the pool
+    already, so a sub-window sees the ones before it).
 
     ``impl``: "pallas" | "lax" | None (platform switch — TPU-class
     backends run the kernel, everything else the bit-exact lax
@@ -388,12 +426,26 @@ def paged_window_attention(q, k_pool, v_pool, rows, pos, *,
         interpret = plat != "tpu"
     BL = int(k_pool.shape[1])
     context = int(rows.shape[1]) * BL
+    S, H, w, hd = q.shape
+    parts = -(-w // max_window(H, hd, q.dtype))
+    if parts > 1:
+        sub = -(-w // (parts * 8)) * 8
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, parts * sub - w), (0, 0)))
+        q = jnp.transpose(q.reshape(S, H, parts, sub, hd),
+                          (0, 2, 1, 3, 4)).reshape(S * parts, H, sub, hd)
+        rows = jnp.repeat(rows, parts, axis=0)
+        pos = (pos[:, None] + sub * jnp.arange(parts)[None]).reshape(-1)
     block_kv, slots_tile = _resolve_paged(
         block_kv, slots_tile, context=context, block_len=BL,
         hd=int(q.shape[3]), w=int(q.shape[2]), platform=plat)
-    return _paged_pallas(q, k_pool, v_pool, rows, pos,
-                         block_kv=block_kv, slots_tile=slots_tile,
-                         interpret=bool(interpret))
+    out = _paged_pallas(q, k_pool, v_pool, rows, pos,
+                        block_kv=block_kv, slots_tile=slots_tile,
+                        interpret=bool(interpret))
+    if parts > 1:
+        out = jnp.transpose(out.reshape(S, parts, H, sub, hd),
+                            (0, 2, 1, 3, 4)).reshape(S, H, parts * sub, hd)
+        out = out[:, :, :w]
+    return out
 
 
 def paged_attention(q, k_pool, v_pool, rows, pos, *,

@@ -53,12 +53,14 @@ def steer_tpu(monkeypatch):
     """Whole programs ask ``target_platform()`` which path to build, and
     here it answers ``cpu``: steer it from the test (not through an
     option of the program) so that the chip's path is what is lowered."""
+    import mmlspark_tpu.dl.pallas_gated_delta as gated_delta
     import mmlspark_tpu.dl.pallas_lightning as lightning
     import mmlspark_tpu.dl.pallas_paged_attention as paged
     import mmlspark_tpu.utils.platform as plat
     monkeypatch.setattr(plat, "target_platform", lambda: "tpu")
     monkeypatch.setattr(paged, "target_platform", lambda: "tpu")
     monkeypatch.setattr(lightning, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(gated_delta, "target_platform", lambda: "tpu")
 
 
 def _sds(shape, dtype, sharding):
@@ -452,6 +454,122 @@ def test_sparse_linear_engine_programs(one_chip, steer_tpu):
         assert mem.temp_size_in_bytes < 500e6, (name,
                                                 mem.temp_size_in_bytes)
         assert mem.argument_size_in_bytes < 11.7e9, name
+
+
+@pytest.mark.parametrize("window", [1, 32, 512], ids=lambda w: f"w{w}")
+def test_gated_delta_kernels(one_chip, window):
+    """32 value heads of 128 x 128 over a pool of 137 states: the decode
+    step (128 slots, ``w`` = 1) and the chunked form of a prefill window;
+    the pool comes back in the buffer it arrived in."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_gated_delta import gated_delta_rule
+    S, H, hd, rows = (128 if window == 1 else 1), 32, 128, 137
+    qkv = [_sds((S, window, H, hd), jnp.bfloat16, one_chip)] * 3
+    gates = [_sds((S, window, H), jnp.float32, one_chip)] * 2
+    compiled = jax.jit(
+        lambda q, k, v, g, b, st, sr, ps, ln: gated_delta_rule(
+            q, k, v, g, b, st, sr, ps, ln, impl="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+            *qkv, *gates, _sds((rows, H, hd, hd), jnp.float32, one_chip),
+            *[_sds((S,), jnp.int32, one_chip)] * 3).compile()
+    text = compiled.as_text()
+    assert ("gated_delta_step" if window == 1 else "gated_delta_chunk") \
+        in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= rows * H * hd * hd * 4
+
+
+@pytest.mark.parametrize("block_len", [128, 256, 512])
+@pytest.mark.parametrize("window", [1, 96, 512], ids=lambda w: f"w{w}")
+def test_grouped_query_paged_attention(one_chip, block_len, window):
+    """16 query heads on 2 key heads of 256 over pools ``[blocks,
+    block_len, 512]`` holding 458k tokens, chains of 10,240: the decode
+    step's 128 slots (every head in one product a cell), a riding window
+    of 96 rows and one of 512 (four sub-windows of 128: a key head's 1,024
+    query rows one product)."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_paged_attention import (
+        GROUPED_KERNEL_NAME, paged_window_attention)
+    H, G, hd = 16, 2, 256
+    S = 128 if window == 1 else 1
+    NB, MB = 458752 // block_len, 10240 // block_len
+    pool = _sds((NB, block_len, G * hd), jnp.bfloat16, one_chip)
+    _, text = _compile(
+        lambda q, k, v, r, p: paged_window_attention(
+            q, k, v, r, p, impl="pallas", interpret=False),
+        _sds((S, H, window, hd), jnp.bfloat16, one_chip), pool, pool,
+        _sds((S, MB), jnp.int32, one_chip), _sds((S,), jnp.int32, one_chip))
+    assert GROUPED_KERNEL_NAME in text
+
+
+def test_gated_delta_moe_engine_programs(one_chip, steer_tpu):
+    """The engine's decode program, its widest prefill programs and the
+    step with the widest window riding, for the gated DeltaNet / gated
+    attention / expert decoder at the benchmark cell's own sizes
+    (``benchmark/configs/qwen3-next-80b-a3b.json``: 8 layers, 128 experts
+    of 512 held a layer, 128 slots): every pool of both kinds donated and
+    not copied — a DeltaNet layer's state AND its tail — the three
+    kernels there, weights and pools inside the chip's memory."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run
+    from benchmark.references import qwen3_next as ref
+    from mmlspark_tpu.obs.metrics import MetricsRegistry
+
+    _, wl, cfg, params = run.load_cell("qwen3-next-80b-a3b.chat",
+                                       run.load_bench())
+    driver = run._load_module("drivers", wl["driver"])
+
+    def leaf(entry):
+        return _sds(entry[1], jnp.bfloat16, one_chip)
+
+    weights = {k: leaf(v) for k, v in ref.top_shapes(cfg).items()}
+    weights["layers"] = [
+        {k: leaf(v) for k, v in ref.layer_shapes(cfg, i).items()}
+        for i in range(int(cfg["num_hidden_layers"]))]
+    eng = params["engine"]
+    num_blocks, rows = int(eng["num_blocks"]), int(eng["state_slots"]) + 1
+    small = {**params, "engine": {**eng, "num_blocks": 4, "state_slots": 2}}
+    engine = driver.build_engine(cfg, small, weights, MetricsRegistry())
+    spec = engine.module.cache_spec()
+    pools = tuple(
+        tuple(_sds(((rows if len(e) > 2 else num_blocks),) + a.shape[1:],
+                   a.dtype, one_chip) for e, a in zip(layer, arrays))
+        for layer, arrays in zip(spec, engine.pools.target))
+    S, P = engine.decoder.slots, engine.prefiller.batch
+    w = engine.prefiller.max_window
+    assert (S, P, w) == (128, int(eng["prefill_batch"]),
+                         int(params["prefill_chunk"]))
+    assert engine.max_blocks * int(eng["block_len"]) == 10240
+
+    programs = _lower_engine_programs(engine, w, one_chip, weights, None,
+                                      pools, None)
+    at_rest = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree.leaves(pools))
+    assert at_rest == 2 * num_blocks * int(eng["block_len"]) * 2048 \
+        + 6 * rows * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        steps = {"decode": ("gated_delta_step",),
+                 "step": ("gated_delta_step", "gated_delta_chunk")}.get(
+                     name.split("_")[0], ("gated_delta_chunk",))
+        for kernel in ("paged_gqa_attn",) + steps:
+            assert kernel in text, (name, kernel)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= at_rest, name
+        assert mem.temp_size_in_bytes < 1.2e9, (name,
+                                                mem.temp_size_in_bytes)
+        assert mem.argument_size_in_bytes < 11.5e9, name
 
 
 @pytest.fixture(scope="module")

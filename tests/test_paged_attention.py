@@ -158,6 +158,53 @@ class TestKernelInterpret:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("heads,kv_heads,w", [
+        (2, 2, 1), (2, 2, 3),            # a key head a query head: as ever
+        (16, 2, 1),                      # 8 a key head, the batched form
+        (16, 2, 3),                      # 8 a key head, a loop over 2
+        (8, 1, 4)])
+    def test_grouped_query_heads_match_lax(self, heads, kv_heads, w):
+        """Query heads ``g * heads / kv_heads ...`` read key head ``g``
+        of pools ``[blocks, block_len, kv_heads * hd]``: the kernel
+        against the lax reference (which repeats each key head), and the
+        lax reference against plain attention over the chain."""
+        kp, vp = _pools(w, heads=kv_heads)
+        rows, pos = _ragged_case(w)
+        q = _q(20 + w, S, heads, w, HD)
+        ref = paged_window_attention(q, kp, vp, rows, pos, impl="lax")
+        got = paged_window_attention(q, kp, vp, rows, pos, impl="pallas",
+                                     interpret=True, block_kv=3,
+                                     slots_tile=2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+        if kv_heads == heads:
+            return
+        r = heads // kv_heads
+        wide = lambda pool: jnp.repeat(
+            pool.reshape(NB, BL, kv_heads, HD), r, axis=2) \
+            .reshape(NB, BL, heads * HD)
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(paged_window_attention(
+                q, wide(kp), wide(vp), rows, pos, impl="lax")),
+            rtol=1e-6, atol=1e-6)
+
+    def test_a_window_wider_than_the_kernel_holds_goes_in_sub_windows(
+            self, monkeypatch):
+        """A window past ``max_window`` is attended in equal sub-windows
+        over the same chain: the same rows come back."""
+        import mmlspark_tpu.dl.pallas_paged_attention as paged
+        w, bl = 20, 8
+        kp, vp = _pools(3, bl=bl, heads=1)
+        rows = jnp.asarray([[6, 7, 8, 9, TRASH_BLOCK]], jnp.int32)
+        pos = jnp.asarray([4 * bl - w], jnp.int32)
+        q = _q(31, 1, 4, w, HD)
+        want = paged_window_attention(q, kp, vp, rows, pos, impl="lax")
+        monkeypatch.setattr(paged, "max_window", lambda *a: 8)
+        got = paged_window_attention(q, kp, vp, rows, pos, impl="pallas",
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
     def test_both_forms_of_the_cell_are_driven(self):
         """The cases fall on both sides of the row count up to which
         every head shares one product."""

@@ -221,3 +221,59 @@ def test_a_manager_without_rows_is_the_one_it_was():
     assert mgr.allocate("q", doc).reused_tokens == 8  # the whole prompt
     assert list(mgr.state_rows(["q"])) == [0]
     assert np.asarray(mgr.block_rows(["q"], 3)).shape == (1, 3)
+
+
+# ------------------------------------------- two arrays a sequence a layer
+#: a layer that keeps TWO per-sequence arrays of different shape and type
+#: (a float32 state and a bfloat16 convolution tail), beside a layer of
+#: chained keys and values
+TWO = ((((2, 4, 4), "float32", "seq"), ((3, 16), "bfloat16", "seq")),
+       (((8,), "float32"), ((8,), "float32")),
+       (((2, 4, 4), "float32", "seq"), ((3, 16), "bfloat16", "seq")))
+
+
+def test_two_arrays_a_sequence_are_one_row_of_the_budget():
+    """A row is every ``"seq"`` array of every layer: priced together,
+    allocated together (one pool an entry, the same row in each), and a
+    block is priced without them."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.paged_kv import copy_state_rows
+    row = 2 * (2 * 4 * 4 * 4 + 3 * 16 * 2)
+    assert state_row_bytes(TWO) == row
+    assert pool_block_bytes(TWO, BL) == pool_block_bytes((TWO[1],), BL)
+    pools = init_pools(TWO, 5, BL, state_slots=3)
+    assert [p.shape for p in pools[0]] == [(4, 2, 4, 4), (4, 3, 16)]
+    assert [str(p.dtype) for p in pools[2]] == ["float32", "bfloat16"]
+    assert [p.shape for p in pools[1]] == [(5, 4, 8), (5, 4, 8)]
+    # a snapshot taken, a snapshot restored: both arrays of both layers
+    marked = tuple(tuple(p.at[1].set(5) for p in layer) if i != 1 else layer
+                   for i, layer in enumerate(pools))
+    out = copy_state_rows(TWO, marked, jnp.asarray([1]), jnp.asarray([3]))
+    for i in (0, 2):
+        for p in out[i]:
+            assert float(p[3].min()) == 5.0 and float(p[2].max()) == 0.0
+    assert out[1][0] is marked[1][0]
+    # the manager's gauge counts a row's bytes whole, live and snapshot
+    reg = MetricsRegistry()
+    blocks = -(-row // pool_block_bytes(TWO, BL))
+    mgr = PagedKVManager(12, BL, state_slots=3, state_row_bytes=row,
+                         state_row_blocks=blocks, registry=reg, service="t")
+    assert mgr.block_budget == 11 + 3 * blocks
+    doc = list(range(1, 9))
+    h, snap = _prefill(mgr, "doc", doc)
+    assert snap is not None and snap != h.state_row
+    assert _value(reg, "kv_state_bytes") == 2 * row
+    mgr.release("doc")
+    assert _value(reg, "kv_state_bytes") == row       # the snapshot stays
+    # a prefix hit names the snapshot's row to restore from, pinned
+    h2 = mgr.allocate("q", doc + [9, 10])
+    assert h2.reused_tokens == 8 and h2.restore_row == snap
+    mgr.restored("q")
+    assert _value(reg, "kv_state_restores_total") == 1
+    # recycled under the rows' pressure: then the prefix is a miss
+    mgr.allocate("a", [21, 22, 23])
+    mgr.allocate("b", [31, 32, 33])                   # takes the snapshot's
+    assert _value(reg, "kv_state_snapshot_evictions_total") == 1
+    mgr.release("q")
+    assert mgr.allocate("q2", doc + [9]).reused_tokens == 0
