@@ -365,9 +365,12 @@ class ContinuousGenerator:
             self._active[a.slot] = True
         if not self._active.any():
             return []
+        # a copy: ``_active`` changes below while the program may not have
+        # read it yet (off the chip an uploaded array can share the host's
+        # memory)
         self._buf, self._ptr = self._run(
             self.variables["params"], self._buf, self._ptr,
-            jnp.asarray(self._active), self._key, self._step_idx)
+            jnp.asarray(self._active.copy()), self._key, self._step_idx)
         self._step_idx += 1
         done = []
         for seq_id, slot in self.sched.step():

@@ -98,11 +98,31 @@ whole chunks is cut at its last one, where its state is copied into a
 snapshot row that ``publish`` indexes with the blocks. Speculation is refused
 beside such a decoder: a state cannot be rewound.
 
-Obs: every boundary is an ``llm.step`` span on the tracer's ring with
+One boundary ahead: a plain step commits one token a runnable row
+whatever the token is, so while enough rows decode for a window to ride
+with them (and a device runs beside the host) the engine dispatches the
+NEXT boundary's program before it fetches the last one's tokens. The
+sampled tokens stay on the device between the two — every step program
+takes the picks of the one before it and an index a row, ``last =
+picks[src]``, or the host's ``last`` where the token is home — and what
+the host does a boundary (commit, handoff, finish, the caller's refill,
+admission, allocation, the window, the block tables) runs under a program
+instead of between two (:class:`DecodeExecutor`, :meth:`LLMEngine.step`).
+A token, a first token and a finished sequence are counted when they are
+home. A speculative step, fewer decoding rows than a window rides with,
+a decoder of one window a walk and the CPU backend keep every fetch at
+its own boundary: no setting chooses.
+
+Obs: every boundary is an ``llm.step`` span on the tracer's ring (its
+``ahead`` attribute: the program went out before the fetch of the one
+before it; ``gen_steps_ahead_total`` counts those beside
+``gen_decode_steps_total``) with
 ``llm.prefill`` (the prefill programs of a boundary with nothing to ride
 with; the host's part of a riding window, whose real rows the root says
 as ``ride_rows``) and ``llm.decode`` (the block tables, the step's one
-program and its fetch) children; a decoder's walk counts
+program and the boundary's fetch, which is an ``llm.fetch`` span of its
+own: what the host waits there is what the device still had to do, the
+host's slack) children; a decoder's walk counts
 land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
@@ -199,6 +219,16 @@ def _donate_pools_kwargs() -> dict:
     if target_platform() == "tpu":
         return {"donate_argnums": (2, 3)}
     return {}
+
+
+def _device_beside_host() -> bool:
+    """Whether a program runs on a processor of its own while the host
+    goes on: an accelerator. On the CPU backend it takes the host's own
+    cores, so there is nothing to hide the host's work under, and the
+    engine keeps every fetch at the boundary that dispatched its program
+    (:meth:`LLMEngine.step`)."""
+    from ..utils.platform import target_platform
+    return target_platform() != "cpu"
 
 
 def _greedy(logits, pad_id: int):
@@ -302,14 +332,19 @@ class _Programs:
     prefill window RIDING in it, so that the weights are read once a
     boundary (the head comes free: decode reads it anyway). Every program
     takes ``(params, draft params, pools, draft pools, dec, win)``:
-    ``dec`` the decode rows' ``(rows, last, ptr, end, active[, state
-    rows])`` or ``()``, ``win`` the window's ``(rows, toks, pos, lens[,
-    state rows])`` or ``()``; it returns the pools and a dict of what it
-    made (``committed``/``n_new``/``n_acc`` of the decode rows, ``first``
-    of the window's prompts, the walk's ``counts``). With a draft model a
-    prefill window also fills the DRAFT pools, and the decode step
-    (``spec_k`` > 0) is ``dl.speculative``'s draft/verify per slot, which
-    no window rides in."""
+    ``dec`` the decode rows' ``(rows, last, ptr, end, active, prev,
+    src[, state rows])`` or ``()`` — a row's input token is ``last`` where
+    it is on the host (``src`` < 0) and ``prev[src]`` where it is a pick
+    of the program before this one, which may still be running — ``win``
+    the window's ``(rows, toks, pos, lens[, state rows])`` or ``()``; it
+    returns the pools and a dict of what it made: ``tok``, every pick
+    (``[S + P]`` of a step: the decoding rows', one token a runnable row,
+    then the window's prompts'; ``[P]`` of a prefill alone), and the
+    walk's ``counts``. With a draft model a prefill window also fills the
+    DRAFT pools, and the decode step (``spec_k`` > 0) is
+    ``dl.speculative``'s draft/verify per slot, which no window rides in
+    and which takes no ``prev`` / ``src`` (what it commits,
+    ``committed``/``n_new``/``n_acc``, is known from its fetch alone)."""
 
     def __init__(self, module, variables, kv: PagedKVManager,
                  pools: _PoolState, *, draft_module=None,
@@ -382,12 +417,16 @@ class _Programs:
     def _walk_program(self, decode: bool, w: int | None, head: bool):
         import jax.numpy as jnp
         module, draft = self.module, self.draft_module
-        pad_id, S = self.pad_id, self.slots
+        pad_id, S, P = self.pad_id, self.slots, self.batch
 
         def run(params, dparams, pools_t, pools_d, dec, win):
             windows = []
             if decode:
-                rows, last, ptr, _, active, *srows = dec
+                rows, last, ptr, _, active, prev, src, *srows = dec
+                # a row's input token, where it lies: at ``src`` among the
+                # picks of the program before this one, which may still
+                # be running, or on the host (``src`` < 0: ``last``)
+                last = jnp.where(src < 0, last, prev[jnp.maximum(src, 0)])
                 windows.append((last[:, None], rows, ptr - 1,
                                 active[:, None], *srows))
             if w is not None:
@@ -413,12 +452,11 @@ class _Programs:
             tok = _greedy(module.apply(
                 {"params": params}, jnp.concatenate(picked),
                 method="logits"), pad_id)
-            if decode:
-                n_new = jnp.where(active, 1, 0)
-                out.update(committed=tok[:S, None], n_new=n_new,
-                           n_acc=n_new)
-            if w is not None:
-                out["first"] = tok[S:] if decode else tok
+            # every pick, one shape whatever rode: the decoding rows'
+            # ``[:S]`` (one token a runnable row, known before any fetch),
+            # then the window's prompts'
+            out["tok"] = jnp.pad(tok, (0, S + P - len(tok))) if decode \
+                else tok
             return pools_t, pools_d, out
 
         return run
@@ -506,6 +544,16 @@ class _Programs:
             self.pools.draft = pools_d
         return out
 
+    def tokens_at_home(self) -> tuple:
+        """The walk program's ``(prev, src)`` where every row's token is on
+        the host: no pick to read, ``src`` -1 a row. The speculative step
+        takes neither."""
+        import jax.numpy as jnp
+        if self.spec_k:
+            return ()
+        return (jnp.zeros(self.slots + self.batch, jnp.int32),
+                jnp.full(self.slots, -1, jnp.int32))
+
     def blank(self, decode: bool, w: int | None) -> tuple:
         """``(dec, win)`` of a call that touches the trash block alone:
         every decode row inactive, every window row of length 0."""
@@ -514,7 +562,7 @@ class _Programs:
         state = bool(self.kv.state_slots)
         dec = (jnp.zeros((S, MB), jnp.int32), jnp.zeros(S, jnp.int32),
                jnp.ones(S, jnp.int32), jnp.full(S, 2, jnp.int32),
-               jnp.zeros(S, bool),
+               jnp.zeros(S, bool), *self.tokens_at_home(),
                *((jnp.zeros(S, jnp.int32),) if state else ())) \
             if decode else ()
         win = (jnp.zeros((P, MB), jnp.int32), jnp.zeros((P, w), jnp.int32),
@@ -648,13 +696,21 @@ class PrefillExecutor:
         #: rows up at the benchmark's sizes (PERF.md section 6, PR 34)
         self.ride_from = 8
         self._queue: deque = deque()    # allocated, not wholly fed: FIFO
-        self._landed: dict = {}
         self.rode = 0                   # rows of the last riding window
 
     @property
     def waiting(self) -> int:
         """Prompts whose chains are allocated and not wholly fed."""
         return len(self._queue)
+
+    @property
+    def rides(self) -> bool:
+        """Whether enough rows decode at this boundary for a window to
+        ride with them — and for the host's work to hide under their
+        program (:meth:`LLMEngine.step` runs one boundary ahead by the
+        same rule)."""
+        rider = self.rider
+        return rider is not None and rider.runnable.sum() >= self.ride_from
 
     # -- host driver --------------------------------------------------------
     def _chunks(self, n: int) -> list:
@@ -762,13 +818,15 @@ class PrefillExecutor:
                           head="row" if ends else "none")
         self._c_rows.inc(rows, service=self.service, ride=ride)
 
-    def _commit(self, feed: _Feed, first: int) -> tuple:
-        """A prompt is wholly in: commit its length, index its new blocks
-        (and its snapshot) for reuse."""
+    def _commit(self, feed: _Feed) -> int:
+        """A prompt is wholly in (the program that holds its last row is
+        dispatched; nothing here waits for a token): commit its length,
+        index its new blocks (and its snapshot) for reuse. Returns the
+        rows it was fed."""
         h = self.kv.handle(feed.seq_id)
         self.kv.advance(feed.seq_id, h.prompt_len - h.length)
         self.kv.publish(feed.seq_id)
-        return int(first), len(feed.prompt) - feed.fed_from
+        return len(feed.prompt) - feed.fed_from
 
     def prefill(self, jobs: list) -> dict:
         """``jobs``: list of ``(seq_id, prompt_tokens)`` whose chains
@@ -777,8 +835,9 @@ class PrefillExecutor:
         prompts that ran to their end ALONE in this call. While slots
         decode nothing runs alone: the next window of the oldest ``batch``
         prompts is left with the decode executor, whose step takes it
-        through its one program, and the prompts that end in it come back
-        from :meth:`landed` after that step's fetch. The prompts behind
+        through its one program; the prompts that end in it are that
+        step's ``landed`` once the program is dispatched, and their first
+        tokens its ``firsts`` once it is fetched. The prompts behind
         them wait their turn, a window a boundary.
 
         With per-sequence cache arrays a feed that reuses a prefix first
@@ -789,14 +848,13 @@ class PrefillExecutor:
         snapshot row before the rest is fed."""
         self._queue.extend(self._feed(*job) for job in jobs)
         self.rode = 0
-        rider = self.rider
-        if rider is not None and rider.runnable.sum() >= self.ride_from:
+        if self.rides:
             # the prompts beyond the window wait their turn, a window a
             # boundary: a waiting prompt idles ONE slot, a prefill program
             # of its own would hold every decoding slot (chip sweep,
             # PERF.md section 6, PR 34)
             if self._queue:
-                self._ride(rider)
+                self._ride(self.rider)
             return {}
         out: dict = {}
         while self._queue:
@@ -818,21 +876,18 @@ class PrefillExecutor:
         for _, feed in ended:
             self._queue.remove(feed)
 
-        def land(first) -> None:
-            """After the step's fetch: the first tokens are here, and a
-            feed's cut falls between this boundary's program and the
-            next one's."""
+        def land() -> dict:
+            """After the step's dispatch: a feed's cut falls between this
+            boundary's program and the next one's, and the prompts that
+            ended in the window are in. Their first tokens are picks of
+            the program, wherever it is by now: ``seq_id -> (row among
+            the picks, rows fed)``."""
             self._snapshot(feeds)
-            for i, feed in ended:
-                self._landed[feed.seq_id] = self._commit(feed, first[i])
+            return {feed.seq_id: (self.programs.slots + i,
+                                  self._commit(feed))
+                    for i, feed in ended}
 
         rider.riding = (win, land)
-
-    def landed(self) -> dict:
-        """``seq_id -> (first_token, suffix_len)`` of the prompts whose
-        last row rode in a decode step since the last call."""
-        out, self._landed = self._landed, {}
-        return out
 
     def _alone(self, feeds: list) -> dict:
         """A batch of prompts to their ends, in step with each other: a
@@ -857,7 +912,7 @@ class PrefillExecutor:
                 if self.programs.walk_stats:
                     counts.append(made["counts"])
                 for i in ends:
-                    firsts[i] = made["first"]
+                    firsts[i] = made["tok"]
 
         feed_while(lambda f: f.at < f.cut)
         self._snapshot(feeds)
@@ -865,7 +920,7 @@ class PrefillExecutor:
         firsts, counts = jax.device_get((firsts, counts))
         for count in counts:
             self.programs.walk_stats.record(count)
-        return {f.seq_id: self._commit(f, firsts[i][i])
+        return {f.seq_id: (int(firsts[i][i]), self._commit(f))
                 for i, f in enumerate(feeds)}
 
     def warm(self, windows=(1,)) -> None:
@@ -891,6 +946,14 @@ class PrefillExecutor:
             self.programs.warm(*key)
 
 
+@dataclass(eq=False)
+class _Flight:
+    """A step's program between its dispatch and its fetch."""
+    made: dict              # what the program made, on the device
+    slots: np.ndarray       # the slots whose rows it decoded
+    landed: dict            # seq_id -> (pick row, rows fed): prompts it ended
+
+
 class DecodeExecutor:
     """The fixed-shape continuous-batching decode step over block
     tables. All shapes are pinned at construction — ``[slots]`` state
@@ -905,13 +968,29 @@ class DecodeExecutor:
     numerics of ``dl.generate``'s cached path with zero dense
     gathers. A prefill window the :class:`PrefillExecutor` leaves
     (``riding``) goes through the same walk and the same head call, and
-    :meth:`step`'s one fetch brings its prompts' first tokens back with
+    the program's one fetch brings its prompts' first tokens back with
     the slots'. Spec mode (draft present): ``dl.speculative``'s
     draft/verify runs as k width-1 draft walks plus one width-(k+1)
     target walk (the kernel's windowed variant) whose ``k + 1`` rows
     all get logits; each slot accepts its
     own longest agreeing prefix — no batch sync-on-min, block chains
-    advance independently."""
+    advance independently.
+
+    ONE BOUNDARY AHEAD. A plain step commits one token a runnable row
+    whatever the token is, so all a step's bookkeeping but the tokens'
+    values is done at DISPATCH (``ptr``, the chains' lengths, who is
+    runnable next, a riding window's snapshot, ``publish`` and the slot
+    of the prompt that ended in it), and the next program reads its input
+    tokens where the last one left them: ``src[s]`` is the row among the
+    picks of the program in flight (``flying``) that slot ``s`` takes —
+    its own ``s``, or ``S + i`` of the prompt that rode in window row
+    ``i`` — and -1 once the token is home in ``last``. With ``ahead`` set
+    (the engine says so a boundary: enough rows decode to hide the host
+    under), :meth:`step` dispatches its program and fetches the one
+    BEFORE it, so the device is never without work while the host commits,
+    hands off, finishes, admits and builds the next tables; otherwise it
+    fetches its own, as ever. Tokens, first tokens and finished sequences
+    are counted when they are home, never at dispatch."""
 
     def __init__(self, programs: _Programs):
         self.programs = programs
@@ -925,22 +1004,39 @@ class DecodeExecutor:
         self.seq_ids: list = [None] * self.slots
         self.ptr = np.ones(self.slots, np.int32)   # committed tokens
         self.end = np.ones(self.slots, np.int32)   # commit cap
-        self.last = np.zeros(self.slots, np.int32)  # token @ ptr-1
+        self.last = np.zeros(self.slots, np.int32)  # token @ ptr-1, at home
         self.active = np.zeros(self.slots, bool)
+        #: where each slot's token @ ptr-1 lies among the picks of the
+        #: program in flight; -1: at home, in ``last``
+        self.src = np.full(self.slots, -1, np.int32)
+        #: the program dispatched and not fetched
+        self.flying: _Flight | None = None
+        #: whether :meth:`step` leaves its program in flight and fetches
+        #: the one before it (the engine sets it, a boundary at a time)
+        self.ahead = False
         #: ``(window, land)`` the prefill executor left for the next step:
         #: the window goes through the step's program with the decoding
-        #: rows, ``land`` takes its prompts' first tokens from the fetch
+        #: rows, ``land`` is called once that program is dispatched
         self.riding: tuple | None = None
+        #: of the last :meth:`step` / :meth:`fetch`: the prompts the
+        #: dispatched window ended (``seq_id -> (pick row, rows fed)``)
+        #: and the first tokens that came home (``seq_id -> token``)
+        self.landed: dict = {}
+        self.firsts: dict = {}
+        self._at_home = programs.tokens_at_home()
 
     @property
     def free_slots(self) -> int:
         return int(self.slots - self.active.sum())
 
     # -- slot lifecycle -----------------------------------------------------
-    def activate(self, slot_hint, state: dict) -> int:
+    def activate(self, slot_hint, state: dict,
+                 first_row: int | None = None) -> int:
         """Adopt a handoff payload into a free slot. ``slot_hint`` (the
         scheduler's assignment) is used when free; any free slot
-        otherwise."""
+        otherwise. ``first_row``: the payload's first token is not home
+        yet (``first`` None; such a payload never went over the wire) and
+        lies at this row among the picks of the program in flight."""
         slot = slot_hint if (slot_hint is not None
                              and not self.active[slot_hint]) else \
             int(np.flatnonzero(~self.active)[0])
@@ -950,7 +1046,10 @@ class DecodeExecutor:
         # prefill) is committed at position prompt_len, pending embed
         self.ptr[slot] = handle.length + 1
         self.end[slot] = handle.length + int(state["max_new_tokens"])
-        self.last[slot] = int(state["first"])
+        if first_row is None:
+            self.last[slot] = int(state["first"])
+        else:
+            self.src[slot] = int(first_row)
         self.active[slot] = True
         return slot
 
@@ -960,6 +1059,7 @@ class DecodeExecutor:
         self.ptr[slot] = 1
         self.end[slot] = 1
         self.last[slot] = self.pad_id
+        self.src[slot] = -1
 
     @property
     def runnable(self) -> np.ndarray:
@@ -972,43 +1072,93 @@ class DecodeExecutor:
     def step(self) -> dict:
         """One decode step over every runnable slot, ONE program: the
         decode rows and, riding with them, the prefill window the
-        :class:`PrefillExecutor` left (``riding``). Returns ``slot ->
-        (tokens_committed list, n_accepted)``; the caller commits
-        tokens, advances the block table, and retires finished
-        sequences."""
-        import jax
+        :class:`PrefillExecutor` left (``riding``). Dispatches it, then
+        fetches — with ``ahead`` the program dispatched BEFORE it, which
+        this one followed onto the device, else its own — and returns
+        what came home, ``slot -> (tokens_committed list, n_accepted)``;
+        the caller commits the tokens and retires finished sequences."""
         import jax.numpy as jnp
         runnable = self.runnable
         (win, land), self.riding = self.riding or ((), None), None
-        if not runnable.any():
-            return {}
-        # capacity for this step's writes: positions up to ptr-1+k
-        for s in range(self.slots):
-            if runnable[s]:
+        before = self.flying
+        self.landed = {}
+        if runnable.any():
+            slots = np.flatnonzero(runnable)
+            # capacity for this step's writes: positions up to ptr-1+k
+            for s in slots:
                 self.kv.ensure_capacity(self.seq_ids[s],
                                         int(self.ptr[s]) + self.spec_k)
-        running = [sid if runnable[i] else None
-                   for i, sid in enumerate(self.seq_ids)]
-        dec = (jnp.asarray(self.kv.block_rows(running, self.max_blocks)),
-               jnp.asarray(self.last), jnp.asarray(self.ptr),
-               jnp.asarray(self.end), jnp.asarray(runnable),
-               *((jnp.asarray(self.kv.state_rows(running)),)
-                 if self.kv.state_slots else ()))
-        # ONE fetch a step: the tokens, a riding window's first tokens
-        # and the walk's counts together
-        made = jax.device_get(self.programs.call(dec, win))
+            running = [sid if runnable[i] else None
+                       for i, sid in enumerate(self.seq_ids)]
+            # copies: the slot state moves on below while the program may
+            # not have read its arguments yet (off the chip an uploaded
+            # array can share the host's memory)
+            picks = () if self.spec_k else (
+                before.made["tok"] if before is not None
+                else self._at_home[0], jnp.asarray(self.src.copy()))
+            dec = (jnp.asarray(self.kv.block_rows(running, self.max_blocks)),
+                   jnp.asarray(self.last.copy()),
+                   jnp.asarray(self.ptr.copy()),
+                   jnp.asarray(self.end.copy()), jnp.asarray(runnable),
+                   *picks,
+                   *((jnp.asarray(self.kv.state_rows(running)),)
+                     if self.kv.state_slots else ()))
+            made = self.programs.call(dec, win)
+            if not self.spec_k:
+                # one token a row, whatever it is: it lies at the row's
+                # own pick until it is home
+                self._advance(slots, 1)
+                self.src[slots] = slots
+            self.landed = land() if land is not None else {}
+            self.flying = _Flight(made, slots, self.landed)
+        if self.ahead:
+            return self._fetch(before)
+        assert before is None, "fetch() what flies before a step that waits"
+        return self.fetch()
+
+    def _advance(self, slots, n) -> None:
+        for s, k in zip(slots, np.broadcast_to(n, len(slots))):
+            self.kv.advance(self.seq_ids[s], int(k))
+            self.ptr[s] += int(k)
+
+    def fetch(self) -> dict:
+        """Bring the program in flight home: its tokens as :meth:`step`
+        returns them, nothing where none flies."""
+        return self._fetch(self.flying)
+
+    def _fetch(self, flight: _Flight | None) -> dict:
+        """ONE fetch a program: the tokens, a riding window's first
+        tokens and the walk's counts together (``llm.fetch``: what the
+        host waits is what the device still had to do)."""
+        import jax
+        self.firsts = {}
+        if flight is None:
+            return {}
+        with _tracer.span("llm.fetch"):
+            made = jax.device_get(flight.made)
+        if flight is self.flying:
+            # nothing flies from here on: EVERY token comes home, that of
+            # a slot handed a prompt this program ended too (it did not
+            # decode in it, so it is in no ``out`` below, and the next
+            # program reads it from ``last``)
+            self.flying = None
+            away = self.src >= 0
+            if away.any():
+                self.last[away] = made["tok"][self.src[away]]
+                self.src[:] = -1
         if self.programs.walk_stats:
             self.programs.walk_stats.record(made["counts"])
-        if land is not None:
-            land(made["first"])
-        out = {}
-        for s in np.flatnonzero(runnable):
-            n = int(made["n_new"][s])
-            toks = [int(t) for t in made["committed"][s, :n]]
-            self.kv.advance(self.seq_ids[s], n)
-            self.ptr[s] += n
+        if self.spec_k:
+            self._advance(flight.slots, made["n_new"][flight.slots])
+            out = {int(s): ([int(t) for t in
+                             made["committed"][s, :made["n_new"][s]]],
+                            int(made["n_acc"][s])) for s in flight.slots}
+        else:
+            out = {int(s): ([int(made["tok"][s])], 1) for s in flight.slots}
+            self.firsts = {seq_id: int(made["tok"][row])
+                           for seq_id, (row, _) in flight.landed.items()}
+        for s, (toks, _) in out.items():
             self.last[s] = toks[-1]
-            out[int(s)] = (toks, int(made["n_acc"][s]))
         return out
 
     def warm(self) -> None:
@@ -1027,7 +1177,8 @@ class _SeqMeta:
     t_submit: float
     slot: int | None = None
     t_first: float | None = None
-    first_token: int | None = None
+    first_token: int | None = None     # None until it is home
+    handed: bool = False                # to its decode slot
     reused_tokens: int = 0
     prefill_tokens: int = 0
     decode_steps: int = 0
@@ -1123,8 +1274,13 @@ class LLMEngine:
                 getattr(module, "several_windows", False):
             self.prefiller.rider = self.decoder
         self.handoff = HandoffQueue()
+        # one boundary ahead only where a device runs beside the host
+        self._beside = _device_beside_host()
         self._meta: dict = {}
         self._to_prefill: list = []
+        #: prompts wholly in whose first token still flies and that no
+        #: slot has taken: ``seq_id -> pick row``, oldest first
+        self._landing: dict = {}
         self._first_credit: dict = {}
         self._done: dict = {}
         self.expired: list = []
@@ -1144,6 +1300,10 @@ class LLMEngine:
             "for the benchmark's reader until ROADMAP Q1 drops it")
         self._c_steps = reg.counter(
             "gen_decode_steps_total", "decode steps executed, by service")
+        self._c_ahead = reg.counter(
+            "gen_steps_ahead_total",
+            "decode steps whose program was dispatched before the tokens "
+            "of the step before were fetched, by service")
         self._g_accept = reg.gauge(
             "gen_spec_accept_ratio",
             "rolling fraction of offered draft tokens accepted, "
@@ -1175,19 +1335,38 @@ class LLMEngine:
     # -- one step boundary --------------------------------------------------
     def step(self) -> list:
         """One boundary: admit, allocate the admitted prompts' chains,
-        ONE program — the decoding rows and, riding with them, the next
-        prefill window of the prompts that wait — one fetch, and the
-        handoff of the prompts whose last row was in the window to their
-        slots (they decode from the next boundary). Where no slot
-        decodes, beside a speculative step, or with a decoder that takes
-        one window a walk, the order is prefill (every window of every
-        ready prompt, alone) → handoff → decode. Returns ``(seq_id,
-        tokens)`` pairs (full sequence: prompt then generated) finished
-        at this boundary."""
+        dispatch ONE program — the decoding rows and, riding with them,
+        the next prefill window of the prompts that wait — fetch, commit
+        what the fetch brought, and hand the prompts whose last row was
+        in the window to their slots (they decode from the next boundary).
+
+        While enough rows decode for a window to ride with them
+        (``prefiller.rides``) the engine runs ONE BOUNDARY AHEAD: the
+        fetch is of the program dispatched at the boundary BEFORE, which
+        this boundary's program followed onto the device, so that
+        everything the host does — this commit, the handoffs, the
+        finishes, the caller's refill, the next boundary's admission,
+        allocation, window and block tables — happens under a running
+        program (:class:`DecodeExecutor`). Where no slot decodes or too
+        few, beside a speculative step, or with a decoder that takes one
+        window a walk, the order is prefill (every window of every ready
+        prompt, alone) → handoff → decode → its own fetch, after whatever
+        still flew has come home.
+
+        Returns ``(seq_id, tokens)`` pairs (full sequence: prompt then
+        generated) of the sequences whose last token has come HOME at
+        this boundary — one boundary after the program that made it,
+        while the engine runs ahead. A token counts as served
+        (``gen_tokens_total``, ``gen_ttft_seconds``) when it is home."""
         with _tracer.span("llm.step") as root:
             return self._step(root)
 
     def _step(self, root) -> list:
+        ahead = self._beside and self.prefiller.rides
+        # a boundary that waits for its own program first brings home
+        # the one that still flies
+        finished = self._settle(self.decoder.fetch()) \
+            if not ahead and self.decoder.flying is not None else []
         for a in self.sched.admit():
             self._to_prefill.append(a)
         for seq_id in self.sched.drain_expired():
@@ -1198,15 +1377,34 @@ class LLMEngine:
             # the host's part of a riding window; every window, every
             # program and the fetch of a prefill alone
             with _tracer.span("llm.prefill", parent=root):
-                self._hand_off(self.prefiller.prefill(jobs))
+                alone = self.prefiller.prefill(jobs)
+                for seq_id, (first, _) in alone.items():
+                    self._first_token(seq_id, first)
+                self._hand_off({seq_id: (None, rows)
+                                for seq_id, (_, rows) in alone.items()})
             root.set_attr("ride_rows", self.prefiller.rode)
-        finished = []
+        # a program goes out wherever a slot is runnable: ahead of the
+        # fetch of the one that still flies
+        stepping = bool(self.decoder.runnable.any())
+        flew = stepping and self.decoder.flying is not None
+        root.set_attr("ahead", flew)
+        self.decoder.ahead = ahead
         with _tracer.span("llm.decode", parent=root):
             results = self.decoder.step()
-        # the prompts whose last row rode in the step's program
-        self._hand_off(self.prefiller.landed())
-        if results:
+        if stepping:
             self._c_steps.inc(1, service=self.service)
+            self._c_ahead.inc(int(flew), service=self.service)
+        return finished + self._settle(results, self.decoder.landed)
+
+    def _settle(self, results: dict, landed: dict | None = None) -> list:
+        """What a fetch brought home — first tokens, then ``slot ->
+        (tokens, n_accepted)`` — committed and counted; the prompts the
+        program just dispatched ended (``landed``) handed to their slots;
+        the sequences that are complete retired. Returns those."""
+        for seq_id, first in self.decoder.firsts.items():
+            self._first_token(seq_id, first)
+        if landed is not None:
+            self._hand_off(landed)
         tokens_by_slot = dict(self._first_credit)
         self._first_credit = {}
         for slot, (toks, n_acc) in results.items():
@@ -1227,6 +1425,7 @@ class LLMEngine:
         if self._spec_acc[1]:
             self._g_accept.set(self._spec_acc[0] / self._spec_acc[1],
                                service=self.service)
+        finished = []
         active = self.sched.active_slots
         if active:
             # sequences still in prefill/handoff hold scheduler slots
@@ -1260,34 +1459,70 @@ class LLMEngine:
         self._to_prefill = still_stalled
         return ready
 
-    def _hand_off(self, firsts: dict) -> None:
-        """Prompts that are wholly in, ``seq_id -> (first_token,
-        suffix_len)``: TTFT, then through the handoff queue to their
-        slots."""
-        now = self.clock()
-        for seq_id, (first, suffix_len) in firsts.items():
+    def _first_token(self, seq_id, first: int) -> None:
+        """A prompt's first token is home: TTFT, and 1 of its slot's
+        budget."""
+        meta = self._meta[seq_id]
+        meta.t_first = self.clock()
+        meta.first_token = int(first)
+        self._h_ttft.observe(
+            meta.t_first - meta.t_submit, service=self.service,
+            reuse="warm" if meta.reused_tokens else "cold")
+        if meta.handed:
+            self._first_credit[meta.slot] = 1
+        elif self._landing.pop(seq_id, None) is not None:
+            # no slot took it while its token flew: by value from here on
+            self.handoff.push(self._payload(seq_id))
+
+    def _payload(self, seq_id) -> dict:
+        meta = self._meta[seq_id]
+        return {"seq": self.kv.export_seq(seq_id),
+                "first": meta.first_token,
+                "max_new_tokens": meta.max_new_tokens}
+
+    def _hand_off(self, ready: dict) -> None:
+        """Prompts that are wholly in, ``seq_id -> (pick row, rows
+        fed)``, to their slots. One whose first token is home goes through
+        the handoff queue, by value. One whose token still flies goes to
+        a free slot by the token's ROW among the picks of the program in
+        flight and never through the queue: a row means something on
+        this process's device alone, and the wire carries tokens. With no
+        slot free it waits here, and goes through the queue once its
+        token is home (:meth:`_first_token`)."""
+        for seq_id, (row, rows) in ready.items():
             meta = self._meta[seq_id]
-            meta.t_first = now
-            meta.prefill_tokens = suffix_len
-            self._h_ttft.observe(
-                now - meta.t_submit, service=self.service,
-                reuse="warm" if meta.reused_tokens else "cold")
-            self.handoff.push({
-                "seq": self.kv.export_seq(seq_id),
-                "first": first,
-                "max_new_tokens": meta.max_new_tokens,
-            })
+            meta.prefill_tokens = rows
+            # home already: a prefill alone, or a window that rode in a
+            # program this boundary waited for
+            if meta.first_token is not None:
+                self.handoff.push(self._payload(seq_id))
+            else:
+                self._landing[seq_id] = row
         for payload in self.handoff.pull(self.decoder.free_slots):
-            meta = self._meta[payload["seq"]["seq_id"]]
-            slot = self.decoder.activate(meta.slot, payload)
-            meta.slot = slot
-            meta.first_token = int(payload["first"])
-            # the prefill-produced first token spends 1 of the slot's
-            # budget; credit it at this boundary's scheduler step
-            self._first_credit[slot] = 1
+            self._activate(payload)
+        # the queue's are older: a landing prompt goes once it is empty
+        while self._landing and self.decoder.free_slots \
+                and not len(self.handoff):
+            seq_id = next(iter(self._landing))
+            self._activate(self._payload(seq_id), self._landing.pop(seq_id))
+
+    def _activate(self, payload: dict, first_row: int | None = None) -> None:
+        meta = self._meta[payload["seq"]["seq_id"]]
+        meta.slot = self.decoder.activate(meta.slot, payload, first_row)
+        meta.handed = True
+        # the prefill-produced first token spends 1 of the slot's budget;
+        # credit it at the scheduler step of the boundary at which it is
+        # home
+        if first_row is None:
+            self._first_credit[meta.slot] = 1
 
     def _finish(self, seq_id) -> np.ndarray:
         meta = self._meta.pop(seq_id)
+        # its blocks and its state row go to whoever is admitted next
+        # while a program may still fly: that one was dispatched after the
+        # program that wrote this sequence's last row, and whatever
+        # touches the blocks again is dispatched after both — the one
+        # stream of the device keeps the order of dispatch
         self.kv.release(seq_id)
         total_len = min(len(meta.prompt) + 1 + len(meta.generated),
                         len(meta.prompt) + meta.max_new_tokens)
@@ -1316,7 +1551,11 @@ class LLMEngine:
         each given length is fed through — its bucket, or
         ``max_window`` chunks plus the remainder's; the decode step)
         and optionally declare CompileTracker steady state. Returns the
-        union of both executors' AOT fingerprints."""
+        union of both executors' AOT fingerprints. A program in flight
+        comes home first (the sequences it finishes come out of the next
+        :meth:`run_until_drained`)."""
+        if self.decoder.flying is not None:
+            self._done.update(self._settle(self.decoder.fetch()))
         self.prefiller.warm(prefill_windows)
         self.decoder.warm()
         if mark_steady:
@@ -1324,8 +1563,9 @@ class LLMEngine:
         return self.programs.aot_fingerprints()
 
     def run_until_drained(self) -> dict:
-        """Step until every submitted sequence completes or expires;
-        returns ``seq_id -> [prompt + generated] int32 array``."""
+        """Step until every submitted sequence completes or expires —
+        nothing flies then: a sequence is complete when its last token is
+        home; returns ``seq_id -> [prompt + generated] int32 array``."""
         stalled = 0
         while self.sched.busy or self._to_prefill or len(self.handoff):
             before = len(self._done)

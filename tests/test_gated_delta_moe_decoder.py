@@ -238,6 +238,43 @@ def test_a_prefix_hit_restores_state_and_tail_riding_or_alone(model, case):
             (order == "ride")
 
 
+@pytest.mark.parametrize("case", sorted(RIDE_CASES))
+def test_one_boundary_ahead_restores_state_and_tail(model, monkeypatch,
+                                                    case):
+    """The same turn while the engine runs ONE BOUNDARY AHEAD (as it does
+    where a device runs beside the host): the restore's copy, the cut's
+    snapshot and the handoff by the first token's row fall between
+    programs whose tokens are not fetched yet, and the tokens are still
+    the cold engine's."""
+    from mmlspark_tpu.serving import llm
+    extra, new = RIDE_CASES[case]
+    rng = np.random.default_rng(21)
+    doc = rng.integers(1, 256, 64).astype(np.int32)
+    other = rng.integers(1, 256, 37).astype(np.int32)
+    ask = np.concatenate([doc, rng.integers(1, 256, extra).astype(np.int32)])
+    cold = _engine(model, MetricsRegistry())
+    cold.submit("q", ask, new)
+    want = cold.run_until_drained()["q"]
+    cold.submit("other", other, 12)
+    want_other = cold.run_until_drained()["other"]
+    monkeypatch.setattr(llm, "_device_beside_host", lambda: True)
+    reg = MetricsRegistry()
+    eng = _engine(model, reg, slots=3, state_slots=6, prefill_batch=1)
+    eng.prefiller.ride_from = 1
+    eng.submit("doc", doc, 1)
+    eng.run_until_drained()
+    eng.submit("other", other, 12)
+    out = dict(eng.step())
+    eng.submit("q", ask, new)
+    out.update(eng.run_until_drained())
+    assert eng.decoder.flying is None
+    np.testing.assert_array_equal(out["q"], want)
+    np.testing.assert_array_equal(out["other"], want_other)
+    assert _counter(reg, "kv_state_restores_total") == 1
+    assert _counter(reg, "gen_steps_ahead_total") >= 3
+    assert _counter(reg, "kv_state_slots_used") == 0
+
+
 def test_without_the_restore_the_tokens_differ(model):
     """The control of the test above: the same turn served from zeros at
     the system prompt's end is not what the cold engine serves, so the

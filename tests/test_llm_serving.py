@@ -544,6 +544,433 @@ def test_a_boundary_is_one_program_that_reads_each_weight_once(lm):
     eng.run_until_drained()
 
 
+# -- one boundary ahead: the next program before the last one's fetch ---------
+
+@pytest.fixture
+def beside(monkeypatch):
+    """The engine as it stands where an accelerator runs beside the
+    host: here the programs take the host's own cores, and the engine
+    would keep every fetch at its own boundary."""
+    from mmlspark_tpu.serving import llm
+    monkeypatch.setattr(llm, "_device_beside_host", lambda: True)
+
+
+@pytest.fixture(scope="module", params=["per_head", "state_a_sequence"])
+def crowd(request, lm):
+    """``(kind, module, variables, reference)`` of a decoder that ten
+    slots decode of: ``MaskedLMModel``, whose streams are
+    ``dl.generate``'s, and the block-sparse / lightning decoder, which
+    keeps a state a SEQUENCE (restored from a snapshot after a prefix
+    hit, copied into one at a cut) and whose streams are those of an
+    engine of one slot that serves each request alone, cold, in today's
+    order. ``reference(prompt, max_new) -> tokens``."""
+    if request.param == "per_head":
+        module, variables = lm
+
+        def reference(prompt, max_new):
+            return np.asarray(generate(
+                module, variables, prompt[None, :], max_new_tokens=max_new,
+                temperature=0.0)[0])[:len(prompt) + max_new]
+
+        return request.param, module, variables, reference
+    from test_sparse_linear_decoder import BL, ref, small_cfg
+    from mmlspark_tpu.dl.sparse_linear_decoder import SparseLinearDecoder
+    cfg = small_cfg()
+    module = SparseLinearDecoder(cfg, dtype=jnp.float32, max_window=24)
+    variables = {"params": ref.make_weights(cfg, 11)}
+    served = {}
+
+    def reference(prompt, max_new):
+        key = (prompt.tobytes(), max_new)
+        if key not in served:
+            alone = LLMEngine(module, variables, slots=1, block_len=BL,
+                              max_seq_len=128, num_blocks=10, state_slots=1,
+                              prefill_batch=1, hbm_fraction=1.0,
+                              service="ahead-alone",
+                              registry=MetricsRegistry())
+            alone.submit("r", prompt, max_new)
+            served[key] = alone.run_until_drained()["r"]
+        return served[key]
+
+    return request.param, module, variables, reference
+
+
+def _crowd_engine(crowd, reg, svc, **kw):
+    """Ten slots and the engine's OWN rule for what rides and what runs
+    ahead (eight rows decoding); blocks of 16, a window of 24 rows at
+    most, so that a prompt of 60 rides in three windows."""
+    kind, module, variables, _ = crowd
+    kw = {"slots": 10, "block_len": 16, "max_seq_len": 128,
+          "num_blocks": 64, "prefill_batch": 2, "hbm_fraction": 1.0, **kw}
+    if kind == "state_a_sequence":
+        kw.setdefault("state_slots", 16)
+    eng = LLMEngine(module, variables, service=svc, registry=reg, **kw)
+    eng.prefiller.max_window = 24
+    assert eng.prefiller.ride_from == 8
+    return eng
+
+
+def _value(reg, name, **labels):
+    return next(m for m in reg.metrics(name) if m.name == name).value(
+        **labels)
+
+
+#: case -> (engine keywords, waves of (prompt length or ("doc", extra
+#: tokens after the 48-token document), new tokens) a boundary apart —
+#: after a first wave of nine requests that decode throughout —, and what
+#: the case is there to hold)
+AHEAD_CASES = {
+    # 60 rows ride in three windows of 24, 24 and 12 while nine rows decode
+    "a_prompt_rides_in_several_windows": ({}, [[(60, 5)]], "rode"),
+    # the document's three blocks are indexed; the question restores its
+    # snapshot, brings a whole block of its own (a cut at 64, where its
+    # snapshot is taken) and five rows more
+    "a_prefix_hit_restores_and_cuts": (
+        {}, [[(("doc", 21), 6)]], "reused"),
+    # finished by the window its last row rode in: no decode row of its own
+    "one_new_token": ({}, [[(19, 1)], [(7, 3)]], "rode"),
+    # 24 blocks: the nine (17 tokens and up: two blocks each from the
+    # start) hold 18; the first of 64 rows takes four and grows into a
+    # fifth, the second waits for the blocks that finished sequences
+    # release, then holds what those held
+    "the_pool_runs_out_then_blocks_are_reused": (
+        {"num_blocks": 25, "crowd_prompt": 17, "slots": 11},
+        [[(64, 4), (64, 4)]],
+        "stalled"),
+}
+
+
+def _serve_crowd(crowd, case, reg, svc, **over):
+    kind, _, _, reference = crowd
+    kw, waves, _ = AHEAD_CASES[case]
+    kw = dict(kw)
+    crowd_prompt = kw.pop("crowd_prompt", 3)
+    rng = np.random.default_rng(53)
+    doc = rng.integers(2, VOCAB, size=48).astype(np.int32)
+
+    def prompt(size):
+        if isinstance(size, tuple):
+            return np.concatenate(
+                [doc, rng.integers(2, VOCAB, size=size[1]).astype(np.int32)])
+        return rng.integers(2, VOCAB, size=size).astype(np.int32)
+
+    eng = _crowd_engine(crowd, reg, svc, **{**kw, **over})
+    eng.submit("doc", doc, 1)
+    served = dict(eng.run_until_drained())
+    sent = {"doc": (doc, 1)}
+    # nine that decode from the second boundary to the end of the case
+    first = [(f"bg{i}", prompt(crowd_prompt + i % 4), 10 + i % 5)
+             for i in range(9)]
+    later = [[(f"w{j}-{i}", prompt(size), new)
+              for i, (size, new) in enumerate(wave)]
+             for j, wave in enumerate(waves)]
+    stalled = 0
+    for wave in [first] + later:
+        for seq_id, p, new in wave:
+            eng.submit(seq_id, p, new)
+            sent[seq_id] = (p, new)
+        served.update(dict(eng.step()))
+        stalled += bool(eng._to_prefill)
+    for _ in range(200):
+        if not eng.sched.busy:
+            break
+        served.update(dict(eng.step()))
+        stalled += bool(eng._to_prefill)
+    assert not eng.sched.busy and eng.decoder.flying is None
+    assert set(served) == set(sent)
+    for seq_id, (p, new) in sent.items():
+        np.testing.assert_array_equal(served[seq_id], reference(p, new),
+                                      err_msg=str(seq_id))
+    assert eng.kv.stats()["sequences"] == 0
+    return eng, stalled
+
+
+@pytest.mark.parametrize("case", sorted(AHEAD_CASES))
+def test_one_boundary_ahead_serves_the_references_tokens(crowd, beside,
+                                                         case):
+    """Ten slots, nine of them decoding throughout, the engine's own rule
+    (eight rows) for what rides and what runs ahead: every stream is the
+    reference's, token for token, over a prompt that rides in several
+    windows, a prefix hit (with its state's restore and a snapshot at the
+    cut), a request of one new token, and a pool that runs out and hands
+    a finished sequence's blocks to the next admission."""
+    kind = crowd[0]
+    reg = MetricsRegistry()
+    svc = f"ahead-{kind}-{case}"
+    eng, stalled = _serve_crowd(crowd, case, reg, svc)
+    steps = _value(reg, "gen_decode_steps_total", service=svc)
+    ahead = _value(reg, "gen_steps_ahead_total", service=svc)
+    # every boundary at which eight or more decode, but the first, ran
+    # ahead of the fetch before it
+    assert 6 <= ahead < steps
+    rode = _value(reg, "gen_prefill_rows_total", service=svc, ride="decode")
+    reused = _value(reg, "kv_prefix_tokens_reused_total", service=svc)
+    holds = AHEAD_CASES[case][2]
+    if holds == "rode":
+        assert rode == sum(size for wave in AHEAD_CASES[case][1]
+                           for size, _ in wave)
+    elif holds == "reused":
+        assert reused == 48 and rode == 21
+        if kind == "state_a_sequence":
+            assert _value(reg, "kv_state_restores_total", service=svc) == 1
+            # the document's, and the question's at its cut
+            assert _value(reg, "kv_state_snapshots", service=svc) >= 2
+    else:
+        assert stalled > 0
+    if kind == "state_a_sequence":
+        assert _value(reg, "kv_state_slots_used", service=svc) == 0
+
+
+def test_the_next_program_is_dispatched_before_the_last_ones_fetch(
+        crowd, beside, monkeypatch):
+    """The order of a boundary, seen from the two calls that touch the
+    device: program ``k + 1`` goes out (``_Programs.call``) BEFORE the
+    tokens of ``k`` are fetched (``jax.device_get`` of what ``k`` made),
+    tokens and first tokens are counted when they are home, and
+    ``run_until_drained`` leaves nothing in flight."""
+    from mmlspark_tpu.serving import llm
+    kind = crowd[0]
+    events = []
+    made_by = {}
+    real_call, real_get = llm._Programs.call, jax.device_get
+
+    def call(self, dec=(), win=(), head=True):
+        out = real_call(self, dec, win, head)
+        if dec:
+            made_by[id(out["tok"])] = len(made_by)
+            events.append(("dispatch", made_by[id(out["tok"])]))
+            call.keep.append(out["tok"])     # ids stay distinct
+        return out
+
+    call.keep = []
+
+    def device_get(tree):
+        if isinstance(tree, dict) and id(tree.get("tok")) in made_by:
+            events.append(("fetch", made_by[id(tree["tok"])]))
+        return real_get(tree)
+
+    monkeypatch.setattr(llm._Programs, "call", call)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    reg = MetricsRegistry()
+    svc = f"order-{kind}"
+    eng, _ = _serve_crowd(crowd, "a_prompt_rides_in_several_windows", reg,
+                          svc)
+    assert eng.decoder.flying is None and not eng.decoder.src.max() >= 0
+    order = {e: i for i, e in enumerate(events)}
+    n = len(made_by)
+    assert all(("fetch", k) in order for k in range(n))     # all came home
+    ahead = [k for k in range(1, n)
+             if order["dispatch", k] < order["fetch", k - 1]]
+    assert len(ahead) == _value(reg, "gen_steps_ahead_total", service=svc)
+    assert len(ahead) >= 6
+    # a fetch is never of a program after one still out, and at most one
+    # program is out beyond the one being fetched
+    for k in range(n):
+        assert order["dispatch", k] < order["fetch", k]
+        if k + 2 < n:
+            assert order["fetch", k] < order["dispatch", k + 2]
+    spans = [s for s in llm._tracer.recent() if s.name == "llm.fetch"]
+    assert spans
+    roots = [s for s in llm._tracer.recent() if s.name == "llm.step"
+             and s.attrs.get("ahead")]
+    assert roots
+
+
+def test_tokens_count_when_they_are_home_not_at_dispatch(crowd, beside):
+    """Between two boundaries that run ahead the engine's counters hold
+    what has been FETCHED: the program in flight has moved ``ptr`` (the
+    driver's record of rows reads it) and no token of it is counted."""
+    kind, _, _, reference = crowd
+    reg = MetricsRegistry()
+    svc = f"home-{kind}"
+    eng = _crowd_engine(crowd, reg, svc)
+    rng = np.random.default_rng(59)
+    prompts = [rng.integers(2, VOCAB, size=4).astype(np.int32)
+               for _ in range(9)]
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, 12)
+    eng.step()                  # nothing decoded yet: prefill alone, then
+    assert eng.decoder.flying is None       # a step that waits for itself
+    assert _value(reg, "gen_tokens_total", service=svc) == 9
+    eng.step()                  # nine decode: this program stays in flight
+    assert eng.decoder.flying is not None
+    assert _value(reg, "gen_tokens_total", service=svc) == 9
+    assert _value(reg, "gen_steps_ahead_total", service=svc) == 0
+    assert (eng.decoder.ptr[:9] == 4 + 3).all()        # moved at dispatch
+    assert (eng.decoder.src[:9] == np.arange(9)).all()  # tokens not home
+    eng.step()                  # the next goes out, then the first's fetch
+    assert _value(reg, "gen_tokens_total", service=svc) == 18
+    assert _value(reg, "gen_steps_ahead_total", service=svc) == 1
+    assert (eng.decoder.ptr[:9] == 4 + 4).all()
+    got = eng.run_until_drained()
+    assert eng.decoder.flying is None
+    assert _value(reg, "gen_tokens_total", service=svc) == 9 * 11
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(got[i], reference(p, 12))
+
+
+def test_running_ahead_compiles_no_program_of_its_own(lm, beside):
+    """The program that reads its tokens from the picks of the one before
+    it IS the step's program: a warmed engine serves a crowd one boundary
+    ahead, the picks now a host-made array and now a program's output,
+    with no compile, and ``warm`` built no program more than it did."""
+    module, variables = lm
+    reg = MetricsRegistry()
+    eng = LLMEngine(module, variables, slots=10, block_len=4,
+                    max_seq_len=32, prefill_batch=2, service="aheadwarm",
+                    registry=reg)
+    eng.prefiller.max_window = 8
+    fps = eng.warm(prefill_windows=(3, 21), mark_steady=True)
+    try:
+        prompts = _prompts(seed=71, sizes=(3, 5, 2, 6, 4, 3, 5, 2, 6))
+        for i, p in enumerate(prompts):
+            eng.submit(i, p, 10)
+        eng.step()
+        eng.step()
+        late = _prompts(seed=73, sizes=(21,))[0]
+        eng.submit("late", late, 4)
+        got = eng.run_until_drained()
+        compile_tracker.assert_steady_state()
+    finally:
+        compile_tracker.unmark_steady()
+    assert _value(reg, "gen_steps_ahead_total", service="aheadwarm") >= 6
+    assert set(fps) == {
+        "llm_decode_paged_aheadwarm_S10_k0", "llm_prefill_aheadwarm_w4_b2",
+        "llm_prefill_aheadwarm_w8_b2", "llm_prefill_aheadwarm_w8_b2_nohead",
+        "llm_step_aheadwarm_S10_w8_b2"}
+    ref = _ref(lm, prompts + [late], max_new=10)
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(got[i], ref[i][:len(p) + 10])
+    np.testing.assert_array_equal(got["late"], ref[9][:len(late) + 4])
+
+
+@pytest.mark.parametrize("regime", ["speculative", "under_ride_from",
+                                    "decoder_of_one_window",
+                                    "no_device_beside_the_host"])
+def test_what_keeps_the_synchronous_order(lm, draft_lm, monkeypatch,
+                                          regime):
+    """A speculative step (what it commits is known from its fetch),
+    fewer rows decoding than a window rides with, a decoder whose walk
+    takes one window, and a backend whose programs take the host's own
+    cores (this one, left as it is): every program is fetched at the
+    boundary that dispatched it, and the counter stays 0."""
+    if regime != "no_device_beside_the_host":
+        from mmlspark_tpu.serving import llm
+        monkeypatch.setattr(llm, "_device_beside_host", lambda: True)
+    module, variables = _one_window_lm(lm) \
+        if regime == "decoder_of_one_window" else lm
+    kw = {"slots": 10}
+    if regime == "speculative":
+        kw.update(spec_k=2, draft_module=draft_lm[0],
+                  draft_variables=draft_lm[1])
+    elif regime == "under_ride_from":
+        kw.update(slots=7)
+    reg = MetricsRegistry()
+    svc = f"sync-{regime}"
+    eng = LLMEngine(module, variables, block_len=4, max_seq_len=32,
+                    prefill_batch=2, service=svc, registry=reg, **kw)
+    prompts = _prompts(seed=61, sizes=(3, 5, 2, 6, 4, 3, 5, 2, 6, 4))
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, 8)
+    got = {}
+    boundaries = 0
+    for _ in range(100):
+        boundaries += eng.sched.busy
+        got.update(dict(eng.step()))
+        assert eng.decoder.flying is None
+    assert not eng.sched.busy
+    # the scheduler counts ONE step a boundary at which it holds a slot
+    assert _value(reg, "sched_continuous_steps_total",
+                  service=svc) == boundaries
+    ref = _ref(lm, prompts, max_new=8)
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(got[i], ref[i][:len(p) + 8])
+    assert _value(reg, "gen_decode_steps_total", service=svc) > 0
+    assert _value(reg, "gen_steps_ahead_total", service=svc) == 0
+
+
+def test_a_landed_prompt_no_slot_takes_goes_through_the_queue_by_value(
+        lm, beside):
+    """A prompt that ended in the window of a program in flight goes to
+    its slot by its first token's ROW, beside the queue. If no slot takes
+    it at once it waits on the host, the fetch brings the value home, and
+    it goes through the queue like any payload: the wire never carries a
+    row of this process's device."""
+    module, variables = lm
+    reg = MetricsRegistry()
+    eng = LLMEngine(module, variables, slots=10, block_len=4,
+                    max_seq_len=32, prefill_batch=1, service="late",
+                    registry=reg)
+    prompts = _prompts(seed=67, sizes=(3, 5, 2, 6, 4, 3, 5, 2, 6, 7))
+    for i, p in enumerate(prompts[:9]):
+        eng.submit(i, p, 12)
+    eng.step()
+    eng.step()                           # nine decode, one program out
+    eng.submit(9, prompts[9], 6)
+    eng.decoder.active[9] = True         # no slot is free at this boundary
+    eng.step()                           # its 7 rows ride and end here
+    assert eng._landing == {9: 10} and not len(eng.handoff)
+    eng.step()                           # the fetch brings its token home
+    assert not eng._landing
+    (payload,) = eng.handoff._q
+    assert isinstance(payload["first"], int)
+    assert set(payload) == {"seq", "first", "max_new_tokens"}
+    eng.decoder.active[9] = False
+    got = eng.run_until_drained()
+    ref = _ref(lm, prompts, max_new=12)
+    np.testing.assert_array_equal(got[9], ref[9][:len(prompts[9]) + 6])
+    for i in range(9):
+        np.testing.assert_array_equal(got[i], ref[i][:len(prompts[i]) + 12])
+
+
+@pytest.mark.parametrize("then", ["rows_finish_under_ride_from",
+                                  "warm_mid_serve"])
+def test_a_landed_prompts_token_comes_home_when_the_order_turns(
+        crowd, beside, then):
+    """A prompt lands in a window that rides while the engine runs ahead:
+    its slot has its first token by ROW alone. The next boundary waits
+    for its own program — three of the nine rows reached their end in the
+    very program the prompt rode in, so fewer than ``ride_from`` decode;
+    or ``warm`` is called there — and first brings the program in flight
+    home: the landed slot's token with it, though the slot decoded
+    nothing in that program. Every stream is the reference's."""
+    kind, _, _, reference = crowd
+    reg = MetricsRegistry()
+    svc = f"turn-{kind}-{then}"
+    eng = _crowd_engine(crowd, reg, svc)
+    rng = np.random.default_rng(84)
+    # a first token, then one token a program: 5 new tokens end in the
+    # fourth decode program, the one the late prompt rides in
+    short = 5 if then == "rows_finish_under_ride_from" else 14
+    sent = {i: (rng.integers(2, VOCAB, size=3 + i % 4).astype(np.int32),
+                short if i < 3 else 14) for i in range(9)}
+    for i, (p, new) in sent.items():
+        eng.submit(i, p, new)
+    served = {}
+    for _ in range(3):
+        served.update(dict(eng.step()))
+    sent["late"] = (rng.integers(2, VOCAB, size=9).astype(np.int32), 6)
+    eng.submit("late", *sent["late"])
+    served.update(dict(eng.step()))     # nine decode, its nine rows ride
+    slot = eng._meta["late"].slot
+    assert eng.decoder.flying is not None
+    assert eng.decoder.src[slot] == 10 and eng._meta["late"].handed
+    if then == "warm_mid_serve":
+        eng.warm(prefill_windows=(9,), mark_steady=False)
+        assert eng.decoder.last[slot] == reference(*sent["late"])[9]
+    else:
+        assert eng.decoder.runnable.sum() == 7      # under ride_from
+        served.update(dict(eng.step()))             # its second token
+        assert eng.decoder.last[slot] == reference(*sent["late"])[10]
+    assert eng.decoder.flying is None and (eng.decoder.src < 0).all()
+    assert eng._meta["late"].first_token == reference(*sent["late"])[9]
+    served.update(eng.run_until_drained())
+    assert set(served) == set(sent)
+    for seq_id, (p, new) in sent.items():
+        np.testing.assert_array_equal(served[seq_id], reference(p, new),
+                                      err_msg=str(seq_id))
+
+
 class TestPoolSizing:
     def test_block_priced_in_the_kernels_tiled_layout(self):
         from mmlspark_tpu.dl.paged_kv import pool_block_bytes
