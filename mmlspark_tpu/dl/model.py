@@ -22,7 +22,6 @@ import collections
 import contextlib
 import math
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -30,6 +29,7 @@ from ..core import ComplexParam, Model, Param, TypeConverters as TC
 from ..core.contracts import HasInputCol, HasOutputCol
 from ..models.zoo import LoadedModel
 from ..obs.tracing import tracer as _tracer
+from ..parallel.compat import jit as _jit
 
 
 class TPUModel(Model, HasInputCol, HasOutputCol):
@@ -118,10 +118,9 @@ class TPUModel(Model, HasInputCol, HasOutputCol):
         module, variables = self._loaded()
         key = (id(module), id(variables))
         if self._run_cache is None or self._run_cache[0] != key:
-            @jax.jit
             def run(batch):
                 return module.apply(variables, batch, False)
-            self._run_cache = (key, run)
+            self._run_cache = (key, _jit(run, name="tpu_model_apply"))
         return self._run_cache[1]
 
     def _transform(self, df):

@@ -10,6 +10,12 @@ trace files to rotate):
   recompile-hazard pass: the static pass says "this branch COULD
   recompile per step"; the tracker says "this function DID compile 14
   times in the last hour". Steady-state serving must show zero misses.
+  The name a call site gives is the name XLA has for the program
+  (``jit_<name>`` in a lowered module, a device trace and JAX's own
+  compile events), and from those events the tracker keeps a BUILD
+  LEDGER of every jitted function of the process, tracked or not: the
+  seconds each was traced, lowered and in the backend, and whether the
+  backend compiled it or loaded it from the persistent cache.
 
 - :class:`StepProfiler` — attributes wall time into host-dispatch vs
   device-execute per pipeline stage using the ``block_until_ready``
@@ -39,6 +45,7 @@ import collections
 import contextlib
 import functools
 import os
+import re
 import sys
 import threading
 import time
@@ -55,6 +62,17 @@ from .tracing import now_ns, tracer as _tracer, wall_now
 # overridden.
 DEFAULT_PEAK_FLOPS = PEAK_SPECS["tpu-v5e"].peak_flops
 
+# JAX's own events of a build (jax.monitoring): each of the three phases
+# arrives as a scalar when it starts and as a duration when it ends, both
+# with ``fun_name``; the persistent cache's arrive inside the backend
+# phase, with no name
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend"}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_NOT_IN_A_MODULE_NAME = re.compile(r"[^0-9A-Za-z_]")
+
 
 class CompileTracker:
     """Counts retraces and compile time per jitted function.
@@ -70,6 +88,21 @@ class CompileTracker:
       runtime ground truth),
     - ``profile_jit_calls_total{fn=...,outcome=hit|miss}``,
     - ``profile_compile_seconds{fn=...}`` — trace+compile wall time.
+
+    The label, with whatever an HLO module name cannot hold replaced by
+    ``_``, is the jitted function's ``__name__``: XLA calls the program
+    ``jit_<label>`` and the returned callable's ``__name__`` says it
+    (labels that differ only in such characters are one name to XLA, as
+    two functions of one name are).
+
+    The BUILD LEDGER (:meth:`ledger`) is fed by listeners on
+    ``jax.monitoring``, which fire only when JAX builds — a call that
+    hits pays nothing. One entry a function name, for every jitted
+    function of the process, in the order first seen. JAX keeps a
+    listener for the life of the process, so only the process-wide
+    ``compile_tracker`` listens unasked (when this module is imported
+    after JAX, else at its first :meth:`jit`); another tracker keeps a
+    ledger from its :meth:`listen` on.
 
     Intentionally lock-free: the trace-noting shim runs INSIDE the
     traced region (that is the mechanism), where lock acquisition is a
@@ -102,6 +135,70 @@ class CompileTracker:
         self._h_compile = reg.histogram(
             "profile_compile_seconds",
             "trace+compile wall seconds per tracked function")
+        self._builds: dict[str, dict] = {}
+        self._building = threading.local()
+        self._listening = False
+
+    # -- the build ledger --------------------------------------------------
+    def listen(self) -> None:
+        """Register the ledger's listeners on ``jax.monitoring``, once."""
+        if self._listening:
+            return
+        self._listening = True
+        from jax import monitoring
+        monitoring.register_scalar_listener(self._on_phase_start)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_seconds)
+
+    def _entry(self, fun_name: str) -> dict:
+        # the trace's event names the function ``<name>``, the other two
+        # the program, ``jit(<name>)``: one entry
+        name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+        return self._builds.setdefault(name, {
+            "fn": name, "traced": 0, "trace_s": 0.0, "lowered": 0,
+            "lower_s": 0.0, "backend_s": 0.0, "compiled": 0, "loaded": 0})
+
+    def _on_phase_start(self, event: str, _value, **_):
+        phase = _BUILD_PHASES.get(event)
+        if phase == "trace":
+            self._building.traces = getattr(self._building, "traces", 0) + 1
+        elif phase == "backend":
+            # the build a nameless cache hit on this thread belongs to
+            self._building.how = "compiled"
+
+    def _on_event(self, event: str, **_):
+        if event == _CACHE_HIT:
+            self._building.how = "loaded"
+
+    def _on_seconds(self, event: str, seconds: float, fun_name: str = "",
+                    **_):
+        phase = _BUILD_PHASES.get(event)
+        if phase is None:
+            return
+        if phase == "trace":
+            self._building.traces = inside = max(
+                getattr(self._building, "traces", 1) - 1, 0)
+            if inside:
+                # a jitted function called by the one being traced: its
+                # seconds are part of that one's, and it is no program
+                return
+            counted = "traced"
+        elif phase == "lower":
+            counted = "lowered"
+        else:
+            counted = getattr(self._building, "how", "compiled")
+        entry = self._entry(fun_name)
+        entry[counted] += 1
+        entry[f"{phase}_s"] += seconds
+
+    def ledger(self) -> list[dict]:
+        """What JAX built in this process, one plain dict a function
+        name in the order first seen: ``fn``, ``traced`` and ``lowered``
+        (how often; a second ``lower`` of the same arguments traces
+        again, in microseconds, and lowers nothing), ``trace_s``,
+        ``lower_s``, ``backend_s``, ``compiled`` and ``loaded`` (backend
+        builds by how)."""
+        return [dict(entry) for entry in self._builds.values()]
 
     def _note_trace(self, label: str) -> None:
         # runs at trace time, inside the traced region: must stay free
@@ -121,13 +218,18 @@ class CompileTracker:
         if fn is None:
             return functools.partial(self.jit, name=name, **jit_kwargs)
         import jax
+        if self is compile_tracker:
+            self.listen()
         label = name or getattr(fn, "__name__", None) or "<jit>"
+        xla_name = _NOT_IN_A_MODULE_NAME.sub("_", label)
 
         @functools.wraps(fn)
         def traced(*args, **kwargs):
             self._note_trace(label)
             return fn(*args, **kwargs)
 
+        # what XLA, a device trace and JAX's compile events call it
+        traced.__name__ = traced.__qualname__ = xla_name
         compiled = jax.jit(traced, **jit_kwargs)
 
         @functools.wraps(fn)
@@ -150,6 +252,7 @@ class CompileTracker:
         for attr in ("lower", "eval_shape", "trace", "clear_cache"):
             if hasattr(compiled, attr):
                 setattr(call, attr, getattr(compiled, attr))
+        call.__name__ = call.__qualname__ = xla_name
         call.__tracked_label__ = label
         return call
 
@@ -216,6 +319,11 @@ class CompileTracker:
 
 #: THE process-wide tracker (``parallel.compat.jit`` routes through it).
 compile_tracker = CompileTracker()
+if sys.modules.get("jax") is not None:
+    # imported after JAX (a program that already builds): the ledger
+    # holds what is built from here on; importing this module alone
+    # still imports no JAX
+    compile_tracker.listen()
 
 
 class _StepHandle:

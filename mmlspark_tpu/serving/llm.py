@@ -116,13 +116,17 @@ its own boundary: no setting chooses.
 Obs: every boundary is an ``llm.step`` span on the tracer's ring (its
 ``ahead`` attribute: the program went out before the fetch of the one
 before it; ``gen_steps_ahead_total`` counts those beside
-``gen_decode_steps_total``) with
+``gen_decode_steps_total``; ``program``: the name XLA and a device
+trace have for the program it dispatched, less ``jit_``, which is where
+to look on the trace's ``XLA Modules`` line for what this boundary ran;
+``steps``: ``gen_decode_steps_total`` as the boundary left it) with
 ``llm.prefill`` (the prefill programs of a boundary with nothing to ride
 with; the host's part of a riding window, whose real rows the root says
 as ``ride_rows``) and ``llm.decode`` (the block tables, the step's one
 program and the boundary's fetch, which is an ``llm.fetch`` span of its
 own: what the host waits there is what the device still had to do, the
-host's slack) children; a decoder's walk counts
+host's slack; its ``program`` is the one whose results it brings
+home) children; a decoder's walk counts
 land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
@@ -134,7 +138,9 @@ counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 token came of it), ``gen_prefill_rows_total{ride=decode|alone}`` (prompt
 rows by how their window ran),
 ``gen_spec_accept_ratio``, ``gen_decode_steps_total``,
-``gen_decode_attn_seconds{phase}`` here, the ``kv_*`` families
+``gen_decode_attn_seconds{phase}`` (a program's seconds from its
+dispatch, or the fetch before it, to its fetch: observed at the fetch)
+here, the ``kv_*`` families
 (``kv_state_*`` among them) in ``dl.paged_kv`` — all federated
 fleet-wide and recorded by the telemetry history plane. Completions land FeatureLog rows with
 ``decode_steps``/``prefill_tokens``/``context_blocks`` so the cost
@@ -318,6 +324,17 @@ class _PoolState:
 
 # ---------------------------------------------------------------- programs
 
+@dataclass(eq=False)
+class _Flight:
+    """A program between its dispatch and its fetch."""
+    program: str            # the name XLA has for it, less ``jit_``
+    phase: str              # "decode" (a step, whatever rides in it) | "prefill"
+    t0: float               # ``time.perf_counter`` at its dispatch
+    made: dict | None = None        # what it made, on the device
+    slots: np.ndarray | None = None     # the slots whose rows it decoded
+    landed: dict | None = None  # seq_id -> (pick row, rows fed): prompts it ended
+
+
 class _Programs:
     """The one builder of the engine's device programs. Every program is
     ONE walk of the decoder over a tuple of windows — the decoding rows
@@ -372,9 +389,13 @@ class _Programs:
         reg = registry if registry is not None else _default_registry
         self._h_attn = reg.histogram(
             "gen_decode_attn_seconds",
-            "attention-program wall time, by service and phase",
+            "a program's seconds from its dispatch, or from the fetch of "
+            "the one before it where that is later, to its fetch: its "
+            "device time where a device runs beside the host; by service "
+            "and phase",
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1,
                      .25, .5, 1., 2.5))
+        self._home = 0.0        # when the last fetch came home
         self.walk_stats = _WalkStats(module, reg, service)
         self.built: dict[tuple, object] = {}
         self._fps: dict[str, tuple[str, str]] = {}
@@ -530,19 +551,31 @@ class _Programs:
                 self.pools.target, self.pools.draft, dec, win)
 
     def call(self, dec: tuple = (), win: tuple = (), head: bool = True
-             ) -> dict:
+             ) -> _Flight:
         """Dispatch the program of these windows over the shared pools;
-        returns what it made, still on the device."""
+        returns it in flight: what it made is still on the device."""
         w = win[1].shape[1] if win else None
         prog = self.get(bool(dec), w, head)
-        t0 = time.perf_counter()
-        pools_t, pools_d, out = prog(*self._args(dec, win))
-        self._h_attn.observe(time.perf_counter() - t0, service=self.service,
-                             phase="decode" if dec else "prefill")
+        flight = _Flight(prog.__name__, "decode" if dec else "prefill",
+                         time.perf_counter())
+        pools_t, pools_d, flight.made = prog(*self._args(dec, win))
         self.pools.target = pools_t
         if self.draft_module is not None:
             self.pools.draft = pools_d
-        return out
+        return flight
+
+    def home(self, flights: list) -> None:
+        """What these programs made has just been fetched, in one fetch:
+        ``gen_decode_attn_seconds`` gets, once a program, an equal share
+        of the seconds from the first one's dispatch — or from the fetch
+        before this one, where the device was still busy with that — to
+        now."""
+        now = time.perf_counter()
+        share = (now - max(flights[0].t0, self._home)) / len(flights)
+        for flight in flights:
+            self._h_attn.observe(share, service=self.service,
+                                 phase=flight.phase)
+        self._home = now
 
     def tokens_at_home(self) -> tuple:
         """The walk program's ``(prev, src)`` where every row's token is on
@@ -898,7 +931,7 @@ class PrefillExecutor:
         self._restore(feeds)
         padded = feeds + [None] * (self.batch - len(feeds))
         firsts: dict = {}
-        counts: list = []               # each call's walk counts
+        flights: list = []
 
         def feed_while(live) -> None:
             while True:
@@ -908,16 +941,17 @@ class PrefillExecutor:
                     return
                 win, ends, rows = self._window(part, _bucket_window)
                 self._count(ends, rows, "alone")
-                made = self.programs.call((), win, head=bool(ends))
-                if self.programs.walk_stats:
-                    counts.append(made["counts"])
+                flights.append(self.programs.call((), win, head=bool(ends)))
                 for i in ends:
-                    firsts[i] = made["tok"]
+                    firsts[i] = flights[-1].made["tok"]
 
         feed_while(lambda f: f.at < f.cut)
         self._snapshot(feeds)
         feed_while(lambda f: f.at < len(f.prompt))
-        firsts, counts = jax.device_get((firsts, counts))
+        firsts, counts = jax.device_get((
+            firsts, [f.made["counts"] for f in flights]
+            if self.programs.walk_stats else []))
+        self.programs.home(flights)
         for count in counts:
             self.programs.walk_stats.record(count)
         return {f.seq_id: (int(firsts[i][i]), self._commit(f))
@@ -944,14 +978,6 @@ class PrefillExecutor:
             self.programs.copy_rows([])
         for key in sorted(kinds):
             self.programs.warm(*key)
-
-
-@dataclass(eq=False)
-class _Flight:
-    """A step's program between its dispatch and its fetch."""
-    made: dict              # what the program made, on the device
-    slots: np.ndarray       # the slots whose rows it decoded
-    landed: dict            # seq_id -> (pick row, rows fed): prompts it ended
 
 
 class DecodeExecutor:
@@ -1011,6 +1037,9 @@ class DecodeExecutor:
         self.src = np.full(self.slots, -1, np.int32)
         #: the program dispatched and not fetched
         self.flying: _Flight | None = None
+        #: the program the last :meth:`step` dispatched; None: it had no
+        #: runnable row
+        self.sent: _Flight | None = None
         #: whether :meth:`step` leaves its program in flight and fetches
         #: the one before it (the engine sets it, a boundary at a time)
         self.ahead = False
@@ -1082,6 +1111,7 @@ class DecodeExecutor:
         (win, land), self.riding = self.riding or ((), None), None
         before = self.flying
         self.landed = {}
+        self.sent = None
         if runnable.any():
             slots = np.flatnonzero(runnable)
             # capacity for this step's writes: positions up to ptr-1+k
@@ -1103,14 +1133,15 @@ class DecodeExecutor:
                    *picks,
                    *((jnp.asarray(self.kv.state_rows(running)),)
                      if self.kv.state_slots else ()))
-            made = self.programs.call(dec, win)
+            self.sent = flight = self.programs.call(dec, win)
             if not self.spec_k:
                 # one token a row, whatever it is: it lies at the row's
                 # own pick until it is home
                 self._advance(slots, 1)
                 self.src[slots] = slots
             self.landed = land() if land is not None else {}
-            self.flying = _Flight(made, slots, self.landed)
+            flight.slots, flight.landed = slots, self.landed
+            self.flying = flight
         if self.ahead:
             return self._fetch(before)
         assert before is None, "fetch() what flies before a step that waits"
@@ -1134,8 +1165,9 @@ class DecodeExecutor:
         self.firsts = {}
         if flight is None:
             return {}
-        with _tracer.span("llm.fetch"):
+        with _tracer.span("llm.fetch", program=flight.program):
             made = jax.device_get(flight.made)
+        self.programs.home([flight])
         if flight is self.flying:
             # nothing flies from here on: EVERY token comes home, that of
             # a slot handed a prompt this program ended too (it did not
@@ -1391,9 +1423,17 @@ class LLMEngine:
         self.decoder.ahead = ahead
         with _tracer.span("llm.decode", parent=root):
             results = self.decoder.step()
-        if stepping:
+        sent = self.decoder.sent
+        if sent is not None:
             self._c_steps.inc(1, service=self.service)
             self._c_ahead.inc(int(flew), service=self.service)
+            # what this boundary put on the device, by the name the device
+            # trace has for it
+            root.set_attr("program", sent.program)
+        # ``gen_decode_steps_total`` as this boundary leaves it: where a
+        # caller reads the counter a boundary, the ring's roots lie against
+        # its record
+        root.set_attr("steps", int(self._c_steps.value(service=self.service)))
         return finished + self._settle(results, self.decoder.landed)
 
     def _settle(self, results: dict, landed: dict | None = None) -> list:

@@ -138,6 +138,24 @@ class TestTPUModel:
         out1 = m1.transform(images_df)
         np.testing.assert_allclose(out["feat"], out1["feat"], atol=1e-4)
 
+    def test_the_apply_program_is_named_for_the_device_trace(self,
+                                                             images_df):
+        """The one program of a transform is ``jit_tpu_model_apply`` to
+        XLA, and the compile tracker counts it under that name."""
+        from mmlspark_tpu.obs import compile_tracker
+        m = TPUModel(model=tiny_loaded(), inputCol="image",
+                     outputCol="feat", outputNode="pooled", minibatchSize=4)
+        before = compile_tracker.compiles("tpu_model_apply")
+        m.transform(images_df)
+        m.transform(images_df)              # the cached program: no retrace
+        assert compile_tracker.compiles("tpu_model_apply") == before + 1
+        run = m._apply_fn()
+        batch = np.zeros((4,) + images_df["image"].shape[1:], np.float32)
+        assert "module @jit_tpu_model_apply " in run.lower(batch).as_text()
+        entry = next(e for e in compile_tracker.ledger()
+                     if e["fn"] == "tpu_model_apply")
+        assert entry["traced"] >= 1 and entry["backend_s"] > 0
+
     def test_fetch_dict(self, images_df):
         loaded = tiny_loaded()
         m = TPUModel(model=loaded, inputCol="image",

@@ -737,9 +737,9 @@ def test_the_next_program_is_dispatched_before_the_last_ones_fetch(
     def call(self, dec=(), win=(), head=True):
         out = real_call(self, dec, win, head)
         if dec:
-            made_by[id(out["tok"])] = len(made_by)
-            events.append(("dispatch", made_by[id(out["tok"])]))
-            call.keep.append(out["tok"])     # ids stay distinct
+            made_by[id(out.made["tok"])] = len(made_by)
+            events.append(("dispatch", made_by[id(out.made["tok"])]))
+            call.keep.append(out.made["tok"])     # ids stay distinct
         return out
 
     call.keep = []
@@ -807,6 +807,105 @@ def test_tokens_count_when_they_are_home_not_at_dispatch(crowd, beside):
     assert _value(reg, "gen_tokens_total", service=svc) == 9 * 11
     for i, p in enumerate(prompts):
         np.testing.assert_array_equal(got[i], reference(p, 12))
+
+
+def _count(reg, name, **labels):
+    return next(m for m in reg.metrics(name) if m.name == name).count(
+        **labels)
+
+
+def test_a_boundary_says_what_it_ran_and_a_fetch_what_it_brings(crowd,
+                                                                beside):
+    """The spans of a boundary name its program as XLA does: ``llm.step``
+    the one it dispatched (with ``gen_decode_steps_total`` as the
+    boundary left it), ``llm.fetch`` the one it brought home, which one boundary ahead is
+    the one dispatched a boundary BEFORE; ``gen_decode_attn_seconds`` is
+    observed at the fetch, once a program."""
+    from mmlspark_tpu.serving import llm
+    kind, module, variables, reference = crowd
+    reg = MetricsRegistry()
+    svc = f"names-{kind}"
+    from mmlspark_tpu.obs.tracing import now_ns
+    served_by = []              # when the serving ended
+
+    def compare(prompt, max_new):
+        # the reference may serve with engines of its own: their spans
+        # come after
+        served_by.append(now_ns())
+        return reference(prompt, max_new)
+
+    start = now_ns()
+    _serve_crowd((kind, module, variables, compare),
+                 "a_prompt_rides_in_several_windows", reg, svc)
+    spans = [s for s in llm._tracer.recent(since=start)
+             if s.end_ns <= served_by[0]]
+    roots = [s for s in spans if s.name == "llm.step"]
+    decodes = {s.span_id: s.parent_id for s in spans
+               if s.name == "llm.decode"}
+    fetched = {}                        # root -> the programs it brought home
+    for s in spans:
+        if s.name == "llm.fetch":
+            root = decodes.get(s.parent_id, s.parent_id)
+            fetched.setdefault(root, []).append(s)
+    safe = svc.replace("-", "_")
+    steps = 0
+    sent = []                           # (boundary, program) in dispatch order
+    for k, root in enumerate(roots):
+        if "program" in root.attrs:
+            steps += 1
+            sent.append((k, root.attrs["program"]))
+            assert root.attrs["program"].startswith(
+                (f"llm_decode_paged_{safe}_S10", f"llm_step_{safe}_S10_w"))
+            # a window rode in it where the boundary says its rows
+            rode = root.attrs.get("ride_rows", 0) > 0
+            assert rode == root.attrs["program"].startswith("llm_step_")
+        assert root.attrs["steps"] == steps
+    assert steps == _value(reg, "gen_decode_steps_total", service=svc)
+    assert {p.split("_")[1] for _, p in sent} == {"decode", "step"}
+    # every program dispatched is fetched once, in the order of dispatch:
+    # at its own boundary, or ahead at the boundary after
+    home = [(k, f.attrs["program"]) for k, root in enumerate(roots)
+            for f in fetched.get(root.span_id, [])]
+    assert [p for _, p in home] == [p for _, p in sent]
+    assert all(at in (k, k + 1) for (at, _), (k, _) in zip(home, sent))
+    # a boundary that ran ahead fetched the program of the boundary before
+    ahead = [k for k, root in enumerate(roots) if root.attrs.get("ahead")]
+    assert len(ahead) == _value(reg, "gen_steps_ahead_total",
+                                service=svc) >= 6
+    for k in ahead:
+        assert [f.attrs["program"] for f in fetched[roots[k].span_id]] \
+            == [roots[k - 1].attrs["program"]]
+    # once a program, at its fetch: the steps, and the prefills alone
+    assert _count(reg, "gen_decode_attn_seconds", service=svc,
+                  phase="decode") == steps
+    alone = sum(_value(reg, "gen_prefill_calls_total", service=svc, head=h)
+                for h in ("row", "none")) - sum(
+        p.startswith("llm_step_") for _, p in sent)
+    assert _count(reg, "gen_decode_attn_seconds", service=svc,
+                  phase="prefill") == alone > 0
+
+
+def test_a_programs_label_is_its_lowered_modules_name(crowd):
+    """``_Programs.name`` reaches XLA: the decode step, a step with a
+    window riding in it, a prefill alone and the state-row copy are
+    ``jit_<label>`` in the lowered module (a service's ``-`` as ``_``)."""
+    kind = crowd[0]
+    eng = _crowd_engine(crowd, MetricsRegistry(), f"named-{kind}")
+    progs = eng.programs
+    safe = f"named_{kind}"
+    for key, want in (((True, None, True), f"llm_decode_paged_{safe}_S10_k0"),
+                      ((True, 32, True), f"llm_step_{safe}_S10_w32_b2"),
+                      ((False, 8, False), f"llm_prefill_{safe}_w8_b2_nohead")):
+        prog = progs.get(*key)
+        assert prog.__name__ == want
+        assert prog.__tracked_label__ == progs.name(*key)
+        text = prog.lower(*progs._args(*progs.blank(*key[:2]))).as_text()
+        assert f"module @jit_{want} " in text
+    if kind == "state_a_sequence":
+        progs.copy_rows([])
+        rows = jnp.zeros(progs.batch, jnp.int32)
+        text = progs._copy.lower(eng.pools.target, rows, rows).as_text()
+        assert f"module @jit_llm_state_copy_{safe}_b2 " in text
 
 
 def test_running_ahead_compiles_no_program_of_its_own(lm, beside):

@@ -309,6 +309,150 @@ class TestCompileTracker:
         assert compile_tracker.compiles("train_step") == before + 1
 
 
+class TestProgramNamesAndBuildLedger:
+    """ISSUE 37: the name a call site gives is the name XLA has, and
+    JAX's own compile events keep a ledger of what was built."""
+
+    def test_the_label_is_the_lowered_modules_name(self):
+        import jax.numpy as jnp
+
+        tracker = CompileTracker(registry=type(registry)())
+        f = tracker.jit(lambda x: x * 2, name="llm_step_bench-x.y_S4_w32")
+        text = f.lower(jnp.ones((2,))).as_text()
+        assert "module @jit_llm_step_bench_x_y_S4_w32 " in text
+        assert f.__name__ == "llm_step_bench_x_y_S4_w32"
+        # the tracker's own series keep the label as given
+        assert f.__tracked_label__ == "llm_step_bench-x.y_S4_w32"
+
+        def plain(x):
+            return x + 1
+
+        assert "module @jit_plain " in tracker.jit(plain).lower(
+            jnp.ones((2,))).as_text()
+
+    def test_train_step_is_the_modules_name(self):
+        pytest.importorskip("flax")
+        import jax
+        import optax
+        from flax import linen as nn
+
+        from mmlspark_tpu.dl.train import init_train_state, \
+            make_train_step
+
+        class Tiny(nn.Module):
+            @nn.compact
+            def __call__(self, x, train=True):
+                return nn.Dense(3)(x)
+
+        tx = optax.sgd(0.1)
+        state = init_train_state(Tiny(), jax.random.PRNGKey(0),
+                                 np.zeros((4, 5), np.float32), tx)
+        text = make_train_step(Tiny(), tx).lower(
+            state, np.zeros((4, 5), np.float32),
+            np.zeros((4,), np.int32)).as_text()
+        assert "module @jit_train_step " in text
+
+    def test_ledger_of_a_tracked_and_an_untracked_jit(self):
+        """Trace, lower and backend seconds of both; a second ``lower``
+        of the same arguments traces again and lowers nothing; a new
+        shape does both again; a jitted function called inside is no
+        entry of its own."""
+        import jax
+        import jax.numpy as jnp
+
+        tracker = CompileTracker(registry=type(registry)())
+        silent = CompileTracker(registry=type(registry)())
+        tracker.listen()
+        tracker.listen()                    # once, whoever asks again
+
+        @jax.jit
+        def ledger_inner(x):
+            return jnp.sin(x)
+
+        def body(x):
+            return ledger_inner(x) * 2
+
+        tracked = tracker.jit(body, name="ledger-tracked")
+
+        def ledger_untracked(x):
+            return x + 3
+
+        untracked = jax.jit(ledger_untracked)
+        tracked(jnp.ones((4,)))
+        untracked(jnp.ones((4,)))
+        # only the process-wide tracker listens unasked
+        from mmlspark_tpu.obs import compile_tracker
+        silent.jit(lambda x: x - 1, name="ledger_silent")(jnp.ones((4,)))
+        assert silent.ledger() == [] and not silent._listening
+        assert "ledger_silent" in {e["fn"] for e in compile_tracker.ledger()}
+        by_fn = {e["fn"]: e for e in tracker.ledger()}
+        assert "ledger_inner" not in by_fn
+        for fn in ("ledger_tracked", "ledger_untracked"):
+            e = by_fn[fn]
+            assert (e["traced"], e["lowered"]) == (1, 1)
+            assert e["compiled"] + e["loaded"] == 1
+            assert min(e["trace_s"], e["lower_s"], e["backend_s"]) > 0
+        order = [e["fn"] for e in tracker.ledger()]
+        assert order.index("ledger_tracked") \
+            < order.index("ledger_untracked")
+        before = by_fn["ledger_tracked"]
+        tracked.lower(jnp.ones((4,)))       # the same arguments
+        tracked.lower(jnp.ones((6,)))       # a shape it has not seen
+        tracked(jnp.ones((4,)))             # a hit: nothing is built
+        after = next(e for e in tracker.ledger()
+                     if e["fn"] == "ledger_tracked")
+        assert (after["traced"], after["lowered"]) == (3, 2)
+        assert after["compiled"] + after["loaded"] == 1
+        assert after["lower_s"] > before["lower_s"]
+        # a read is a copy
+        tracker.ledger()[0]["traced"] = 99
+        assert tracker.ledger()[0]["traced"] != 99
+
+    def test_ledger_says_loaded_for_a_program_the_cache_held(self,
+                                                             tmp_path):
+        """The same program built twice against a persistent cache: the
+        backend step compiles it, then loads it."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        was = {k: getattr(jax.config, k) for k in keys}
+        tracker = CompileTracker(registry=type(registry)())
+        tracker.listen()
+        try:
+            for k, v in zip(keys, (str(tmp_path), 0.0, 0)):
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+            for _ in range(2):              # a new function each time
+                f = tracker.jit(lambda x: jnp.cos(x) * 5 + 2,
+                                name="ledger_built_twice")
+                f(jnp.ones((3,)))
+        finally:
+            for k, v in was.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+        e = next(e for e in tracker.ledger()
+                 if e["fn"] == "ledger_built_twice")
+        assert (e["traced"], e["lowered"]) == (2, 2)
+        assert (e["compiled"], e["loaded"]) == (1, 1)
+
+    def test_importing_obs_alone_imports_no_jax(self):
+        import subprocess
+        import sys
+        code = ("import sys; import mmlspark_tpu.obs; "
+                "from mmlspark_tpu.obs import compile_tracker as t; "
+                "assert 'jax' not in sys.modules; "
+                "assert t.ledger() == [] and not t._listening; "
+                "import jax; import importlib; "
+                "import mmlspark_tpu.obs.profile as p; importlib.reload(p); "
+                "assert p.compile_tracker._listening")
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       timeout=120)
+
+
 class TestStepProfiler:
     def test_dispatch_device_split_and_spans(self):
         import jax.numpy as jnp
