@@ -73,7 +73,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.moe import MOE_STATS, dropless_moe, route_top_k
+from ..models.moe import (MOE_STATS, add_moe_stats, dropless_moe,
+                          route_top_k)
 from .decoder_blocks import (DictDecoder, causal_conv_tail, lay_rows,
                              rope_angles, rotate_half_partial, split_rows,
                              window_positions)
@@ -260,7 +261,7 @@ class GatedDeltaMoEDecoder(DictDecoder):
         routed, stats = dropless_moe(
             u.astype(self.dtype), experts, probs, lw["exp_gate"],
             lw["exp_up"], lw["exp_down"], held=self.experts_held,
-            valid=valid)
+            valid=valid, num_experts=self.experts)
         shared = jax.nn.sigmoid(self._mm(u, lw["shared_router"])) \
             * self._gated(u, lw["shared_gate"], lw["shared_up"],
                           lw["shared_down"])
@@ -311,7 +312,7 @@ class GatedDeltaMoEDecoder(DictDecoder):
             h = x + mixed
             ffn, counts = self._expert_layer(
                 self._rms_centred(h, lw["ffn_norm"]), lw, token_valid)
-            moe = stats[:n_moe].at[:3].add(counts[:3]).at[3].max(counts[3])
+            moe = add_moe_stats(stats[:n_moe], counts)
             return h + ffn, tuple(layer_pools), jnp.concatenate([moe, gdn])
 
         stats = jnp.zeros((len(self.walk_stats),), jnp.int32)

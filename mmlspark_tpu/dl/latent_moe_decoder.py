@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.moe import MOE_STATS, dropless_moe, route_top_k
+from ..models.moe import (MOE_STATS, add_moe_stats, dropless_moe,
+                          route_top_k)
 from .decoder_blocks import (DictDecoder, lay_rows, split_rows,
                              window_positions)
 from .decoder_blocks import rotate_interleaved as _rotate
@@ -170,7 +171,7 @@ class LatentMoEDecoder(DictDecoder):
             u.astype(self.dtype), experts,
             probs * float(c["routed_scaling_factor"]), lw["exp_gate"],
             lw["exp_up"], lw["exp_down"], held=self.experts_held,
-            valid=valid)
+            valid=valid, num_experts=int(c["n_routed_experts"]))
         return routed + self._gated(u, lw["shared_gate"], lw["shared_up"],
                                     lw["shared_down"]), stats
 
@@ -262,7 +263,7 @@ class LatentMoEDecoder(DictDecoder):
                                     dense=i < self.dense_layers)
             new_pools.append((pool,))
             if counts is not None:
-                stats = stats.at[:3].add(counts[:3]).at[3].max(counts[3])
+                stats = add_moe_stats(stats, counts)
         return split_rows(x, shapes), tuple(new_pools), stats
 
     def logits(self, params, hidden):

@@ -63,7 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.moe import MOE_STATS, dropless_moe, route_top_k
+from ..models.moe import (MOE_STATS, add_moe_stats, dropless_moe,
+                          route_top_k)
 from .decoder_blocks import (DictDecoder, causal_conv_tail, gated_silu,
                              lay_rows, rope_angles, rotate_half, split_rows,
                              window_positions)
@@ -221,7 +222,8 @@ class ShortConvMoEDecoder(DictDecoder):
             routed, stats = dropless_moe(
                 u.astype(self.dtype), experts, weights * self.scaling,
                 lw["exp_gate"], lw["exp_up"], lw["exp_down"],
-                held=self.experts_held, valid=valid)
+                held=self.experts_held, valid=valid,
+                num_experts=self.experts)
         # a pair the unbiased scores would not have chosen
         _, plain = jax.lax.top_k(scores, self.top_k)
         moved = ~jnp.any(experts[:, :, None] == plain[:, None, :], axis=-1)
@@ -276,8 +278,9 @@ class ShortConvMoEDecoder(DictDecoder):
                 ffn = gated_silu(v, lw["w1"], lw["w3"], lw["w2"], self.dtype)
             else:
                 ffn, counts, biased = self._expert_layer(v, lw, token_valid)
-                moe = moe.at[:3].add(counts[:3]).at[3].max(counts[3]) \
-                    .at[n_moe].add(biased)
+                moe = jnp.concatenate([
+                    add_moe_stats(moe[:n_moe], counts),
+                    moe[n_moe:] + biased])
             return h + ffn, tuple(layer_pools), jnp.concatenate([moe, conv])
 
         stats = jnp.zeros((len(self.walk_stats),), jnp.int32)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from ..dl.pallas_grouped import grouped_expert_products
 from ..parallel import collectives as _coll
 from ..parallel.compat import shard_map as _shard_map
 
@@ -108,9 +109,18 @@ def _capacity_ffn(x, eid, pos, keep, w_in, w_out, C: int):
 # a serving decoder's expert layer needs (``dl.latent_moe_decoder``). The
 # training encoder below keeps its top-1 argmax over logits.
 
-#: names of the four numbers :func:`dropless_moe` counts, in order
+#: names of the five numbers :func:`dropless_moe` counts, in order
 MOE_STATS = ("pairs_held", "pairs_absent", "experts_touched",
-             "expert_load_max")
+             "expert_load_max", "rows_computed")
+#: which of them is a largest value and not a sum
+_STAT_IS_MAX = np.array([name.endswith("_max") for name in MOE_STATS])
+
+
+def add_moe_stats(total, counts):
+    """One layer's counts (:data:`MOE_STATS`) onto a walk's: summed, but
+    ``expert_load_max`` the larger of the two."""
+    return jnp.where(_STAT_IS_MAX, jnp.maximum(total, counts),
+                     total + counts)
 
 
 def route_top_k(probs, *, top_k: int, groups: int = 1,
@@ -139,7 +149,8 @@ def route_top_k(probs, *, top_k: int, groups: int = 1,
 
 
 def dropless_moe(x, experts, weights, w_gate, w_up, w_down, *,
-                 held: tuple, valid=None):
+                 held: tuple, valid=None, num_experts: int | None = None,
+                 impl: str | None = None, interpret: bool | None = None):
     """The held experts' part of a gated-SiLU expert layer, dropless.
 
     ``x`` [T, D] tokens; ``experts``/``weights`` [T, k] each token's
@@ -152,11 +163,16 @@ def dropless_moe(x, experts, weights, w_gate, w_up, w_down, *,
     ([T, D] float32), which is what expert parallelism asks of one
     holder before its exchange.
 
-    The pairs are sorted by expert and the three products are grouped
-    (``jax.lax.ragged_dot``: one pass over the held experts' weights,
-    rows only where an expert has pairs). ``valid`` [T] bool leaves
-    padding tokens out of the products and the counts. Also returns the
-    counts :data:`MOE_STATS` as int32 [4]."""
+    The pairs are sorted by expert and the products are grouped
+    (``dl.pallas_grouped.grouped_expert_products``: on a TPU two Pallas
+    kernels that read each held expert with pairs once, gate and up in
+    one pass; elsewhere three ``jax.lax.ragged_dot`` passes; ``impl`` and
+    ``interpret`` as there). ``num_experts`` is the layer's count of
+    experts, held or not (None: the held ones), which sizes the kernels'
+    row tile. ``valid`` [T] bool leaves padding tokens out of the
+    products and the counts. Also returns the counts :data:`MOE_STATS`
+    as int32 [5]: ``rows_computed`` is the rows of the products' tiles,
+    padding included (the held pairs where no tile pads them)."""
     T, k = experts.shape
     lo, hi = int(held[0]), int(held[1])
     n = hi - lo
@@ -169,20 +185,16 @@ def dropless_moe(x, experts, weights, w_gate, w_up, w_down, *,
     sizes = jnp.zeros((n + 1,), jnp.int32).at[local].add(1)[:n]
     token = jnp.arange(T * k, dtype=jnp.int32) // k
     xs = x[token[order]]                           # [T*k, D], by expert
-
-    def grouped(a, w):
-        return jax.lax.ragged_dot(a, w, sizes,
-                                  preferred_element_type=jnp.float32)
-
-    h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-    ys = grouped(h.astype(x.dtype), w_down)        # [T*k, D] float32
+    ys, rows = grouped_expert_products(            # [T*k, D] float32
+        xs, sizes, w_gate, w_up, w_down, num_experts=num_experts,
+        impl=impl, interpret=interpret)
     # rows past the held pairs belong to no group: whatever they hold
     scale = jnp.where(mine, weights.reshape(-1), 0.0)[order]
     ys = jnp.where(mine[order][:, None], ys * scale[:, None], 0.0)
     out = ys[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
     stats = jnp.stack([
         jnp.sum(mine), jnp.sum(real & ~mine), jnp.sum(sizes > 0),
-        jnp.max(sizes)]).astype(jnp.int32)
+        jnp.max(sizes), rows]).astype(jnp.int32)
     return out, stats
 
 

@@ -133,7 +133,8 @@ grid they walked before PR 38, which ``gen_attn_cells_total`` and
 land on the registry inside the step's one fetch (``<name>_total``
 counters, ``*_max`` gauges: ``moe_pairs_held_total``,
 ``moe_pairs_absent_total``, ``moe_experts_touched_total``,
-``moe_expert_load_max``; ``sparse_blocks_chosen_total``,
+``moe_expert_load_max``, ``moe_rows_computed_total``;
+``sparse_blocks_chosen_total``,
 ``sparse_blocks_in_chain_total``, ``sparse_dense_rows_total``);
 ``gen_ttft_seconds{reuse=cold|warm}``,
 ``gen_tokens_total``, ``gen_prefill_calls_total{head=row|none}``

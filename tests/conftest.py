@@ -61,3 +61,20 @@ def eight_device_mesh():
     devices = np.asarray(jax.devices())
     assert devices.size == 8, f"expected 8 virtual devices, got {devices.size}"
     return Mesh(devices, ("dp",))
+
+
+@pytest.fixture
+def grouped_kernels(monkeypatch):
+    """Route every expert layer's grouped products through the Pallas
+    kernels (interpreted), where the platform switch picks the
+    ``ragged_dot`` passes on the CPU: steered from the test, not through an
+    option of the program."""
+    from mmlspark_tpu.dl import pallas_grouped
+    from mmlspark_tpu.models import moe
+
+    def through_kernels(*args, **kw):
+        return pallas_grouped.grouped_expert_products(
+            *args, **{**kw, "impl": "pallas", "interpret": True})
+
+    monkeypatch.setattr(moe, "grouped_expert_products", through_kernels)
+    return monkeypatch          # ``.undo()``: the platform's path again

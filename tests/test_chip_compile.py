@@ -54,6 +54,7 @@ def steer_tpu(monkeypatch):
     here it answers ``cpu``: steer it from the test (not through an
     option of the program) so that the chip's path is what is lowered."""
     import mmlspark_tpu.dl.pallas_gated_delta as gated_delta
+    import mmlspark_tpu.dl.pallas_grouped as grouped
     import mmlspark_tpu.dl.pallas_lightning as lightning
     import mmlspark_tpu.dl.pallas_paged_attention as paged
     import mmlspark_tpu.utils.platform as plat
@@ -61,6 +62,7 @@ def steer_tpu(monkeypatch):
     monkeypatch.setattr(paged, "target_platform", lambda: "tpu")
     monkeypatch.setattr(lightning, "target_platform", lambda: "tpu")
     monkeypatch.setattr(gated_delta, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(grouped, "target_platform", lambda: "tpu")
 
 
 def _sds(shape, dtype, sharding):
@@ -88,6 +90,12 @@ def _lower_engine_programs(engine, w, sharding, params, dparams, pools,
     if engine.prefiller.rider is not None:
         out[f"step_w{w}"] = lower(True, w)
     return out
+
+
+def _defined(text: str, name: str) -> int:
+    """Instructions of a compiled program's text named ``name``."""
+    import re
+    return len(re.findall(rf"%{re.escape(name)}(?:\.\d+)? = ", text))
 
 
 def _compile(fn, *args):
@@ -319,7 +327,12 @@ def test_latent_moe_engine_programs(one_chip, steer_tpu):
         compiled = lowered.compile()
         text = compiled.as_text()
         assert text.count("paged_latent") >= 5, name
-        assert text.count("ragged-dot") >= 12, name
+        # the four expert layers' two grouped kernels (a program with no
+        # head leaves the last layer's experts out: nothing reads them)
+        layers = 3 if name.endswith("head0") else 4
+        for kernel in ("ragged-dot-gate-up", "ragged-dot-down"):
+            assert _defined(text, kernel) == layers, (name, kernel)
+        assert "ragged-dot-none" not in text, name
         # the five layers share each window's table, and the live
         # schedule the kernel walks is built from it once: in fewer
         # fusions a table than there are layers
@@ -578,6 +591,38 @@ def test_gated_delta_moe_engine_programs(one_chip, steer_tpu):
         assert mem.argument_size_in_bytes < 11.5e9, name
 
 
+@pytest.mark.parametrize("T,k,E,n,D,F", [
+    (256, 4, 32, 32, 2048, 1792), (1280, 4, 32, 32, 2048, 1792),
+    (128, 10, 512, 128, 2048, 512), (1152, 10, 512, 128, 2048, 512),
+    (128, 6, 160, 40, 5120, 1536), (320, 6, 160, 40, 5120, 1536)],
+    ids=["short-chat-decode", "short-chat-window", "chat-decode",
+         "chat-window", "doc-qa-decode", "doc-qa-window"])
+def test_grouped_expert_products(one_chip, T, k, E, n, D, F):
+    """One expert layer of each expert cell at its own widths, rows and
+    held experts (a decode step's rows and a step with a window riding):
+    both grouped kernels fit fast memory with their weight blocks — the
+    gate and up blocks double-buffered beside each other — and every
+    product of the layer is one of them."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_grouped import (DOWN_KERNEL_NAME,
+                                                GATE_UP_KERNEL_NAME)
+    from mmlspark_tpu.models.moe import dropless_moe
+    _, text = _compile(
+        lambda x, e, p, a, b, c: dropless_moe(
+            x, e, p, a, b, c, held=(0, n), num_experts=E, impl="pallas",
+            interpret=False),
+        _sds((T, D), jnp.bfloat16, one_chip), _sds((T, k), jnp.int32,
+                                                    one_chip),
+        _sds((T, k), jnp.float32, one_chip),
+        _sds((n, D, F), jnp.bfloat16, one_chip),
+        _sds((n, D, F), jnp.bfloat16, one_chip),
+        _sds((n, F, D), jnp.bfloat16, one_chip))
+    assert _defined(text, GATE_UP_KERNEL_NAME) == 1
+    assert _defined(text, DOWN_KERNEL_NAME) == 1
+    assert "ragged-dot-none" not in text
+
+
 @pytest.mark.parametrize("window", [1, 64, 512], ids=lambda w: f"w{w}")
 def test_paged_attention_on_key_heads_of_64(one_chip, window):
     """32 query heads on 8 key heads of 64 over pools ``[800, 512, 512]``,
@@ -658,6 +703,13 @@ def test_short_conv_moe_engine_programs(one_chip, steer_tpu):
         text = compiled.as_text()
         for kernel in ("paged_gqa_attn", "ragged-dot"):
             assert kernel in text, (name, kernel)
+        # every expert product of the twelve expert layers is one of the
+        # two grouped kernels, and no ragged_dot pass is left beside them
+        # (a program with no head leaves the last layer's experts out)
+        layers = 11 if name.endswith("head0") else 12
+        for kernel in ("ragged-dot-gate-up", "ragged-dot-down"):
+            assert _defined(text, kernel) == layers, (name, kernel)
+        assert "ragged-dot-none" not in text, name
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= at_rest, name
         assert mem.temp_size_in_bytes < 1.5e9, (name,

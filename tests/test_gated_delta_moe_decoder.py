@@ -124,6 +124,31 @@ def test_absent_experts_terms_are_left_out_as_in_the_reference(model):
         stats["moe_pairs_held"] + stats["moe_pairs_absent"] == 30 * 2 * 4
 
 
+def test_walk_gives_the_same_rows_through_the_grouped_kernels(
+        model, grouped_kernels):
+    """The expert products through the Pallas kernels give the rows of the
+    ``ragged_dot`` passes (float32: the order of the sums) with experts
+    2-5 of 8 held, in a window and in steps; every count but the tiles'
+    rows is the same."""
+    cfg, weights, _ = model
+    held = {**cfg, "experts_held": [2, 6]}
+    cut = {**weights, "layers": [
+        {**lw, **{k: lw[k][2:6] for k in ("exp_gate", "exp_up", "exp_down")}}
+        for lw in weights["layers"]]}
+    module = GatedDeltaMoEDecoder(held, dtype=jnp.float32, max_window=24)
+    tokens = np.random.default_rng(9).integers(1, 256, 26)
+    windows = [24, 1, 1]
+    via_kernels, counts = _walk_all(module, cut, tokens, windows)
+    grouped_kernels.undo()
+    via_lax, lax_counts = _walk_all(module, cut, tokens, windows)
+    np.testing.assert_allclose(via_kernels, via_lax, rtol=1e-4, atol=1e-4)
+    a, b = (dict(zip(module.walk_stats, c)) for c in (counts, lax_counts))
+    assert {k: v for k, v in a.items() if k != "moe_rows_computed"} == \
+        {k: v for k, v in b.items() if k != "moe_rows_computed"}
+    assert b["moe_rows_computed"] == b["moe_pairs_held"]
+    assert a["moe_rows_computed"] > a["moe_pairs_held"] > 0
+
+
 def test_the_cache_spec_states_two_arrays_a_sequence(model):
     _, _, module = model
     spec = module.cache_spec()

@@ -82,7 +82,7 @@ def test_prefill_then_decode_through_the_pool_matches_the_reference(model):
         pools)
     assert hidden.shape == (2, w, 64)        # hidden rows, no head
     logits = head(hidden)
-    assert counts.shape == (4,) and int(counts[0]) > 0
+    assert counts.shape == (5,) and int(counts[0]) > 0
     got = [[np.asarray(logits[i, :p])] for i, p in enumerate(prefix)]
     for step in range(5):
         pos = jnp.asarray([p + step for p in prefix], jnp.int32)
@@ -209,6 +209,43 @@ def test_dropless_moe_leaves_padding_and_absent_experts_out():
     np.testing.assert_allclose(out, want, atol=1e-4, rtol=1e-4)
     assert int(counts[0]) == held and int(counts[1]) == 9 * k - held
     assert np.all(np.asarray(out[9:]) == 0)
+
+
+def test_walk_gives_the_same_rows_through_the_grouped_kernels(
+        model, grouped_kernels):
+    """The expert products through the Pallas kernels give the hidden rows
+    of the ``ragged_dot`` passes (float32: the order of the sums) for a
+    prefill window of two sequences, one of them padded, and a decode
+    step; every count but the tiles' rows is the same."""
+    cfg, weights, module = model
+    bl, mb, w = 8, 6, 24
+    rng = np.random.default_rng(9)
+    toks = jnp.asarray(rng.integers(1, 256, (2, w)), jnp.int32)
+    rows = _rows_for([[1, 2, 3, 4], [5, 6, 7]], mb)
+    valid = jnp.arange(w)[None] < jnp.asarray([[21], [13]])
+
+    def walk():
+        pools = init_pools(module.cache_spec(), 16, bl)
+        (hidden,), pools, c = module.apply(
+            {"params": weights},
+            ((toks, rows, jnp.zeros(2, jnp.int32), valid),), pools)
+        (step,), _, c2 = module.apply(
+            {"params": weights}, ((toks[:, :1], rows,
+                                   jnp.asarray([21, 13], jnp.int32),
+                                   jnp.ones((2, 1), bool)),), pools)
+        return (np.concatenate([np.asarray(hidden)[0, :21],
+                                np.asarray(hidden)[1, :13],
+                                np.asarray(step)[:, 0]]),
+                dict(zip(module.walk_stats, np.asarray(c) + np.asarray(c2))))
+
+    via_kernels, a = walk()
+    grouped_kernels.undo()
+    via_lax, b = walk()
+    np.testing.assert_allclose(via_kernels, via_lax, rtol=1e-4, atol=1e-4)
+    assert {k: v for k, v in a.items() if k != "moe_rows_computed"} == \
+        {k: v for k, v in b.items() if k != "moe_rows_computed"}
+    assert b["moe_rows_computed"] == b["moe_pairs_held"]
+    assert a["moe_rows_computed"] > a["moe_pairs_held"] > 0
 
 
 def test_cache_spec_prices_and_shapes_the_latent_pool(model):

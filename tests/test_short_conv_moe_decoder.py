@@ -110,6 +110,25 @@ def test_a_prompt_cut_into_any_windows_gives_the_same_rows(model, cuts):
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
+def test_walk_gives_the_same_rows_through_the_grouped_kernels(
+        model, grouped_kernels):
+    """The expert products through the Pallas kernels give the rows of the
+    ``ragged_dot`` passes (float32: the order of the sums), in windows and
+    in steps; every count but the tiles' rows is the same."""
+    cfg, weights, module = model
+    tokens = np.random.default_rng(9).integers(1, 256, 33)
+    windows = [24, 7, 1, 1]
+    via_kernels, counts = _walk_all(module, weights, tokens, windows)
+    grouped_kernels.undo()
+    via_lax, lax_counts = _walk_all(module, weights, tokens, windows)
+    np.testing.assert_allclose(via_kernels, via_lax, rtol=1e-4, atol=1e-4)
+    a, b = (dict(zip(module.walk_stats, c)) for c in (counts, lax_counts))
+    assert {k: v for k, v in a.items() if k != "moe_rows_computed"} == \
+        {k: v for k, v in b.items() if k != "moe_rows_computed"}
+    assert b["moe_rows_computed"] == b["moe_pairs_held"]
+    assert a["moe_rows_computed"] > a["moe_pairs_held"]
+
+
 def test_the_tail_rounds_to_the_serving_type():
     """In bfloat16 the program's windows read the tail as the reference
     rounds it: a row at a time and one window give the same rows."""
