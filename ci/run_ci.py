@@ -80,7 +80,8 @@ PACKAGES: dict[str, list[str]] = {
             "test_paged_kv_state.py", "test_sparse_linear_decoder.py",
             "test_gated_delta.py", "test_gated_delta_moe_decoder.py",
             "test_qwen3_next_reference.py", "test_short_conv_moe_decoder.py",
-            "test_lfm2_moe_reference.py", "test_pallas_grouped.py"],
+            "test_lfm2_moe_reference.py", "test_pallas_grouped.py",
+            "test_kda.py", "test_kimi_linear_decoder.py"],
     # zero-downtime model lifecycle: versioned registry + blue/green
     # router + canary burn-rate rollback, and the rollout acceptance
     "deploy": ["test_deploy.py"],

@@ -47,6 +47,7 @@ _EXPORTS = {
     "paged_attention": ".pallas_paged_attention",
     "paged_window_attention": ".pallas_paged_attention",
     "paged_latent_attention": ".pallas_paged_attention",
+    "KimiLinearDecoder": ".kimi_linear_decoder",
     "LatentMoEDecoder": ".latent_moe_decoder",
     "SparseLinearDecoder": ".sparse_linear_decoder",
     "GatedDeltaMoEDecoder": ".gated_delta_moe_decoder",

@@ -1,6 +1,7 @@
 """What the config-driven decoders of ``serving.llm.LLMEngine`` share
 (``dl.latent_moe_decoder``, ``dl.sparse_linear_decoder``,
-``dl.gated_delta_moe_decoder``, ``dl.short_conv_moe_decoder``): RMSNorm in
+``dl.gated_delta_moe_decoder``, ``dl.short_conv_moe_decoder``,
+``dl.kimi_linear_decoder``): RMSNorm in
 float32 (plain and zero-centred), the matrix product on operands of the
 serving type with float32 accumulation, the gated SiLU MLP, the two rotary
 pairings, the causal convolution whose inputs before a window are a

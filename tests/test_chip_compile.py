@@ -55,6 +55,7 @@ def steer_tpu(monkeypatch):
     option of the program) so that the chip's path is what is lowered."""
     import mmlspark_tpu.dl.pallas_gated_delta as gated_delta
     import mmlspark_tpu.dl.pallas_grouped as grouped
+    import mmlspark_tpu.dl.pallas_kda as kda
     import mmlspark_tpu.dl.pallas_lightning as lightning
     import mmlspark_tpu.dl.pallas_paged_attention as paged
     import mmlspark_tpu.utils.platform as plat
@@ -63,6 +64,7 @@ def steer_tpu(monkeypatch):
     monkeypatch.setattr(lightning, "target_platform", lambda: "tpu")
     monkeypatch.setattr(gated_delta, "target_platform", lambda: "tpu")
     monkeypatch.setattr(grouped, "target_platform", lambda: "tpu")
+    monkeypatch.setattr(kda, "target_platform", lambda: "tpu")
 
 
 def _sds(shape, dtype, sharding):
@@ -496,6 +498,31 @@ def test_gated_delta_kernels(one_chip, window):
     text = compiled.as_text()
     assert ("gated_delta_step" if window == 1 else "gated_delta_chunk") \
         in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= rows * H * hd * hd * 4
+
+
+@pytest.mark.parametrize("window", [1, 512], ids=lambda w: f"w{w}")
+def test_kda_kernels(one_chip, window):
+    """The gated delta rule with a decay a key channel, 32 heads of 128 x
+    128 over a pool of 137 states: the decode step (128 slots) and the
+    chunked form of a 512-row prefill window; the pool comes back in the
+    buffer it arrived in."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.pallas_kda import kda_rule
+    S, H, hd, rows = (128 if window == 1 else 1), 32, 128, 137
+    qkv = [_sds((S, window, H, hd), jnp.bfloat16, one_chip)] * 3
+    compiled = jax.jit(
+        lambda q, k, v, g, b, st, sr, ps, ln: kda_rule(
+            q, k, v, g, b, st, sr, ps, ln, impl="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+            *qkv, _sds((S, window, H, hd), jnp.float32, one_chip),
+            _sds((S, window, H), jnp.float32, one_chip),
+            _sds((rows, H, hd, hd), jnp.float32, one_chip),
+            *[_sds((S,), jnp.int32, one_chip)] * 3).compile()
+    assert ("kda_step" if window == 1 else "kda_chunk") in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= rows * H * hd * hd * 4
 
